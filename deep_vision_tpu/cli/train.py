@@ -215,9 +215,9 @@ def main(argv=None):
         if dev_norm:
             from deep_vision_tpu.ops.preprocess import make_imagenet_preprocess
 
-            # try the fused Pallas train-ingest (decode+jitter+normalize in
-            # one VMEM pass) at the REAL per-shard compiled shape — the
-            # factory parity-gates it and falls back to the XLA path.
+            # fused Pallas train-ingest (decode+jitter+normalize in one
+            # VMEM pass), checked against the XLA path at the REAL
+            # per-shard compiled shape before the step bakes it in.
             # cfg.batch_size is per-host; the data axis spans all hosts.
             import jax as _jax
 
@@ -236,11 +236,18 @@ def main(argv=None):
     if args.profile:
         trainer.profile_steps = (10, 20)
     state = None
-    if args.pretrained:
-        state = _load_pretrained_state(args, cfg, trainer, train_loader)
-    state = trainer.fit(train_loader, val_loader, state=state,
-                        resume=args.resume)
-    final = trainer.evaluate(state, val_loader)
+    try:
+        if args.pretrained:
+            state = _load_pretrained_state(args, cfg, trainer, train_loader)
+        state = trainer.fit(train_loader, val_loader, state=state,
+                            resume=args.resume)
+        final = trainer.evaluate(state, val_loader)
+    finally:
+        # the ImageNet loaders own decode worker pools: a caller that
+        # outlives main() (bench.py, chip_smoke.py) must not inherit them
+        for loader in (train_loader, val_loader):
+            if hasattr(loader, "close"):
+                loader.close()
     print("final:", " ".join(f"{k}={v:.4f}" for k, v in final.items()))
     return 0
 
@@ -347,18 +354,20 @@ def _main_detection(args, cfg, mesh):
         from deep_vision_tpu.data.detection import DetectionLoader as LoaderCls
         from deep_vision_tpu.tasks.detection import YoloTask
 
-        # pallas ignore-mask kernel: TPU only, gated on a parity check;
-        # sharded meshes route it through a data-axis shard_map
-        # (best_iou_max_sharded), so multi-chip keeps the fused path
+        # pallas ignore-mask kernel: TPU only; sharded meshes route it
+        # through a data-axis shard_map (best_iou_max_sharded), so
+        # multi-chip keeps the fused path
         use_pallas = jax.default_backend() == "tpu"
         if use_pallas:
-            from deep_vision_tpu.ops.pallas_ops import pallas_parity_ok
+            from deep_vision_tpu.ops.pallas_ops import best_iou_parity
             from deep_vision_tpu.tasks.detection import MAX_BOXES
 
-            # check at the REAL compiled shapes — Mosaic tiling/VMEM limits
-            # are shape-dependent, so toy shapes prove nothing; the loss
-            # calls the kernel once PER SCALE with that scale's n_pred, and
-            # under shard_map the kernel sees the PER-SHARD batch.
+            # run it once against the XLA path at the REAL compiled shapes
+            # — Mosaic tiling/VMEM limits are shape-dependent, so toy
+            # shapes prove nothing; the loss calls the kernel once PER
+            # SCALE with that scale's n_pred, and under shard_map the
+            # kernel sees the PER-SHARD batch.  A refusal or a mismatch
+            # stops the run here with the reason.
             # cfg.batch_size is per-HOST, the data axis spans all hosts —
             # the global batch is per-host × process_count; grad accum then
             # splits each shard into microbatches INSIDE the step, so the
@@ -367,11 +376,11 @@ def _main_detection(args, cfg, mesh):
             accum = max(1, getattr(cfg, "grad_accum_steps", 1))
             per_shard = max(
                 global_batch // mesh.shape.get("data", 1) // accum, 1)
-            use_pallas = all(
-                pallas_parity_ok(batch=per_shard,
-                                 n_pred=3 * (cfg.image_size // s) ** 2,
-                                 n_gt=MAX_BOXES)
-                for s in (8, 16, 32))
+            for s in (8, 16, 32):
+                best_iou_parity(batch=per_shard,
+                                n_pred=3 * (cfg.image_size // s) ** 2,
+                                n_gt=MAX_BOXES)
+        print(f"[loss] ignore mask: {'pallas' if use_pallas else 'xla'}")
         task = YoloTask(cfg.num_classes, use_pallas=use_pallas,
                         mesh=mesh if mesh.devices.size > 1 else None)
     if args.synthetic:

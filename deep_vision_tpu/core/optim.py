@@ -17,7 +17,9 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Sequence
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 
 
@@ -45,7 +47,6 @@ def _weight_decay_mask(params):
     effective behavior of torch SGD weight_decay on conv/fc layers dominating
     the norm (ResNet/pytorch/train.py:166-184 uses blanket 1e-4; we use the
     modern no-BN-decay recipe required to reach 76% top-1)."""
-    import jax
 
     def keep(path, x):
         leaf = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
@@ -99,9 +100,22 @@ def get_learning_rate(opt_state) -> float:
 
 
 def set_learning_rate(opt_state, lr: float):
-    """Functionally rewrite the injected LR (no retrace: same pytree shape)."""
+    """Functionally rewrite the injected LR.  No retrace: same pytree
+    shape AND the same placement as the leaf it replaces — a placed
+    leaf's sharding is part of its type, so a fresh unplaced scalar in
+    the first call (where every later call sees the step's own placed
+    output) made jit trace and compile the whole train step a second
+    time (ResNet-50 on the v5e: 40 s + 30 s)."""
     hp = dict(opt_state.hyperparams)
-    hp["learning_rate"] = jnp.asarray(lr, jnp.asarray(hp["learning_rate"]).dtype)
+    old = hp["learning_rate"]
+    new = np.asarray(lr, jnp.asarray(old).dtype)
+    if isinstance(old, jax.Array) and old.committed:
+        # also right on a multi-process mesh: every process holds the
+        # same scalar and contributes its addressable replicas
+        hp["learning_rate"] = jax.make_array_from_process_local_data(
+            old.sharding, new)
+    else:
+        hp["learning_rate"] = jnp.asarray(new)
     return opt_state._replace(hyperparams=hp)
 
 

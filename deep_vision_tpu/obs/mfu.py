@@ -18,9 +18,10 @@ StableHLO blob has no compiled object; some backends return no
 dense-matmul LOWER BOUND that ignores convolution reuse — and labels
 the source ``params_lower_bound`` so a too-good-to-be-true gauge is
 never silently wrong.  Peak FLOP/s comes from the same public
-spec-sheet table bench.py has always used (bf16 dense, per chip);
-non-TPU backends fall back to the v5e figure, which makes CPU-run MFU
-honest only as a "> 0 and sane" plumbing check, not a roofline.
+spec-sheet table bench.py has always used (bf16 dense, per chip).  A
+device that is not in the table has no peak: ``peak_tflops`` raises and
+the meter reports ``serving_mfu: None`` — a CPU run counts FLOPs and
+compute seconds but never divides them by some other chip's rate.
 """
 
 from __future__ import annotations
@@ -38,11 +39,10 @@ PEAK_BF16_TFLOPS = {
     "TPU v6 lite": 918.0,   # Trillium
 }
 
-_DEFAULT_TFLOPS = 197.0  # conservative: v5e
-
 
 def peak_tflops(device_kind: str | None = None) -> float:
-    """Peak bf16 TFLOP/s for a device kind (current backend if None)."""
+    """Peak bf16 TFLOP/s for a device kind (current backend if None);
+    ``LookupError`` for a device the table does not list."""
     if device_kind is None:
         import jax
 
@@ -50,7 +50,11 @@ def peak_tflops(device_kind: str | None = None) -> float:
     for k, v in PEAK_BF16_TFLOPS.items():
         if device_kind.startswith(k):
             return v
-    return _DEFAULT_TFLOPS
+    raise LookupError(
+        f"no peak bf16 TFLOP/s on record for device kind "
+        f"'{device_kind}' (have {sorted(PEAK_BF16_TFLOPS)}); an MFU "
+        f"needs the real chip's peak — add it to PEAK_BF16_TFLOPS with "
+        f"its source")
 
 
 def peak_flops_per_s(device_kind: str | None = None) -> float:
@@ -113,12 +117,14 @@ class MfuMeter:
     Thread-safe under its own lock: ``observe`` is called from the
     drainer (pipelined path) and from the synchronous retry path.  The
     peak resolves lazily on first ``report`` so constructing an engine
-    never initializes the JAX backend.
+    never initializes the JAX backend; on a device without a known peak
+    it stays None and so does the MFU.
     """
 
     def __init__(self, peak: float | None = None):
         self._lock = new_lock("obs.mfu.MfuMeter._lock")
         self._peak = peak
+        self._peak_resolved = peak is not None
         self._bucket_flops: dict[int, float | None] = {}  # guarded-by: _lock
         self._source: str | None = None  # guarded-by: _lock
         self.batches = 0  # guarded-by: _lock
@@ -147,9 +153,13 @@ class MfuMeter:
             else:
                 self.unknown_flops_batches += 1
 
-    def peak(self) -> float:
-        if self._peak is None:
-            self._peak = peak_flops_per_s()
+    def peak(self) -> float | None:
+        if not self._peak_resolved:
+            try:
+                self._peak = peak_flops_per_s()
+            except LookupError:
+                self._peak = None  # unknown device: no peak, no MFU
+            self._peak_resolved = True
         return self._peak
 
     def mfu(self) -> float | None:
@@ -157,7 +167,8 @@ class MfuMeter:
             if self.compute_s <= 0 or self.flops <= 0:
                 return None
             flops, secs = self.flops, self.compute_s
-        return flops / secs / self.peak()
+        peak = self.peak()
+        return flops / secs / peak if peak else None
 
     def report(self) -> dict:
         mfu = self.mfu()
@@ -180,8 +191,9 @@ class MfuMeter:
         FLOPs and compute seconds sum; MFU recomputes from the sums."""
         flops = sum(m.flops for m in meters)
         secs = sum(m.compute_s for m in meters)
-        peak = meters[0].peak() if meters else peak_flops_per_s()
-        mfu = flops / secs / peak if secs > 0 and flops > 0 else None
+        peak = meters[0].peak() if meters else None
+        mfu = flops / secs / peak \
+            if peak and secs > 0 and flops > 0 else None
         by_bucket: dict[str, float | None] = {}
         for m in meters:
             for b, f in m._bucket_flops.items():

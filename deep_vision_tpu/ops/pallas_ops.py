@@ -21,8 +21,8 @@ VMEM, broadcasts the tiny gt set per tile, and reduces to the (B, N) max
 in-register — one HBM pass over the predictions.
 
 Layout notes (TPU tiling):
-- predictions arrive (B, N, 4) and are processed in (TILE_N, 4) VMEM
-  blocks; coordinate columns are read as (TILE_N, 1) slices so the
+- predictions arrive (B, N, 4) and are processed in (TILE_B, TILE_N, 4)
+  VMEM blocks; coordinate columns are read as (TILE_N, 1) slices so the
   (TILE_N, M) broadcast needs no in-kernel transpose;
 - ground truth is passed PRE-TRANSPOSED as (B, 4, M) so coordinate rows
   read as (1, M) slices — M is padded to the 128-lane width;
@@ -39,6 +39,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 TILE_N = 256
+#: best_iou_max batch tile — the f32 sublane granularity, so the (B, N)
+#: output block tiles cleanly
+TILE_B = 8
 LANE = 128
 #: serve_ingest row tile (sublane dim of the (B·H, W·C) view) — a
 #: multiple of the int8 sublane granularity (32) so the quantized
@@ -74,13 +77,20 @@ def _ingest_norm_constants(kind: str, channels: int):
     return mean, std
 
 
+def _load_u8_as_f32(x_ref):
+    # dvtlint: traced
+    # Mosaic has no uint8 → float32 cast ("Unsupported cast" at lowering);
+    # widening to int32 first is supported and exact for 0..255
+    return x_ref[...].astype(jnp.int32).astype(jnp.float32)
+
+
 def _serve_ingest_kernel(x_ref, mean_ref, std_ref, out_ref, *,
                          act_scale: float, quantize: bool):
     # dvtlint: traced
     # one (TILE_R, lanes) block: decode, normalize, quantize, store —
     # division (not reciprocal-multiply) keeps it bit-identical to the
     # XLA serve_normalize/quantize_activations path
-    x = x_ref[...].astype(jnp.float32) / 255.0
+    x = _load_u8_as_f32(x_ref) / 255.0
     y = (x - mean_ref[...]) / std_ref[...]
     if quantize:
         q = jnp.clip(jnp.round(y / act_scale), -127.0, 127.0)
@@ -142,42 +152,55 @@ def serve_ingest_auto(x, kind: str, act_scale: float = 1.0,
                         interpret=not on_tpu)
 
 
-_INGEST_PARITY_CACHE: dict[tuple, bool] = {}
+#: max error of every parity check run compiled in this process, by
+#: (kernel, shape, parameters) — bucket programs and replicas re-ask
+_PARITY_CACHE: dict[tuple, float] = {}
 
 
-def ingest_parity_ok(shape: tuple, kind: str, act_scale: float,
-                     interpret: bool = False) -> bool:
-    """One-batch parity check of the compiled ingest kernel vs the pure
-    jnp reference, gated per (shape, kind) before a bucket program
-    selects the Pallas path on real hardware (the ``pallas_parity_ok``
-    pattern: Mosaic lowering is environment- and shape-sensitive, so a
-    compile failure or >1-step divergence falls back to XLA)."""
-    key = (tuple(shape), kind, round(float(act_scale), 12))
-    if key in _INGEST_PARITY_CACHE and not interpret:
-        return _INGEST_PARITY_CACHE[key]
-    try:
-        B, H, W, C = shape
+def _parity_once(key: tuple, interpret: bool, run):
+    """``run()`` → max error, remembered per ``key`` for compiled runs
+    (interpreted ones are tests: always re-run).  ``run`` raises on a
+    mismatch, so only passing results are ever stored."""
+    if not interpret and key in _PARITY_CACHE:
+        return _PARITY_CACHE[key]
+    err = run()
+    if not interpret:
+        _PARITY_CACHE[key] = err
+    return err
+
+
+def serve_ingest_parity(shape: tuple, kind: str, act_scale: float,
+                        interpret: bool = False) -> int:
+    """Run the ingest kernel on one seeded batch of ``shape`` and compare
+    it with the pure-NumPy prologue; returns the max error in
+    quantization steps, cached per (shape, kind, scale) per process.
+
+    Called before a bucket program bakes the kernel in.  Nothing here
+    selects a path: a Mosaic lowering or compile error propagates, and a
+    divergence beyond one step of rounding slack raises with its size —
+    a chip that cannot run the kernel says so instead of quietly serving
+    the XLA prologue."""
+
+    def run() -> int:
         raw = np.random.RandomState(7).randint(0, 256, shape, np.uint8)
         got = np.asarray(jax.device_get(
             serve_ingest(jnp.asarray(raw), kind, act_scale=act_scale,
                          interpret=interpret))).astype(np.int32)
-        mean_c, std_c = _ingest_norm_constants(kind, C)
+        mean_c, std_c = _ingest_norm_constants(kind, shape[-1])
         y = (raw.astype(np.float32) / 255.0 - mean_c) / std_c
         want = np.clip(np.round(y / float(act_scale)), -127.0,
                        127.0).astype(np.int32)
         err = int(np.abs(got - want).max())
-        ok = err <= 1  # one quantization step of rounding slack
-        if not ok:
-            print(f"[pallas] ingest parity FAILED (max err {err} steps)"
-                  " — falling back to the XLA serve prologue")
-    except Exception as e:  # noqa: BLE001 — compile/runtime failure → XLA fallback
-        print(f"[pallas] ingest kernel unavailable "
-              f"({type(e).__name__}: {e}) — falling back to the XLA "
-              f"serve prologue")
-        ok = False
-    if not interpret:
-        _INGEST_PARITY_CACHE[key] = ok
-    return ok
+        if err > 1:  # one quantization step of rounding slack
+            raise RuntimeError(
+                f"[pallas] serve_ingest {tuple(shape)} '{kind}': max error "
+                f"{err} quantization steps against the reference prologue "
+                f"(allowed: 1)")
+        return err
+
+    return _parity_once(
+        ("serve_ingest", tuple(shape), kind, round(float(act_scale), 12)),
+        interpret, run)
 
 
 def _gray_matrix(W: int, C: int, l_pad: int) -> np.ndarray:
@@ -206,14 +229,18 @@ def _train_ingest_kernel(x_ref, s_ref, mean_ref, std_ref, g_ref, out_ref):
     # post-brightness image mean prebaked into per-ROW scalars (every row
     # of image i carries the same (fb, fc, fs, m) — computed in-trace by
     # train_ingest_factors, so no cross-row reduction happens in-kernel)
-    x = x_ref[...].astype(jnp.float32) / 255.0
+    x = _load_u8_as_f32(x_ref) / 255.0
     fb = s_ref[:, 0:1]
     fc = s_ref[:, 1:2]
     fs = s_ref[:, 2:3]
     m = s_ref[:, 3:4]
     x = x * fb                     # brightness
     x = (x - m) * fc + m           # contrast about the per-image mean
-    gray = jnp.dot(x, g_ref[...], preferred_element_type=jnp.float32)
+    # HIGHEST: Mosaic's default f32 matmul is one bf16 MXU pass, which
+    # puts the gray 4e-3 off the elementwise XLA reference (measured on
+    # the v5e); the fp32 contraction agrees to 1e-6
+    gray = jnp.dot(x, g_ref[...], preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
     x = gray + (x - gray) * fs     # saturation toward per-pixel gray
     x = jnp.clip(x, 0.0, 1.0)
     out_ref[...] = (x - mean_ref[...]) / std_ref[...]
@@ -257,8 +284,8 @@ def train_ingest(x, factors, kind: str = "imagenet",
     quadruple is repeated per row (every row of image i shares it) and
     saturation's per-pixel gray is a matmul against a prebaked
     block-diagonal matrix (no in-kernel reshape).  CPU tests run with
-    ``interpret=True``; real use goes through the per-shape parity gate
-    (``train_ingest_parity_ok``) with jitter_normalize as the fallback.
+    ``interpret=True``; real use is checked once per shape against
+    ``jitter_normalize`` (``train_ingest_parity``).
     """
     B, H, W, C = x.shape
     mean_c, std_c = _ingest_norm_constants(kind, C)
@@ -308,92 +335,74 @@ def train_ingest_sharded(x, factors, mesh, kind: str = "imagenet"):
 
     from deep_vision_tpu.parallel.mesh import DATA_AXIS
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-
     fn = functools.partial(train_ingest_auto, kind=kind)
     spec = P(DATA_AXIS)
-    try:
-        wrapped = shard_map(fn, mesh=mesh, in_specs=(spec, spec),
-                            out_specs=spec, check_vma=False)
-    except TypeError:  # older jax without check_vma
-        wrapped = shard_map(fn, mesh=mesh, in_specs=(spec, spec),
-                            out_specs=spec)
-    return wrapped(x, factors)
+    # check_vma=False: pallas_call cannot annotate varying-manual-axes on
+    # its outputs (sound here: no collectives inside, every input/output
+    # is batch-sharded the same way)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec),
+                         out_specs=spec, check_vma=False)(x, factors)
 
 
-_TRAIN_INGEST_PARITY_CACHE: dict[tuple, bool] = {}
+def train_ingest_parity(shape: tuple, kind: str = "imagenet",
+                        brightness: float = 0.2, contrast: float = 0.2,
+                        saturation: float = 0.2,
+                        interpret: bool = False,
+                        tol: float = 1e-4) -> float:
+    """Run the fused train-ingest kernel on one seeded batch of ``shape``
+    and compare it with the XLA ``jitter_normalize`` path; returns the max
+    absolute error, cached per (shape, kind, jitter params) per process.
 
-
-def train_ingest_parity_ok(shape: tuple, kind: str = "imagenet",
-                           brightness: float = 0.2, contrast: float = 0.2,
-                           saturation: float = 0.2,
-                           interpret: bool = False,
-                           tol: float = 1e-4) -> bool:
-    """One-batch parity check of the fused train-ingest kernel vs the XLA
-    ``jitter_normalize`` path, gated per (shape, kind, jitter params)
-    before the trainer's preprocess_fn selects the Pallas path (the PR 10
-    ``ingest_parity_ok`` pattern: Mosaic lowering is environment- and
-    shape-sensitive, so a compile failure or numeric divergence beyond
-    ``tol`` falls back to XLA — never a silent accuracy change)."""
+    Called before the trainer's preprocess_fn bakes the kernel in.  Like
+    ``serve_ingest_parity`` it selects nothing: a Mosaic error propagates
+    and a divergence beyond ``tol`` raises with its size."""
     from deep_vision_tpu.ops.preprocess import jitter_normalize
 
-    key = (tuple(shape), kind,
-           round(float(brightness), 6), round(float(contrast), 6),
-           round(float(saturation), 6))
-    if key in _TRAIN_INGEST_PARITY_CACHE and not interpret:
-        return _TRAIN_INGEST_PARITY_CACHE[key]
-    try:
-        B, H, W, C = shape
-        raw = np.random.RandomState(11).randint(0, 256, shape, np.uint8)
+    def run() -> float:
+        raw = jnp.asarray(
+            np.random.RandomState(11).randint(0, 256, shape, np.uint8))
         rng = jax.random.PRNGKey(23)
-        mean_c, std_c = _ingest_norm_constants(kind, C)
-        factors = train_ingest_factors(jnp.asarray(raw), rng,
-                                       brightness, contrast, saturation)
+        mean_c, std_c = _ingest_norm_constants(kind, shape[-1])
+        factors = train_ingest_factors(raw, rng, brightness, contrast,
+                                       saturation)
         got = np.asarray(jax.device_get(
-            train_ingest(jnp.asarray(raw), factors, kind,
-                         interpret=interpret)))
+            train_ingest(raw, factors, kind, interpret=interpret)))
         want = np.asarray(jax.device_get(jitter_normalize(
-            jnp.asarray(raw), rng, True, mean=mean_c, std=std_c,
-            brightness=brightness, contrast=contrast,
-            saturation=saturation)))
+            raw, rng, True, mean=mean_c, std=std_c, brightness=brightness,
+            contrast=contrast, saturation=saturation)))
         err = float(np.abs(got - want).max())
-        ok = err <= tol
-        if not ok:
-            print(f"[pallas] train-ingest parity FAILED (max err {err:.2e})"
-                  " — falling back to the XLA jitter_normalize prologue")
-    except Exception as e:  # noqa: BLE001 — compile/runtime failure → XLA fallback
-        print(f"[pallas] train-ingest kernel unavailable "
-              f"({type(e).__name__}: {e}) — falling back to the XLA "
-              f"jitter_normalize prologue")
-        ok = False
-    if not interpret:
-        _TRAIN_INGEST_PARITY_CACHE[key] = ok
-    return ok
+        if not err <= tol:
+            raise RuntimeError(
+                f"[pallas] train_ingest {tuple(shape)} '{kind}': max error "
+                f"{err:.2e} against jitter_normalize (allowed: {tol:.0e})")
+        return err
+
+    return _parity_once(
+        ("train_ingest", tuple(shape), kind, round(float(brightness), 6),
+         round(float(contrast), 6), round(float(saturation), 6)),
+        interpret, run)
 
 
 def _best_iou_kernel(pred_ref, gt_ref, mask_ref, out_ref):
-    # blocks carry the FULL batch (out tiling rule: the sublane dim of the
-    # (B, N) output block must equal B); grid runs over N tiles only.
-    # pred_ref: (B, TILE_N, 4); gt_ref: (B, 4, M); mask_ref: (B, 1, M)
-    px1 = pred_ref[:, :, 0:1]   # (B, T, 1)
+    # one (TILE_B images × TILE_N predictions) block; the grid runs over
+    # batch tiles and N tiles.
+    # pred_ref: (TB, TILE_N, 4); gt_ref: (TB, 4, M); mask_ref: (TB, 1, M)
+    px1 = pred_ref[:, :, 0:1]   # (TB, T, 1)
     py1 = pred_ref[:, :, 1:2]
     px2 = pred_ref[:, :, 2:3]
     py2 = pred_ref[:, :, 3:4]
-    gx1 = gt_ref[:, 0:1, :]     # (B, 1, M)
+    gx1 = gt_ref[:, 0:1, :]     # (TB, 1, M)
     gy1 = gt_ref[:, 1:2, :]
     gx2 = gt_ref[:, 2:3, :]
     gy2 = gt_ref[:, 3:4, :]
-    mask = mask_ref[:, 0:1, :]  # (B, 1, M)
+    mask = mask_ref[:, 0:1, :]  # (TB, 1, M)
 
     inter_w = jnp.maximum(jnp.minimum(px2, gx2) - jnp.maximum(px1, gx1), 0.0)
     inter_h = jnp.maximum(jnp.minimum(py2, gy2) - jnp.maximum(py1, gy1), 0.0)
-    inter = inter_w * inter_h                            # (B, T, M)
+    inter = inter_w * inter_h                            # (TB, T, M)
     area_p = jnp.maximum(px2 - px1, 0.0) * jnp.maximum(py2 - py1, 0.0)
     area_g = jnp.maximum(gx2 - gx1, 0.0) * jnp.maximum(gy2 - gy1, 0.0)
-    iou = inter / (area_p + area_g - inter + 1e-9)       # (B, T, M)
+    iou = inter / (area_p + area_g - inter + 1e-9)       # (TB, T, M)
     iou = jnp.where(mask > 0, iou, 0.0)
     out_ref[:, :] = jnp.max(iou, axis=2)
 
@@ -403,30 +412,41 @@ def best_iou_max(pred_boxes, gt_boxes, gt_mask, interpret: bool = False):
     """(B,N,4) corner preds × (B,M,4) corner gts + (B,M) mask → (B,N) max IoU.
 
     Matches ``broadcast_iou(...).max(-1)`` with masked gts scoring 0.
+
+    The batch is tiled through the grid TILE_B images at a time: the
+    (TILE_N, 4) prediction rows pad their 4-wide lane dim to 128 in VMEM,
+    so one image's block is 128 KiB and its (TILE_N, M) temporaries as
+    much again each — a whole batch of 128 (``yolov3_coco``) in one block
+    would ask for several times the chip's VMEM.
     """
     B, N, _ = pred_boxes.shape
     M = gt_boxes.shape[1]
+    # the (B, N) output block's sublane dim must be a multiple of 8 or
+    # the whole batch
+    tile_b = B if B <= TILE_B else TILE_B
+    b_pad = (-B) % tile_b
     n_pad = (-N) % TILE_N
     m_pad = (-M) % LANE
-    pred = jnp.pad(pred_boxes, ((0, 0), (0, n_pad), (0, 0)))
-    gt_t = jnp.pad(jnp.swapaxes(gt_boxes, 1, 2), ((0, 0), (0, 0), (0, m_pad)))
-    mask = jnp.pad(gt_mask, ((0, 0), (0, m_pad)))[:, None, :]
-    Np, Mp = N + n_pad, M + m_pad
+    pred = jnp.pad(pred_boxes, ((0, b_pad), (0, n_pad), (0, 0)))
+    gt_t = jnp.pad(jnp.swapaxes(gt_boxes, 1, 2),
+                   ((0, b_pad), (0, 0), (0, m_pad)))
+    mask = jnp.pad(gt_mask, ((0, b_pad), (0, m_pad)))[:, None, :]
+    Bp, Np, Mp = B + b_pad, N + n_pad, M + m_pad
 
     out = pl.pallas_call(
         _best_iou_kernel,
-        out_shape=jax.ShapeDtypeStruct((B, Np), jnp.float32),
-        grid=(Np // TILE_N,),
+        out_shape=jax.ShapeDtypeStruct((Bp, Np), jnp.float32),
+        grid=(Bp // tile_b, Np // TILE_N),
         in_specs=[
-            pl.BlockSpec((B, TILE_N, 4), lambda n: (0, n, 0)),
-            pl.BlockSpec((B, 4, Mp), lambda n: (0, 0, 0)),
-            pl.BlockSpec((B, 1, Mp), lambda n: (0, 0, 0)),
+            pl.BlockSpec((tile_b, TILE_N, 4), lambda b, n: (b, n, 0)),
+            pl.BlockSpec((tile_b, 4, Mp), lambda b, n: (b, 0, 0)),
+            pl.BlockSpec((tile_b, 1, Mp), lambda b, n: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((B, TILE_N), lambda n: (0, n)),
+        out_specs=pl.BlockSpec((tile_b, TILE_N), lambda b, n: (b, n)),
         interpret=interpret,
     )(pred.astype(jnp.float32), gt_t.astype(jnp.float32),
       mask.astype(jnp.float32))
-    return out[:, :N]
+    return out[:B, :N]
 
 
 def best_iou_max_auto(pred_boxes, gt_boxes, gt_mask):
@@ -449,44 +469,27 @@ def best_iou_max_sharded(pred_boxes, gt_boxes, gt_mask, mesh):
 
     from deep_vision_tpu.parallel.mesh import DATA_AXIS
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-
     spec = P(DATA_AXIS)
-    try:
-        # pallas_call can't annotate varying-manual-axes on its outputs,
-        # so disable the VMA type check (sound here: no collectives inside,
-        # every input/output is batch-sharded the same way)
-        fn = shard_map(best_iou_max_auto, mesh=mesh,
-                       in_specs=(spec, spec, spec), out_specs=spec,
-                       check_vma=False)
-    except TypeError:  # older jax without check_vma
-        fn = shard_map(best_iou_max_auto, mesh=mesh,
-                       in_specs=(spec, spec, spec), out_specs=spec)
-    return fn(pred_boxes, gt_boxes, gt_mask)
+    # check_vma=False: see train_ingest_sharded
+    return jax.shard_map(best_iou_max_auto, mesh=mesh,
+                         in_specs=(spec, spec, spec), out_specs=spec,
+                         check_vma=False)(pred_boxes, gt_boxes, gt_mask)
 
 
-_PARITY_CACHE: dict[tuple, bool] = {}
+def best_iou_parity(batch: int = 2, n_pred: int = 600, n_gt: int = 100,
+                    tol: float = 1e-5, interpret: bool = False) -> float:
+    """Run ``best_iou_max`` on one seeded batch and compare it with the
+    XLA ``broadcast_iou(...).max(-1)`` path; returns the max absolute
+    error, cached per shape per process.
 
-
-def pallas_parity_ok(batch: int = 2, n_pred: int = 600, n_gt: int = 100,
-                     tol: float = 1e-5, interpret: bool = False) -> bool:
-    """One-batch parity check of the COMPILED kernel vs the XLA path.
-
-    The Mosaic compilation of ``best_iou_max`` (block shapes with lane dim 4
-    and full-batch sublane blocks) is environment- AND shape-sensitive, so
-    callers must gate on the exact (batch, n_pred, n_gt) shapes training
-    will use; results are cached per shape per process. A compile failure
-    or numeric divergence disables the Pallas path.
+    Mosaic's tiling and VMEM limits depend on the shape, so callers check
+    the exact (batch, n_pred, n_gt) training will compile.  Like the
+    ingest checks it selects nothing: a Mosaic error propagates and a
+    divergence beyond ``tol`` raises with its size.
     """
-    key = (batch, n_pred, n_gt)
-    if key in _PARITY_CACHE and not interpret:
-        return _PARITY_CACHE[key]
     from deep_vision_tpu.ops.boxes import broadcast_iou
 
-    try:
+    def run() -> float:
         rng = jax.random.PRNGKey(42)
         k1, k2, k3, k4, k5 = jax.random.split(rng, 5)
         p_xy = jax.random.uniform(k1, (batch, n_pred, 2))
@@ -501,16 +504,13 @@ def pallas_parity_ok(batch: int = 2, n_pred: int = 600, n_gt: int = 100,
             jnp.float32)
         got = best_iou_max(pred, gt, mask, interpret=interpret)
         iou = jnp.where(mask[:, None, :] > 0, broadcast_iou(pred, gt), 0.0)
-        want = iou.max(-1)
-        err = float(jax.device_get(jnp.abs(got - want).max()))
-        ok = err < tol
-        if not ok:
-            print(f"[pallas] parity check FAILED (max err {err:.2e}) — "
-                  "falling back to the XLA ignore-mask path")
-    except Exception as e:  # noqa: BLE001 — compile/runtime failure → XLA fallback
-        print(f"[pallas] kernel unavailable ({type(e).__name__}: {e}) — "
-              "falling back to the XLA ignore-mask path")
-        ok = False
-    if not interpret:
-        _PARITY_CACHE[key] = ok
-    return ok
+        err = float(jax.device_get(jnp.abs(got - iou.max(-1)).max()))
+        if not err < tol:
+            raise RuntimeError(
+                f"[pallas] best_iou_max (batch={batch}, n_pred={n_pred}, "
+                f"n_gt={n_gt}): max error {err:.2e} against the XLA "
+                f"ignore-mask path (allowed: {tol:.0e})")
+        return err
+
+    return _parity_once(("best_iou_max", batch, n_pred, n_gt), interpret,
+                        run)

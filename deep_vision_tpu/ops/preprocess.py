@@ -16,12 +16,15 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from deep_vision_tpu.data.mnist import MEAN as MNIST_MEAN
 from deep_vision_tpu.data.mnist import STD as MNIST_STD
 from deep_vision_tpu.data.transforms import IMAGENET_MEAN, IMAGENET_STD
 
-_GRAY = jnp.asarray([0.299, 0.587, 0.114])
+# NumPy, not jnp: a device array here would initialize the JAX backend —
+# and claim the chip — in every process that merely imports this module
+_GRAY = np.asarray([0.299, 0.587, 0.114], np.float32)
 
 #: normalization families the serving wire supports (docs/SERVING.md
 #: "Wire format & inference dtype"); "unit" is plain [0,1] scaling,
@@ -103,8 +106,8 @@ def make_int8_ingest(kind: str, wire_dtype, act_scale: float,
     A uint8 wire takes the FUSED path — decode + normalize + quantize in
     one VMEM pass (ops/pallas_ops.serve_ingest; interpret-mode off-TPU)
     so the wire bytes never materialize as an f32 HWC tensor in HBM —
-    unless ``use_pallas`` is False (the XLA fallback kept for parity
-    testing, or a failed on-TPU parity gate).  A float wire was
+    unless ``use_pallas`` is False (the XLA prologue, kept as the
+    reference the kernel is checked against).  A float wire was
     normalized by the client, so only the quantize runs.  The "gan"
     kind always takes the XLA path — the fused kernel's constant table
     (ops/pallas_ops._ingest_norm_constants) only bakes the mean/std
@@ -183,24 +186,25 @@ def make_imagenet_preprocess(brightness: float = 0.2, contrast: float = 0.2,
     """Trainer ``preprocess_fn``: applied to uint8 image batches inside the
     jitted step; float batches (host-normalized path) pass through.
 
-    With ``use_fused`` and a concrete ``fused_shape`` (the global
-    (B, H, W, C) train batch), the train-time jitter chain goes through
-    the fused Pallas ``train_ingest`` kernel instead of the multi-op XLA
-    ``jitter_normalize`` — but only after the one-batch parity gate for
-    that exact shape passes (ops/pallas_ops.train_ingest_parity_ok); a
-    failed gate or kernel compile silently selects XLA, never a silent
-    accuracy change.  On a multi-device ``mesh`` the kernel runs under
-    shard_map per batch shard with globally-drawn factors.  The eval
-    path is always the plain normalize (no jitter — nothing to fuse).
+    With ``use_fused`` and a concrete ``fused_shape`` (the per-shard
+    (B, H, W, C) train batch the step compiles), the train-time jitter
+    chain goes through the fused Pallas ``train_ingest`` kernel instead
+    of the multi-op XLA ``jitter_normalize``.  The kernel is first run
+    once at that exact shape against the XLA path
+    (ops/pallas_ops.train_ingest_parity): a kernel Mosaic refuses, or one
+    that diverges, stops the run with the reason — it never quietly
+    trains through the other path.  On a multi-device ``mesh`` the kernel
+    runs under shard_map per batch shard with globally-drawn factors.
+    The eval path is always the plain normalize (no jitter — nothing to
+    fuse).
     """
-    fused = False
-    if use_fused and fused_shape is not None:
-        from deep_vision_tpu.ops.pallas_ops import train_ingest_parity_ok
+    fused = bool(use_fused and fused_shape is not None)
+    if fused:
+        from deep_vision_tpu.ops.pallas_ops import train_ingest_parity
 
-        on_tpu = jax.default_backend() == "tpu"
-        fused = train_ingest_parity_ok(
+        train_ingest_parity(
             tuple(fused_shape), "imagenet", brightness, contrast,
-            saturation, interpret=not on_tpu)
+            saturation, interpret=jax.default_backend() != "tpu")
     multi = mesh is not None and mesh.devices.size > 1
 
     # dvtlint: hot
@@ -226,7 +230,7 @@ def make_imagenet_preprocess(brightness: float = 0.2, contrast: float = 0.2,
                 saturation=saturation)
         return out
 
-    fn.fused = fused  # introspectable: tests + CLI log which path won
+    fn.fused = fused  # introspectable: tests + CLI log which path runs
     return fn
 
 

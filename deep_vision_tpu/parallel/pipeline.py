@@ -40,32 +40,18 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from deep_vision_tpu.parallel.mesh import DATA_AXIS
-
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
 
 PIPE_AXIS = "pipe"
 
 
 def _pvary(x, axes=(PIPE_AXIS,)):
     """Mark ``x`` as varying over ``axes`` for shard_map's
-    varying-manual-axes (VMA) type check; no-op on JAX versions without
-    the check.  ``pcast(..., to="varying")`` is the current API (probed
-    first, guarded since its signature may still move); deprecated
-    ``pvary`` is the fallback for versions that predate it."""
-    if hasattr(jax.lax, "pcast"):
-        try:  # the current API (pvary is deprecated in its favor)
-            return jax.lax.pcast(x, tuple(axes), to="varying")
-        except TypeError:  # future signature drift: fall through
-            pass
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, tuple(axes))
-    return x
+    varying-manual-axes (VMA) type check."""
+    return jax.lax.pcast(x, tuple(axes), to="varying")
 
 # stage_fn(stage_params, carry, stage_state) -> (carry, out, stage_state)
 StageFn = Callable[[Any, jax.Array, Any], tuple[jax.Array, Any, Any]]

@@ -19,14 +19,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deep_vision_tpu.parallel.mesh import SPATIAL_AXIS  # single source
-
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
 
 
 def _same_pad(dim: int, k: int, s: int) -> tuple[int, int]:

@@ -510,23 +510,23 @@ class CheckpointServingModel(ServingModel):
             return post(out) if post is not None else out
 
         if self.infer_dtype == "int8":
-            # the fused Pallas ingest is the default on the uint8 wire;
-            # on real TPUs it must pass the per-shape parity gate first
-            # (Mosaic lowering is shape-sensitive), falling back to the
-            # XLA prologue — NEVER recompiling any other model's
-            # retained f32/bf16 bucket programs
-            act_scale = float(self.quant.act_scale)
-            # the fused kernel's constant table has no "gan" family —
+            # the fused Pallas ingest is the default on the uint8 wire.
+            # The fused kernel's constant table has no "gan" family —
             # GAN-kind ingest always takes the XLA prologue
+            act_scale = float(self.quant.act_scale)
             use_pallas = self.ingest == "pallas" and \
                 jnp.issubdtype(wire, jnp.integer) and \
                 self.preprocess_kind != "gan"
             if use_pallas and jax.default_backend() == "tpu":
-                from deep_vision_tpu.ops.pallas_ops import ingest_parity_ok
+                from deep_vision_tpu.ops.pallas_ops import (
+                    serve_ingest_parity,
+                )
 
-                use_pallas = ingest_parity_ok(
-                    (batch, *self.input_shape), self.preprocess_kind,
-                    act_scale)
+                # run the compiled kernel once at this bucket's shape
+                # against the reference prologue: a kernel Mosaic refuses
+                # or one that diverges fails the load with the reason
+                serve_ingest_parity((batch, *self.input_shape),
+                                    self.preprocess_kind, act_scale)
             self.ingest_path = "pallas" if use_pallas else "xla"
             pre_q = make_int8_ingest(self.preprocess_kind, wire,
                                      act_scale, use_pallas=use_pallas)
@@ -737,7 +737,7 @@ class ModelRegistry:
         (serve/quant.py) — ``calib_batches`` held-out batches from
         ``calib_dir`` (deterministic synthetic data when None) calibrate
         the activation scales, and ``ingest`` picks the fused Pallas
-        serve-prologue ("pallas", the default) or the XLA fallback.
+        serve-prologue ("pallas", the default) or the XLA prologue ("xla").
         ``cascade_topk`` > 0 marks a cascade FRONT tier: the classify
         workload fuses its confidence epilogue (softmax + top-K on
         device) into the bucket programs (serve/cascade.py).

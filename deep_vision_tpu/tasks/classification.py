@@ -14,21 +14,6 @@ import jax.numpy as jnp
 import optax
 
 
-@jax.custom_jvp
-def _barrier(x):
-    return jax.lax.optimization_barrier(x)
-
-
-@_barrier.defjvp
-def _barrier_jvp(primals, tangents):
-    # The barrier is an identity: pass the tangent straight through.
-    # jax.lax.optimization_barrier has no differentiation rule of its own
-    # on some JAX versions, which would otherwise make the training loss
-    # non-differentiable.
-    (x,), (t,) = primals, tangents
-    return jax.lax.optimization_barrier(x), t
-
-
 def _materialize(logits):
     """f32 logits behind an optimization barrier.
 
@@ -41,7 +26,7 @@ def _materialize(logits):
     hiding inside every fused eval loss).  The barrier forces the logits
     to materialize once, making both reductions read the same values.
     """
-    return _barrier(logits.astype(jnp.float32))
+    return jax.lax.optimization_barrier(logits.astype(jnp.float32))
 
 
 class ClassificationTask:

@@ -108,8 +108,10 @@ def main():
                 time.sleep(0.01)
             for name in monotone:
                 assert second[name][lab] > first[name][lab], name
-            mfu = second["dvt_serve_mfu"][lab]
-            assert 0 < mfu < 1, mfu
+            # this smoke runs on the CPU, which has no peak on record:
+            # FLOPs are counted, the MFU gauge stays absent
+            assert second["dvt_serve_flops_total"][lab] > 0
+            assert "dvt_serve_mfu" not in second, second["dvt_serve_mfu"]
             # -- the trace ring is served --
             traces = json.loads(_get(base, "/v1/traces?n=8")[2])
             assert any(t["request_id"] == rid for t in traces["traces"]), \
@@ -139,7 +141,7 @@ def main():
                   f"({covered / max(trace['total_ms'], 1e-9):.1%}), "
                   f"serve+gateway /metrics parsed "
                   f"({len(second)}+{len(gsamples)} series), "
-                  f"serving_mfu {mfu:.3g}, id {grid} propagated "
+                  f"no MFU gauge off-chip, id {grid} propagated "
                   f"gateway -> backend ring")
         finally:
             if gsrv is not None:

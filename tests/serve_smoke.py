@@ -129,9 +129,8 @@ def main():
     opts = p.parse_args()
     if opts.multi:
         # 2 fake host devices, depth 2, fault-injected: the replica
-        # wiring end to end.  The platform pin must land before the jax
-        # backend initializes (env JAX_PLATFORMS alone can be overridden
-        # by site config, so pin at the config level too).
+        # wiring end to end.  Smokes run on the CPU; the platform pin
+        # must land before the jax backend initializes.
         os.environ.setdefault(
             "XLA_FLAGS", "--xla_force_host_platform_device_count=2")
         import jax

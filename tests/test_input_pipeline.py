@@ -182,10 +182,13 @@ def test_train_ingest_interpret_parity():
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
-def test_train_ingest_parity_gate_and_fallback(monkeypatch):
-    """The preprocess factory selects the fused kernel only when the
-    one-batch parity gate passes; a failing gate silently selects the
-    XLA path (no accuracy change either way)."""
+def test_train_ingest_parity_check_and_selection(monkeypatch):
+    """The preprocess factory runs the one-batch parity check before it
+    bakes the fused kernel in; the XLA path is what ``use_fused=False``
+    asks for, never what a failed check falls back to — a kernel that
+    diverges raises with the error size."""
+    import pytest
+
     from deep_vision_tpu.ops import pallas_ops
     from deep_vision_tpu.ops.preprocess import (
         jitter_normalize,
@@ -193,7 +196,7 @@ def test_train_ingest_parity_gate_and_fallback(monkeypatch):
     )
 
     shape = (4, 16, 16, 3)
-    assert pallas_ops.train_ingest_parity_ok(shape, interpret=True)
+    assert pallas_ops.train_ingest_parity(shape, interpret=True) <= 1e-4
 
     fn = make_imagenet_preprocess(use_fused=True, fused_shape=shape)
     assert fn.fused
@@ -204,12 +207,16 @@ def test_train_ingest_parity_gate_and_fallback(monkeypatch):
     want = jitter_normalize(x, rng, train=True)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
-    monkeypatch.setattr(pallas_ops, "train_ingest_parity_ok",
-                        lambda *a, **k: False)
-    fb = make_imagenet_preprocess(use_fused=True, fused_shape=shape)
+    fb = make_imagenet_preprocess(use_fused=False)
     assert not fb.fused
     np.testing.assert_allclose(fb({"image": x}, rng, train=True)["image"],
                                want, rtol=1e-6, atol=1e-7)
+
+    monkeypatch.setattr(pallas_ops, "train_ingest",
+                        lambda x, factors, kind, interpret=False:
+                        jnp.zeros(x.shape, jnp.float32))
+    with pytest.raises(RuntimeError, match="max error"):
+        make_imagenet_preprocess(use_fused=True, fused_shape=shape)
 
     # float batches pass through untouched on both paths
     xf = jnp.ones(shape, jnp.float32)
@@ -220,10 +227,9 @@ def test_train_ingest_parity_gate_and_fallback(monkeypatch):
 
 
 class _PlainXentTask:
-    """Barrier-free classification task: this environment's jax build has
-    no differentiation rule for ``optimization_barrier`` (the pre-existing
-    test_trainer_mnist failures), so the wire-parity test supplies the
-    same cross-entropy math without ``_materialize``."""
+    """Plain cross-entropy task for the wire-parity test: the same math
+    as ``ClassificationTask`` without the logits barrier, so both wires
+    compile to the simplest comparable program."""
 
     monitor = "top1"
 

@@ -212,6 +212,22 @@ def test_mfu_meter_arithmetic():
         m.report()["serving_mfu"])
 
 
+def test_unknown_device_has_no_peak():
+    """A device outside the table is an error from the lookup and a None
+    from the meter — never another chip's peak."""
+    from deep_vision_tpu.obs.mfu import peak_tflops
+
+    assert peak_tflops("TPU v5 lite") == 197.0
+    with pytest.raises(LookupError, match="cpu"):
+        peak_tflops("cpu")
+    m = MfuMeter()  # resolves against this process's CPU backend
+    m.set_bucket_flops(8, 50.0, "xla_cost_analysis")
+    m.observe(8, images=8, compute_s=1.0)
+    r = m.report()
+    assert r["serving_mfu"] is None and r["peak_flops_per_s"] is None
+    assert r["flops_total"] == 50.0
+
+
 # -- engine span plumbing ---------------------------------------------------
 
 def test_engine_trace_normal_request_stages(lenet_serving):
@@ -284,8 +300,11 @@ def test_engine_serving_mfu_sane_under_load(lenet_serving):
                 assert f.result(60) is not None
         stats = eng.stats()
     mfu = stats["mfu"]
-    assert mfu["serving_mfu"] is not None
-    assert 0 < mfu["serving_mfu"] < 1
+    # the CPU is not in the peak table: FLOPs and seconds are counted,
+    # but nothing divides them by another chip's rate
+    assert mfu["serving_mfu"] is None
+    assert mfu["peak_flops_per_s"] is None
+    assert mfu["flops_total"] > 0
     assert mfu["compute_s"] > 0
     assert mfu["flops_source"] in ("xla_cost_analysis",
                                    "params_lower_bound")
@@ -367,11 +386,13 @@ def test_http_metrics_parse_and_monotonic(serve_stack):
     for name in ("dvt_serve_requests_submitted_total",
                  "dvt_serve_requests_served_total",
                  "dvt_serve_batches_total", "dvt_serve_up",
-                 "dvt_serve_mfu", "dvt_serve_compute_seconds_total",
+                 "dvt_serve_flops_total", "dvt_serve_compute_seconds_total",
                  "dvt_serve_traces_finished_total"):
         assert lab in first[name], f"{name} missing model label"
     assert first["dvt_serve_up"][lab] == 1
-    assert 0 < first["dvt_serve_mfu"][lab] < 1
+    assert first["dvt_serve_flops_total"][lab] > 0
+    # no peak on record for the CPU → the MFU gauge is absent, not made up
+    assert "dvt_serve_mfu" not in first
     assert frozenset({("model", "lenet5"), ("le", "+Inf")}) in \
         first["dvt_serve_request_latency_seconds_bucket"]
     _classify(base)
@@ -483,8 +504,10 @@ def test_gateway_stats_merge_and_metrics(lenet_serving):
         g = stats["gateway"]
         assert g["backend_latency_hist"]["total"] == expect.total >= 10
         assert g["backend_latency"] == expect.percentiles()
-        assert g["mfu"]["serving_mfu"] is not None
-        assert 0 < g["mfu"]["serving_mfu"] < 1
+        # CPU backends report no peak, so the fleet has no MFU either —
+        # the summed numerator and denominator are still there
+        assert g["mfu"]["serving_mfu"] is None
+        assert g["mfu"]["flops_total"] > 0 and g["mfu"]["compute_s"] > 0
         assert g["latency"]["count"] >= 10  # gateway-side histogram
         # both backends saw probes; at least one served traffic
         assert set(stats["backends"]) == {b.name for b in gw.backends}
@@ -493,7 +516,7 @@ def test_gateway_stats_merge_and_metrics(lenet_serving):
         assert samples["dvt_gateway_proxied_total"][frozenset()] >= 10
         assert samples["dvt_gateway_routable_backends"][
             frozenset()] == 2
-        assert 0 < samples["dvt_gateway_serving_mfu"][frozenset()] < 1
+        assert "dvt_gateway_serving_mfu" not in samples
         assert frozenset({("le", "+Inf")}) in \
             samples["dvt_gateway_request_latency_seconds_bucket"]
         for b in gw.backends:

@@ -40,10 +40,13 @@ def test_best_iou_max_all_masked_is_zero():
 
 
 def test_parity_check_passes_interpret():
-    """The startup gate the CLI uses before enabling the Pallas path."""
-    from deep_vision_tpu.ops.pallas_ops import pallas_parity_ok
+    """The startup check the CLI runs before baking the Pallas path in —
+    including a batch that is not a whole number of TILE_B tiles."""
+    from deep_vision_tpu.ops.pallas_ops import best_iou_parity
 
-    assert pallas_parity_ok(interpret=True)
+    assert best_iou_parity(interpret=True) < 1e-5
+    assert best_iou_parity(batch=12, n_pred=300, n_gt=20,
+                           interpret=True) < 1e-5
 
 
 def test_best_iou_max_sharded_matches_reference(mesh8):
@@ -65,3 +68,38 @@ def test_best_iou_max_sharded_matches_reference(mesh8):
     want = _reference(jnp.asarray(pred), jnp.asarray(gt), jnp.asarray(mask))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-6)
+
+
+def test_kernels_lower_for_tpu_at_zoo_shapes():
+    """Mosaic lowering needs no chip: exporting for the TPU platform runs
+    the Pallas→Mosaic lowering rules on the CPU host, so an op Mosaic has
+    no rule for (the uint8→float32 cast both ingest kernels once used)
+    fails here and not on the first chip run.  Shapes are the ones
+    chip_smoke.py compiles: every default ``cli.serve`` bucket, the
+    ResNet-50 train batch per shard on one and four chips, and the three
+    YOLO scales at the ``yolov3_voc`` / ``yolov3_coco`` batches."""
+    import functools
+
+    import jax
+
+    from deep_vision_tpu.ops import pallas_ops
+    from deep_vision_tpu.tasks.detection import MAX_BOXES
+
+    S = jax.ShapeDtypeStruct
+
+    def lower(fn, *specs):
+        jax.export.export(jax.jit(fn), platforms=["tpu"])(*specs)
+
+    for b in (1, 2, 4, 8, 16, 32):
+        lower(functools.partial(pallas_ops.serve_ingest, kind="imagenet",
+                                act_scale=0.02),
+              S((b, 224, 224, 3), jnp.uint8))
+    for b in (256, 64):
+        lower(functools.partial(pallas_ops.train_ingest, kind="imagenet"),
+              S((b, 224, 224, 3), jnp.uint8), S((b, 4), jnp.float32))
+    for b in (16, 128):
+        for s in (8, 16, 32):
+            n = 3 * (416 // s) ** 2
+            lower(pallas_ops.best_iou_max, S((b, n, 4), jnp.float32),
+                  S((b, MAX_BOXES, 4), jnp.float32),
+                  S((b, MAX_BOXES), jnp.float32))

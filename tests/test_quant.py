@@ -204,7 +204,7 @@ def test_ingest_decode_normalize_parity():
 def test_ingest_quantize_matches_xla_prologue():
     """The fused kernel's int8 activations agree with the two-op XLA
     path (serve_normalize → quantize_activations) to ≤ 1 step — the
-    same bar ingest_parity_ok holds the compiled kernel to on TPU."""
+    same bar serve_ingest_parity holds the compiled kernel to on TPU."""
     import jax.numpy as jnp
 
     from deep_vision_tpu.ops.pallas_ops import serve_ingest
@@ -225,13 +225,13 @@ def test_ingest_quantize_matches_xla_prologue():
                          - ref.astype(np.int32))) <= 1
 
 
-def test_ingest_parity_gate():
-    from deep_vision_tpu.ops.pallas_ops import ingest_parity_ok
+def test_ingest_parity_check():
+    from deep_vision_tpu.ops.pallas_ops import serve_ingest_parity
 
-    assert ingest_parity_ok((8, 32, 32, 1), "mnist", 2.8 / 127.0,
-                            interpret=True)
-    assert ingest_parity_ok((2, 8, 8, 3), "imagenet", 3.1 / 127.0,
-                            interpret=True)
+    assert serve_ingest_parity((8, 32, 32, 1), "mnist", 2.8 / 127.0,
+                               interpret=True) <= 1
+    assert serve_ingest_parity((2, 8, 8, 3), "imagenet", 3.1 / 127.0,
+                               interpret=True) <= 1
 
 
 # -- the int8 serving path end to end --------------------------------------

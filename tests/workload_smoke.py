@@ -270,8 +270,7 @@ def smoke():
 
 
 def main():
-    # pin the platform before jax initializes (site config can override
-    # the env var alone, so set it at the config level too)
+    # smokes run on the CPU: pin the platform before jax initializes
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
 
