@@ -23,6 +23,7 @@ TPU mapping:
 from __future__ import annotations
 
 import functools
+import json
 import os
 import time
 from typing import Any, Iterable
@@ -54,6 +55,13 @@ def install_sigterm_flag(on_sigterm):
         return lambda: None
     restore_to = prev if prev is not None else signal.SIG_DFL
     return lambda: signal.signal(signal.SIGTERM, restore_to)
+
+
+def _clock_pair() -> tuple[int, int]:
+    """The spans' clock beside the trace's: ``Span`` marks read
+    ``time.monotonic``, a profiler trace dates itself in ns since the
+    epoch."""
+    return time.monotonic_ns(), time.time_ns()
 
 
 class Trainer:
@@ -245,16 +253,21 @@ class Trainer:
                 variables = {"params": params}
                 if has_bn:
                     variables["batch_stats"] = batch_stats
-                out = apply_fn(
-                    variables, batch["image"], train=True,
-                    rngs={"dropout": dropout_rng},
-                    mutable=["batch_stats"] if has_bn else False)
+                # scopes name the step's phases in a device trace: what
+                # runs under these two comes out as jvp(forward) and
+                # jvp(loss), their gradients as transpose(jvp(...))
+                with jax.named_scope("forward"):
+                    out = apply_fn(
+                        variables, batch["image"], train=True,
+                        rngs={"dropout": dropout_rng},
+                        mutable=["batch_stats"] if has_bn else False)
                 if has_bn:
                     out, new_vars = out
                     new_bs = new_vars["batch_stats"]
                 else:
                     new_bs = batch_stats
-                loss, aux = task.loss(out, batch)
+                with jax.named_scope("loss"):
+                    loss, aux = task.loss(out, batch)
                 return loss, (new_bs, aux)
 
             (loss, (new_bs, aux)), grads = jax.value_and_grad(
@@ -264,8 +277,9 @@ class Trainer:
         def train_step(state: TrainState, batch: dict):
             step_rng = jax.random.fold_in(state.rng, state.step)
             if preprocess_fn is not None:
-                batch = preprocess_fn(
-                    batch, jax.random.fold_in(step_rng, 1), train=True)
+                with jax.named_scope("prologue"):
+                    batch = preprocess_fn(
+                        batch, jax.random.fold_in(step_rng, 1), train=True)
 
             if accum == 1:
                 loss, new_bs, aux, grads = grad_one(
@@ -317,21 +331,23 @@ class Trainer:
             # applied) and counted; the epoch loop halts past
             # config.max_bad_steps (reference context: the NaN val losses
             # Hourglass/tensorflow/train.py:126-130 only TODO'd about)
-            new_state = state.apply_gradients_if_finite(
-                loss, grads, batch_stats=new_bs)
-            if ema_decay:
-                # guard-aware: a skipped step reverted params, so the EMA
-                # merely re-averages toward the unchanged weights.
-                # Warmup (tf.train.ExponentialMovingAverage num_updates /
-                # timm ModelEmaV2 semantics): the effective decay ramps as
-                # min(d, (1+t)/(10+t)) so short or freshly-seeded runs
-                # aren't dominated by the seed point at high decays.
-                t = new_state.step.astype(jnp.float32)
-                d = jnp.minimum(ema_decay, (1.0 + t) / (10.0 + t))
-                new_state = new_state.replace(
-                    ema_params=jax.tree_util.tree_map(
-                        lambda e, p: d * e + (1 - d) * p,
-                        new_state.ema_params, new_state.params))
+            with jax.named_scope("optimizer"):
+                new_state = state.apply_gradients_if_finite(
+                    loss, grads, batch_stats=new_bs)
+                if ema_decay:
+                    # guard-aware: a skipped step reverted params, so the
+                    # EMA merely re-averages toward the unchanged weights.
+                    # Warmup (tf.train.ExponentialMovingAverage num_updates
+                    # / timm ModelEmaV2 semantics): the effective decay
+                    # ramps as min(d, (1+t)/(10+t)) so short or
+                    # freshly-seeded runs aren't dominated by the seed
+                    # point at high decays.
+                    t = new_state.step.astype(jnp.float32)
+                    d = jnp.minimum(ema_decay, (1.0 + t) / (10.0 + t))
+                    new_state = new_state.replace(
+                        ema_params=jax.tree_util.tree_map(
+                            lambda e, p: d * e + (1 - d) * p,
+                            new_state.ema_params, new_state.params))
             metrics = {"loss": loss, "bad_steps": new_state.bad_steps, **aux}
             return new_state, metrics
 
@@ -484,6 +500,40 @@ class Trainer:
               f"(pool alloc {stats['pool']['allocated']} "
               f"reuse {stats['pool']['reused']})", flush=True)
 
+    def _start_trace(self):
+        """Device operations and Python frames, the host runtime's own
+        events left out: with them the H2D linearize thread alone writes
+        4.6 M events for a dozen steps and each transfer takes eight to ten
+        times as long, so the traced steps would measure the tracer
+        (PERF.md §6, PR 25)."""
+        options = jax.profiler.ProfileOptions()
+        options.host_tracer_level = 0
+        jax.profiler.start_trace(os.path.join(self.workdir, "profile"),
+                                 profiler_options=options)
+
+    def _write_spans(self, stream, clock: list):
+        """``workdir/spans.jsonl`` for a profiled epoch: a header, then one
+        line per interval of the prefetcher's producer and of this loop,
+        in ``time.time_ns`` terms.  A trace's ``Task Environment`` plane
+        gives its start on that clock, so a reader can lay these on the
+        device's timeline (docs/OBSERVABILITY.md has the schema)."""
+        t0 = time.perf_counter()
+        stats = stream.stats()
+        mono_ns, wall_ns = clock[0]
+        path = os.path.join(self.workdir, "spans.jsonl")
+        with open(path, "w") as f:
+            f.write(json.dumps({
+                "clock": clock, "profile_steps": list(self.profile_steps),
+                "depth": stream.depth, "batches": stats["batches"],
+                "h2d_bytes": stats["h2d_bytes"]}) + "\n")
+            for thread, stage, batch, a, b in stream.intervals():
+                f.write(json.dumps({
+                    "thread": thread, "stage": stage, "batch": batch,
+                    "t0_ns": round(a * 1e9) - mono_ns + wall_ns,
+                    "t1_ns": round(b * 1e9) - mono_ns + wall_ns}) + "\n")
+        print(f"[profile] spans written to {path} in "
+              f"{(time.perf_counter() - t0) * 1e3:.1f} ms", flush=True)
+
     def train_epoch(self, state: TrainState, train_data: Iterable,
                     epoch: int) -> TrainState:
         cfg = self.config
@@ -493,6 +543,7 @@ class Trainer:
         pending = None  # async metric fetch: log step N-1 while N runs
         profiling = self.profile_steps if epoch == self.start_epoch else None
         trace_active = False
+        clock = []  # _clock_pair() as the trace starts and as it stops
         # staged input pipeline: batch N+1 assembles/stages/transfers on
         # the producer thread while step N computes; the stream yields
         # already-placed device batches (shard_batch in train_step is a
@@ -501,26 +552,36 @@ class Trainer:
         for i, batch in enumerate(stream):
             if profiling is not None:
                 if i == profiling[0]:
-                    jax.profiler.start_trace(
-                        os.path.join(self.workdir, "profile"))
+                    clock.append(_clock_pair())
+                    self._start_trace()
+                    stream.mark("profile")
                     trace_active = True
                 elif i == profiling[1]:
                     jax.profiler.stop_trace()
+                    clock.append(_clock_pair())
+                    stream.mark("profile")
                     trace_active = False
                     print(f"[profile] trace written to "
                           f"{self.workdir}/profile", flush=True)
                     profiling = None
             bs = len(jax.tree_util.tree_leaves(batch)[0])
             state, metrics = self.train_step(state, batch)
+            # the consumer's span, split where the loop can wait: the
+            # jitted call's return, the read of the last step's metrics
+            # (the host's wait for the device), the guard and the logger;
+            # the rest of the iteration closes as "step" at the next dequeue
+            stream.mark("dispatch")
             meter.update(bs)
             if pending is not None and (i % cfg.log_every_steps == 0):
                 m = {k: float(v) for k, v in jax.device_get(pending).items()}
+                stream.mark("fetch")
                 self.guard.check(m)
                 self.logger.log_dict(int(state.step) - 1,
                                      {f"train_{k}": v for k, v in m.items()})
                 print(f"Epoch {epoch} Batch {i} loss {m['loss']:.4f} "
                       f"lr {self.scheduler.lr:.2e} "
                       f"{meter.images_per_sec:.1f} img/s", flush=True)
+                stream.mark("log")
             pending = metrics
             if self._preempted:
                 print("[preempt] SIGTERM — stopping at step boundary",
@@ -529,6 +590,7 @@ class Trainer:
         if trace_active:
             # epoch ended inside the trace window: flush what we have
             jax.profiler.stop_trace()
+            clock.append(_clock_pair())
             print(f"[profile] short-epoch trace written to "
                   f"{self.workdir}/profile", flush=True)
         if pending is not None:
@@ -536,6 +598,8 @@ class Trainer:
             self.guard.check(m)
             self.logger.log_dict(int(state.step),
                                  {f"train_{k}": v for k, v in m.items()})
+        if clock:
+            self._write_spans(stream, clock)
         self.logger.log("images_per_sec", int(state.step), meter.images_per_sec)
         self._log_input_stats(int(state.step), stream.stats(), epoch)
         return state

@@ -15,17 +15,24 @@ copies on H2D, one more when the CPU runtime zero-copies and release is
 deferred to the device array's GC; reused forever either way),
 ``h2d`` issues the sharded ``device_put`` and waits for the transfer, and
 ``enqueue`` hands the *device* batch to the bounded queue.  The consumer
-side records two stages — ``stall`` (time the train loop waited on the
-queue: input-bound) and ``step`` (time between dequeues: compute-bound) —
-in the :class:`deep_vision_tpu.obs.trace.Span` style, so each side's
-stages sum exactly to its wall time by construction and
+side marks its own span, in the :class:`deep_vision_tpu.obs.trace.Span`
+style: ``stall`` (the wait on the queue, marked here at each dequeue) and
+whatever the loop marks through :meth:`_EpochStream.mark` between two
+dequeues (the trainer: ``dispatch``, ``fetch``, ``log``), with ``step``
+closing what is left at the next dequeue.  Each side's stages sum exactly
+to its wall time by construction, and
 
-    input_stall_frac = stall / (stall + step)
+    input_stall_frac = stall / (stall + everything else)
 
-is the honest "how much of the epoch was spent waiting on input" number
-(docs/PERF.md "Input pipeline").  H2D traffic is accounted per batch key
-(``h2d_bytes_by_key``) so the uint8-vs-float32 wire ratio is measured on
-the image tensor alone, not diluted by labels.
+is the share of the HOST LOOP's time parked on the queue.  It is not the
+share of the epoch the chip waited for input: under async dispatch the
+loop runs ahead of the device and the queue is where it parks, so the
+number reads 17-25% while the device is 99% busy (PERF.md §6, PR 25).
+What the chip waited for is read from a trace, with the intervals of both
+spans (:meth:`_EpochStream.intervals`) laid on its clock
+(``Trainer.profile_steps`` writes them to ``spans.jsonl``).  H2D traffic is
+accounted per batch key (``h2d_bytes_by_key``) so the uint8-vs-float32
+wire ratio is measured on the image tensor alone, not diluted by labels.
 
 Unlike the legacy generator, an epoch here is abandonable: ``close()``
 (called from ``Trainer.fit``'s finally path, and from the legacy shim's
@@ -107,8 +114,8 @@ class _EpochStream:
     """One epoch's staged batch stream (created by ``DevicePrefetcher.iterate``).
 
     Producer thread owns ``_pspan`` (prep_wait/assemble/h2d/enqueue marks),
-    the consumer owns ``_cspan`` (stall/step) — the Span ownership rule, so
-    neither side's marks race the other's.
+    the consumer owns ``_cspan`` (stall, the stages its loop marks, step) —
+    the Span ownership rule, so neither side's marks race the other's.
     """
 
     def __init__(self, mesh, iterable: Iterable, depth: int,
@@ -267,6 +274,11 @@ class _EpochStream:
         self.batches += 1
         return item
 
+    def mark(self, stage: str):
+        """Close a segment of the consumer's span under the loop's own
+        name for it; called from the consuming thread, between dequeues."""
+        self._cspan.mark(stage)
+
     def close(self):
         """Stop the producer, drain pinned device batches, join the thread.
 
@@ -285,12 +297,30 @@ class _EpochStream:
     def alive(self) -> bool:
         return self._thread.is_alive()
 
+    def intervals(self) -> list[tuple[str, str, int, float, float]]:
+        """``(thread, stage, batch, t0, t1)`` of both spans, seconds on
+        ``time.monotonic``.  ``batch`` is the number in the epoch of the
+        batch the thread was on, the identifier the two threads share: the
+        ordinal of the stage that opens a batch on that thread."""
+        out = []
+        for thread, span, opens in (("producer", self._pspan, "prep_wait"),
+                                    ("consumer", self._cspan, "stall")):
+            batch = 0
+            for stage, n, t0, t1 in span.intervals():
+                if stage == opens:
+                    batch = n
+                out.append((thread, stage, batch, t0, t1))
+        return out
+
     def stats(self) -> dict:
-        """Per-epoch input-goodput block (the trainer logs this verbatim)."""
+        """Per-epoch input block (the trainer logs this verbatim).
+        ``step_ms`` is every consumer stage but ``stall``;
+        ``input_stall_frac`` is the host loop's share parked on the queue,
+        which is high while the chip is busy (module docstring)."""
         prod = self._pspan.to_dict()["stages"]
         cons = self._cspan.to_dict()["stages"]
         stall_ms = cons.get("stall", 0.0)
-        step_ms = cons.get("step", 0.0)
+        step_ms = sum(cons.values()) - stall_ms
         wall_ms = stall_ms + step_ms
         n = max(1, self.batches)
         return {

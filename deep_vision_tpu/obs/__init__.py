@@ -1,14 +1,18 @@
-"""Serving observability: per-request tracing, structured logging, MFU.
+"""Observability: spans, structured logging, MFU.
 
-Three small, dependency-free pieces that the serving stack
-(``deep_vision_tpu/serve``) threads through every layer — batcher,
+Three small, dependency-free pieces.  The serving stack
+(``deep_vision_tpu/serve``) threads them through every layer — batcher,
 drainer, router, watchdog, prober — without perturbing the clean hot
 path (the same discipline as ``faults.py``: one ``enabled``/``is None``
-read guards every touch point):
+read guards every touch point); the train loop keeps two ``Span``s an
+epoch (``data/pipeline.py``: the prefetcher's producer and the loop
+itself) and writes their intervals beside a profiler trace
+(``Trainer.profile_steps`` → ``spans.jsonl``):
 
-    trace.py  ``Span`` (per-request stage timestamps + hop notes) and
-              ``Tracer`` (bounded in-memory ring of recent traces, a
-              slow-request JSONL sampler, per-stage aggregate sums).
+    trace.py  ``Span`` (stage timestamps + hop notes, read back as a
+              breakdown or as intervals) and ``Tracer`` (bounded
+              in-memory ring of recent traces, a slow-request JSONL
+              sampler, per-stage aggregate sums).
               Request ids arrive at the edge (``X-DVT-Request-Id``,
               generated at gateway or backend, propagated via header);
               ``?debug=1`` echoes a request's own breakdown.

@@ -1,4 +1,5 @@
-"""Per-request spans + the process-wide trace ring and slow sampler.
+"""Spans (a served request's, a train epoch's two threads') + the
+process-wide trace ring and slow sampler.
 
 A ``Span`` is an append-only list of ``(stage, monotonic_ts)`` marks
 plus ``(event, detail, ts)`` notes.  The first mark is the origin; each
@@ -76,6 +77,21 @@ class Span:
     @property
     def total_s(self) -> float:
         return self.marks[-1][1] - self.marks[0][1]
+
+    def intervals(self) -> list[tuple[str, int, float, float]]:
+        """``(stage, ordinal, t0, t1)`` per segment, in mark order, on the
+        marks' own clock: they tile the span, each starting where the one
+        before ended.  The ordinal of a stage is its count so far, so a
+        stage marked once per item carries the item's number."""
+        out, seen = [], {}
+        marks = list(self.marks)
+        prev = marks[0][1]
+        for stage, t in marks[1:]:
+            n = seen.get(stage, 0)
+            seen[stage] = n + 1
+            out.append((stage, n, prev, t))
+            prev = t
+        return out
 
     def to_dict(self) -> dict:
         marks = list(self.marks)

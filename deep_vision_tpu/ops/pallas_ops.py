@@ -139,6 +139,7 @@ def serve_ingest(x, kind: str, act_scale: float = 1.0,
         out_specs=pl.BlockSpec((INGEST_TILE_R, lanes_p),
                                lambda r: (r, 0)),
         interpret=interpret,
+        name="serve_ingest",
     )(x2, jnp.asarray(mean_row, jnp.float32),
       jnp.asarray(std_row, jnp.float32))
     return out[:rows, :lanes].reshape(B, H, W, C)
@@ -313,6 +314,7 @@ def train_ingest(x, factors, kind: str = "imagenet",
         out_specs=pl.BlockSpec((INGEST_TILE_R, lanes_p),
                                lambda r: (r, 0)),
         interpret=interpret,
+        name="train_ingest",
     )(x2, s_rows, jnp.asarray(mean_row, jnp.float32),
       jnp.asarray(std_row, jnp.float32),
       jnp.asarray(_gray_matrix(W, C, l_pad)))
@@ -444,6 +446,7 @@ def best_iou_max(pred_boxes, gt_boxes, gt_mask, interpret: bool = False):
         ],
         out_specs=pl.BlockSpec((tile_b, TILE_N), lambda b, n: (b, n)),
         interpret=interpret,
+        name="best_iou_max",
     )(pred.astype(jnp.float32), gt_t.astype(jnp.float32),
       mask.astype(jnp.float32))
     return out[:B, :N]

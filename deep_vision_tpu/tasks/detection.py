@@ -103,27 +103,30 @@ def yolo_scale_loss(raw, y_true, gt_boxes, gt_mask, anchors_wh,
     # to linearize under value_and_grad.
     B, G = raw.shape[0], raw.shape[1]
     flat_pred = jax.lax.stop_gradient(pred_corners.reshape(B, -1, 4))
-    if use_pallas:
-        # fused tiled kernel (ops/pallas_ops.py) — avoids the (B,N,M) HBM
-        # intermediate.  pallas_call has no GSPMD partitioning rule, so a
-        # sharded mesh routes through a shard_map over the data axis (the
-        # reduction is per-image independent); single-device calls the
-        # kernel directly.
-        from deep_vision_tpu.ops.pallas_ops import (
-            best_iou_max_auto,
-            best_iou_max_sharded,
-        )
+    # one scope over both implementations, so a trace reads the same work
+    # under the same name whichever runs
+    with jax.named_scope("best_iou"):
+        if use_pallas:
+            # fused tiled kernel (ops/pallas_ops.py) — avoids the (B,N,M)
+            # HBM intermediate.  pallas_call has no GSPMD partitioning
+            # rule, so a sharded mesh routes through a shard_map over the
+            # data axis (the reduction is per-image independent);
+            # single-device calls the kernel directly.
+            from deep_vision_tpu.ops.pallas_ops import (
+                best_iou_max_auto,
+                best_iou_max_sharded,
+            )
 
-        if mesh is not None and mesh.devices.size > 1:
-            best_iou = best_iou_max_sharded(
-                flat_pred, gt_boxes, gt_mask, mesh).reshape(obj.shape)
+            if mesh is not None and mesh.devices.size > 1:
+                best_iou = best_iou_max_sharded(
+                    flat_pred, gt_boxes, gt_mask, mesh).reshape(obj.shape)
+            else:
+                best_iou = best_iou_max_auto(flat_pred, gt_boxes,
+                                             gt_mask).reshape(obj.shape)
         else:
-            best_iou = best_iou_max_auto(flat_pred, gt_boxes,
-                                         gt_mask).reshape(obj.shape)
-    else:
-        iou = broadcast_iou(flat_pred, gt_boxes)           # (B, N, M)
-        iou = jnp.where(gt_mask[:, None, :] > 0, iou, 0.0)
-        best_iou = iou.max(-1).reshape(obj.shape)
+            iou = broadcast_iou(flat_pred, gt_boxes)           # (B, N, M)
+            iou = jnp.where(gt_mask[:, None, :] > 0, iou, 0.0)
+            best_iou = iou.max(-1).reshape(obj.shape)
     ignore = (best_iou < ignore_thresh).astype(jnp.float32)
 
     obj_entropy = _bce(raw[..., 4:5], true_obj, from_probs=False)[..., 0]

@@ -99,6 +99,58 @@ def test_stage_timers_sum_to_wall(mesh1):
     assert st["h2d_bytes_per_step"] > 0
 
 
+def test_consumer_marks_keep_the_sums_and_share_batch_numbers(mesh1):
+    """A loop that marks its own stages (as ``Trainer.train_epoch`` does)
+    changes no number: ``stall_ms + step_ms`` is still the wall time,
+    ``step_ms`` is every stage but ``stall``, and ``input_stall_frac`` is
+    what an unmarked loop with the same waits reads.  The intervals of
+    both threads carry the batch's number in the epoch."""
+    import time
+
+    def epoch(marked: bool):
+        pf = DevicePrefetcher(mesh1, depth=2)
+        try:
+            t0 = time.perf_counter()
+            stream = pf.iterate(_batches(5))
+            for i, b in enumerate(stream):
+                jax.block_until_ready(b["image"])
+                time.sleep(0.02)
+                if marked:
+                    stream.mark("dispatch")
+                time.sleep(0.02)
+                if marked and i % 2 == 0:
+                    stream.mark("fetch")
+                    stream.mark("log")
+            wall_ms = (time.perf_counter() - t0) * 1e3
+            return stream.stats(), stream.intervals(), wall_ms
+        finally:
+            pf.close()
+
+    plain, _, _ = epoch(marked=False)
+    st, ivs, wall_ms = epoch(marked=True)
+    assert st["batches"] == 5
+    assert st["stall_ms"] + st["step_ms"] == pytest.approx(wall_ms, abs=60)
+    assert st["step_ms"] >= 5 * 40 - 1
+    assert st["input_stall_frac"] == pytest.approx(
+        st["stall_ms"] / (st["stall_ms"] + st["step_ms"]))
+    assert st["input_stall_frac"] == pytest.approx(
+        plain["input_stall_frac"], abs=0.15)
+    cons = [iv for iv in ivs if iv[0] == "consumer"]
+    prod = [iv for iv in ivs if iv[0] == "producer"]
+    assert {s for _, s, *_ in cons} == {"stall", "dispatch", "fetch", "log",
+                                        "step"}
+    assert {s for _, s, *_ in prod} == {"prep_wait", "assemble", "h2d",
+                                        "enqueue"}
+    # each thread's intervals tile its span; the batch number is shared
+    for thread in (cons, prod):
+        assert all(a[4] == b[3] for a, b in zip(thread, thread[1:]))
+    assert [b for _, s, b, *_ in cons if s == "dispatch"] == [0, 1, 2, 3, 4]
+    assert [b for _, s, b, *_ in cons if s == "fetch"] == [0, 2, 4]
+    assert [b for _, s, b, *_ in prod if s == "h2d"] == [0, 1, 2, 3, 4]
+    # the last dequeue finds the end of the stream, not a batch
+    assert cons[-1][1:3] == ("stall", 5)
+
+
 def test_abandoned_epoch_leaks_nothing(mesh1):
     """Abandoning iteration mid-epoch (preemption, divergence abort) must
     not leave a producer thread behind nor device batches pinned in the

@@ -165,6 +165,35 @@ def test_span_breakdown_sums_to_total():
     assert d["notes"][0]["event"] == "attempt"
 
 
+def test_span_intervals_tile_the_span_and_count_per_stage():
+    """``intervals()`` is the marks read as segments: no hole, no overlap,
+    first start = origin, last end = last mark; a stage's ordinal is its
+    count so far, so a producer's and a consumer's spans number the same
+    batch alike although their stages differ."""
+    producer, consumer = Span("producer", "start"), Span("consumer", "start")
+    for batch in range(3):
+        for stage in ("prep_wait", "assemble", "h2d", "enqueue"):
+            producer.mark(stage)
+        consumer.mark("stall")
+        consumer.mark("dispatch")
+        if batch == 2:  # a stage that is not marked for every batch
+            consumer.mark("fetch")
+        consumer.mark("step")
+    for span in (producer, consumer):
+        ivs = span.intervals()
+        assert len(ivs) == len(span.marks) - 1
+        assert ivs[0][2] == span.marks[0][1] and ivs[-1][3] == span.marks[-1][1]
+        assert all(a[3] == b[2] for a, b in zip(ivs, ivs[1:]))
+        assert all(t0 <= t1 for _, _, t0, t1 in ivs)
+        assert sum(t1 - t0 for _, _, t0, t1 in ivs) == pytest.approx(
+            span.total_s, abs=1e-9)
+    assert [n for st, n, _, _ in producer.intervals() if st == "h2d"] == [0, 1, 2]
+    assert [n for st, n, _, _ in consumer.intervals() if st == "stall"] == [0, 1, 2]
+    assert [n for st, n, _, _ in consumer.intervals() if st == "dispatch"] == [0, 1, 2]
+    assert [n for st, n, _, _ in consumer.intervals() if st == "fetch"] == [0]
+    assert Span("empty").intervals() == []
+
+
 def test_tracer_ring_disable_and_env(monkeypatch):
     tr = Tracer(ring=4)
     for i in range(10):

@@ -2,6 +2,7 @@
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from deep_vision_tpu.ops.boxes import broadcast_iou
 from deep_vision_tpu.ops.pallas_ops import best_iou_max
@@ -103,3 +104,27 @@ def test_kernels_lower_for_tpu_at_zoo_shapes():
             lower(pallas_ops.best_iou_max, S((b, n, 4), jnp.float32),
                   S((b, MAX_BOXES, 4), jnp.float32),
                   S((b, MAX_BOXES), jnp.float32))
+
+
+@pytest.mark.parametrize("name,kwargs,specs", [
+    ("serve_ingest", {"kind": "imagenet", "act_scale": 0.02},
+     [((2, 32, 32, 3), jnp.uint8)]),
+    ("train_ingest", {"kind": "imagenet"},
+     [((2, 32, 32, 3), jnp.uint8), ((2, 4), jnp.float32)]),
+    ("best_iou_max", {},
+     [((2, 192, 4), jnp.float32), ((2, 100, 4), jnp.float32),
+      ((2, 100), jnp.float32)]),
+])
+def test_kernel_lowers_under_its_name(name, kwargs, specs):
+    """The Mosaic custom call carries the kernel's name, which is what a
+    device trace shows the kernel under."""
+    import functools
+
+    import jax
+
+    from deep_vision_tpu.ops import pallas_ops
+
+    fn = functools.partial(getattr(pallas_ops, name), **kwargs)
+    exported = jax.export.export(jax.jit(fn), platforms=["tpu"])(
+        *[jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in specs])
+    assert f'kernel_name = "{name}"' in exported.mlir_module()
