@@ -21,11 +21,12 @@ VMEM, broadcasts the tiny gt set per tile, and reduces to the (B, N) max
 in-register — one HBM pass over the predictions.
 
 Layout notes (TPU tiling):
-- predictions arrive (B, N, 4) and are processed in (TILE_B, TILE_N, 4)
-  VMEM blocks; coordinate columns are read as (TILE_N, 1) slices so the
-  (TILE_N, M) broadcast needs no in-kernel transpose;
-- ground truth is passed PRE-TRANSPOSED as (B, 4, M) so coordinate rows
-  read as (1, M) slices — M is padded to the 128-lane width;
+- predictions arrive as (B, 4, N) corner planes — N on the 128-wide lane
+  axis, as the loss computes them — and are processed in
+  (TILE_B, 4, TILE_N) VMEM blocks; a coordinate is a (1, TILE_N) row;
+- ground truth stays (B, M, 4): a coordinate is an (M, 1) column on the
+  sublanes (M padded to a multiple of 8), the (M, TILE_N) broadcast needs
+  no in-kernel transpose and the max runs over the sublane axis;
 - CPU tests run the same kernel via ``interpret=True``.
 """
 
@@ -39,10 +40,11 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 TILE_N = 256
+LANE = 128
+SUBLANE = 8
 #: best_iou_max batch tile — the f32 sublane granularity, so the (B, N)
 #: output block tiles cleanly
-TILE_B = 8
-LANE = 128
+TILE_B = SUBLANE
 #: serve_ingest row tile (sublane dim of the (B·H, W·C) view) — a
 #: multiple of the int8 sublane granularity (32) so the quantized
 #: output block tiles cleanly
@@ -388,51 +390,49 @@ def train_ingest_parity(shape: tuple, kind: str = "imagenet",
 def _best_iou_kernel(pred_ref, gt_ref, mask_ref, out_ref):
     # one (TILE_B images × TILE_N predictions) block; the grid runs over
     # batch tiles and N tiles.
-    # pred_ref: (TB, TILE_N, 4); gt_ref: (TB, 4, M); mask_ref: (TB, 1, M)
-    px1 = pred_ref[:, :, 0:1]   # (TB, T, 1)
-    py1 = pred_ref[:, :, 1:2]
-    px2 = pred_ref[:, :, 2:3]
-    py2 = pred_ref[:, :, 3:4]
-    gx1 = gt_ref[:, 0:1, :]     # (TB, 1, M)
-    gy1 = gt_ref[:, 1:2, :]
-    gx2 = gt_ref[:, 2:3, :]
-    gy2 = gt_ref[:, 3:4, :]
-    mask = mask_ref[:, 0:1, :]  # (TB, 1, M)
+    # pred_ref: (TB, 4, TILE_N); gt_ref: (TB, M, 4); mask_ref: (TB, M, 1)
+    px1 = pred_ref[:, 0:1, :]   # (TB, 1, T): predictions on the lanes
+    py1 = pred_ref[:, 1:2, :]
+    px2 = pred_ref[:, 2:3, :]
+    py2 = pred_ref[:, 3:4, :]
+    gx1 = gt_ref[:, :, 0:1]     # (TB, M, 1): ground truth on the sublanes
+    gy1 = gt_ref[:, :, 1:2]
+    gx2 = gt_ref[:, :, 2:3]
+    gy2 = gt_ref[:, :, 3:4]
+    mask = mask_ref[...]        # (TB, M, 1)
 
     inter_w = jnp.maximum(jnp.minimum(px2, gx2) - jnp.maximum(px1, gx1), 0.0)
     inter_h = jnp.maximum(jnp.minimum(py2, gy2) - jnp.maximum(py1, gy1), 0.0)
-    inter = inter_w * inter_h                            # (TB, T, M)
+    inter = inter_w * inter_h                            # (TB, M, T)
     area_p = jnp.maximum(px2 - px1, 0.0) * jnp.maximum(py2 - py1, 0.0)
     area_g = jnp.maximum(gx2 - gx1, 0.0) * jnp.maximum(gy2 - gy1, 0.0)
-    iou = inter / (area_p + area_g - inter + 1e-9)       # (TB, T, M)
+    iou = inter / (area_p + area_g - inter + 1e-9)       # (TB, M, T)
     iou = jnp.where(mask > 0, iou, 0.0)
-    out_ref[:, :] = jnp.max(iou, axis=2)
+    out_ref[:, :] = jnp.max(iou, axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def best_iou_max(pred_boxes, gt_boxes, gt_mask, interpret: bool = False):
-    """(B,N,4) corner preds × (B,M,4) corner gts + (B,M) mask → (B,N) max IoU.
+    """(B,4,N) corner planes × (B,M,4) corner gts + (B,M) mask → (B,N) max IoU.
 
     Matches ``broadcast_iou(...).max(-1)`` with masked gts scoring 0.
 
-    The batch is tiled through the grid TILE_B images at a time: the
-    (TILE_N, 4) prediction rows pad their 4-wide lane dim to 128 in VMEM,
-    so one image's block is 128 KiB and its (TILE_N, M) temporaries as
-    much again each — a whole batch of 128 (``yolov3_coco``) in one block
-    would ask for several times the chip's VMEM.
+    The batch is tiled through the grid TILE_B images at a time: one
+    image's (M, TILE_N) temporaries are 104 KiB each, so a whole batch of
+    128 (``yolov3_coco``) in one block would ask for several times the
+    chip's VMEM.
     """
-    B, N, _ = pred_boxes.shape
+    B, _, N = pred_boxes.shape
     M = gt_boxes.shape[1]
     # the (B, N) output block's sublane dim must be a multiple of 8 or
     # the whole batch
     tile_b = B if B <= TILE_B else TILE_B
     b_pad = (-B) % tile_b
     n_pad = (-N) % TILE_N
-    m_pad = (-M) % LANE
-    pred = jnp.pad(pred_boxes, ((0, b_pad), (0, n_pad), (0, 0)))
-    gt_t = jnp.pad(jnp.swapaxes(gt_boxes, 1, 2),
-                   ((0, b_pad), (0, 0), (0, m_pad)))
-    mask = jnp.pad(gt_mask, ((0, b_pad), (0, m_pad)))[:, None, :]
+    m_pad = (-M) % SUBLANE
+    pred = jnp.pad(pred_boxes, ((0, b_pad), (0, 0), (0, n_pad)))
+    gt = jnp.pad(gt_boxes, ((0, b_pad), (0, m_pad), (0, 0)))
+    mask = jnp.pad(gt_mask, ((0, b_pad), (0, m_pad)))[:, :, None]
     Bp, Np, Mp = B + b_pad, N + n_pad, M + m_pad
 
     out = pl.pallas_call(
@@ -440,14 +440,14 @@ def best_iou_max(pred_boxes, gt_boxes, gt_mask, interpret: bool = False):
         out_shape=jax.ShapeDtypeStruct((Bp, Np), jnp.float32),
         grid=(Bp // tile_b, Np // TILE_N),
         in_specs=[
-            pl.BlockSpec((tile_b, TILE_N, 4), lambda b, n: (b, n, 0)),
-            pl.BlockSpec((tile_b, 4, Mp), lambda b, n: (b, 0, 0)),
-            pl.BlockSpec((tile_b, 1, Mp), lambda b, n: (b, 0, 0)),
+            pl.BlockSpec((tile_b, 4, TILE_N), lambda b, n: (b, 0, n)),
+            pl.BlockSpec((tile_b, Mp, 4), lambda b, n: (b, 0, 0)),
+            pl.BlockSpec((tile_b, Mp, 1), lambda b, n: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((tile_b, TILE_N), lambda b, n: (b, n)),
         interpret=interpret,
         name="best_iou_max",
-    )(pred.astype(jnp.float32), gt_t.astype(jnp.float32),
+    )(pred.astype(jnp.float32), gt.astype(jnp.float32),
       mask.astype(jnp.float32))
     return out[:B, :N]
 
@@ -499,13 +499,14 @@ def best_iou_parity(batch: int = 2, n_pred: int = 600, n_gt: int = 100,
         p_wh = jax.random.uniform(k2, (batch, n_pred, 2), minval=0.01,
                                   maxval=0.4)
         pred = jnp.concatenate([p_xy - p_wh / 2, p_xy + p_wh / 2], -1)
+        planes = jnp.swapaxes(pred, 1, 2)  # (batch, 4, n_pred), as the loss
         g_xy = jax.random.uniform(k3, (batch, n_gt, 2))
         g_wh = jax.random.uniform(k4, (batch, n_gt, 2), minval=0.01,
                                   maxval=0.4)
         gt = jnp.concatenate([g_xy - g_wh / 2, g_xy + g_wh / 2], -1)
         mask = (jax.random.uniform(k5, (batch, n_gt)) > 0.3).astype(
             jnp.float32)
-        got = best_iou_max(pred, gt, mask, interpret=interpret)
+        got = best_iou_max(planes, gt, mask, interpret=interpret)
         iou = jnp.where(mask[:, None, :] > 0, broadcast_iou(pred, gt), 0.0)
         err = float(jax.device_get(jnp.abs(got - iou.max(-1)).max()))
         if not err < tol:

@@ -13,7 +13,12 @@ TPU notes: the ignore mask compares pred boxes against a FIXED-SIZE padded
 list of ground-truth boxes per image (batch["boxes"], mask in
 batch["boxes_mask"]) — the reference's ``tf.boolean_mask`` is dynamic-shaped
 (and mixes images across the batch); this formulation is static, per-image
-correct, and vmap-free.
+correct, and vmap-free.  Layout rule: the 5+C axis of ``raw`` / ``y_true`` is
+the lane axis on the chip, so ``x[..., k]`` on a full-size array is a pass
+over all of it that fills 1-2 of 128 lanes.  ``yolo_scale_loss`` moves that
+axis off the lanes once (``_channel_major``: cells on the lanes, the batch on
+the sublanes) and only ever slices whole planes; ``tests/test_detection.py``
+holds the rule on the traced loss.
 """
 
 from __future__ import annotations
@@ -65,6 +70,24 @@ def _bce(logit_or_prob, target, from_probs: bool, eps: float = 1e-7):
         jnp.log1p(jnp.exp(-jnp.abs(logit_or_prob)))
 
 
+def _channel_major(x, merged: bool):
+    """(B, G, G, A, 5+C) → (A, 5+C, B, G·G): the one pass that takes the
+    channel axis off the lanes; every piece is then a slice of planes.
+
+    Two spellings of one move, so that XLA compiles each operand's to a
+    single copy out of the layout it arrives in (read off the step compiled
+    for the v5e: the other spelling costs either operand two more passes).
+    ``merged``: the head conv's output, A·(5+C) on its lanes — moved as a
+    (B, G·G, A·(5+C)) array and split on the major side, which is free.
+    Otherwise ``y_true`` as the host hands it over, the batch between A
+    and 5+C on the chip — moved as the 4-D array it is."""
+    B, G, _, A, C = x.shape
+    if merged:
+        x = jnp.transpose(x.reshape(B, G * G, A * C), (2, 0, 1))
+        return x.reshape(A, C, B, G * G)
+    return jnp.transpose(x.reshape(B, G * G, A, C), (2, 3, 0, 1))
+
+
 def yolo_scale_loss(raw, y_true, gt_boxes, gt_mask, anchors_wh,
                     ignore_thresh: float = 0.5, lambda_coord: float = 5.0,
                     lambda_noobj: float = 0.5, use_pallas: bool = False,
@@ -74,35 +97,46 @@ def yolo_scale_loss(raw, y_true, gt_boxes, gt_mask, anchors_wh,
     raw: (B,G,G,A,5+C) head output; y_true: same shape, absolute xywh +
     obj + one-hot; gt_boxes: (B,MAX_BOXES,4) corner boxes; gt_mask: (B,M).
     Returns (total (B,), components dict).
-    """
-    num_classes = raw.shape[-1] - 5
-    pred_xy_rel = jax.nn.sigmoid(raw[..., 0:2])
-    pred_wh_rel = raw[..., 2:4]
-    pred_box_abs, pred_obj, _ = decode_boxes(raw, anchors_wh)
-    pred_corners = xywh_to_corners(pred_box_abs)
 
-    true_xy_abs = y_true[..., 0:2]
-    true_wh_abs = y_true[..., 2:4]
-    true_obj = y_true[..., 4:5]
-    true_class = y_true[..., 5:]
-    true_xy_rel, true_wh_rel = encode_boxes(y_true[..., 0:4], anchors_wh)
+    Computed on channel-major planes: ``decode_boxes`` / ``encode_boxes``
+    written out per plane of an (A, 5+C, B, G·G) array, the cells on the
+    minor axis (see the module's layout rule).
+    """
+    B, grid, _, A, _ = raw.shape
+    cell = np.arange(grid * grid)
+    c_xy = np.stack([cell % grid, cell // grid]).astype(np.float32)
+    c_xy = c_xy[:, None, :]                                   # (2, 1, G·G)
+    anchors = jnp.asarray(anchors_wh)[:, :, None, None]      # (A, 2, 1, 1)
+
+    raw = _channel_major(raw, merged=True)
+    y_true = _channel_major(y_true, merged=False)
+    raw_wh, raw_obj = raw[:, 2:4], raw[:, 4]
+    true_xy, true_wh, obj = y_true[:, 0:2], y_true[:, 2:4], y_true[:, 4]
+
+    pred_xy_rel = jax.nn.sigmoid(raw[:, 0:2])
+    pred_xy = (pred_xy_rel + c_xy) / grid
+    pred_wh = jnp.exp(jnp.clip(raw_wh, -9.0, 9.0)) * anchors
+    true_xy_rel = true_xy * grid - jnp.floor(true_xy * grid)
+    eps = 1e-9  # encode_boxes': empty cells → 0 target
+    true_wh_rel = jnp.log(jnp.maximum(true_wh, eps) / anchors)
+    true_wh_rel = jnp.where(true_wh <= eps, 0.0, true_wh_rel)
 
     # small-box upweighting (2 - w·h), darknet yolo_layer.c:190 via :405-407
-    weight = 2.0 - true_wh_abs[..., 0] * true_wh_abs[..., 1]
-    obj = true_obj[..., 0]
-
-    xy_loss = jnp.square(true_xy_rel - pred_xy_rel).sum(-1)
-    xy_loss = (obj * weight * xy_loss).sum((1, 2, 3)) * lambda_coord
-    wh_loss = jnp.square(true_wh_rel - pred_wh_rel).sum(-1)
-    wh_loss = (obj * weight * wh_loss).sum((1, 2, 3)) * lambda_coord
+    weight = obj * (2.0 - true_wh[:, 0] * true_wh[:, 1])      # (A, B, G·G)
+    xy_loss = jnp.square(true_xy_rel - pred_xy_rel).sum(1)
+    xy_loss = (weight * xy_loss).sum((0, 2)) * lambda_coord
+    wh_loss = jnp.square(true_wh_rel - raw_wh).sum(1)
+    wh_loss = (weight * wh_loss).sum((0, 2)) * lambda_coord
 
     # ignore mask: preds overlapping ANY same-image gt > thresh are not
     # penalized as background (yolov3.py:438-459, static-shape version).
     # stop_gradient: the mask is a hard threshold (zero gradient anyway) and
     # pallas_call has no autodiff rule — without this the Pallas path fails
     # to linearize under value_and_grad.
-    B, G = raw.shape[0], raw.shape[1]
-    flat_pred = jax.lax.stop_gradient(pred_corners.reshape(B, -1, 4))
+    corners = jnp.concatenate(
+        [pred_xy - pred_wh / 2.0, pred_xy + pred_wh / 2.0], 1)  # (A,4,B,G·G)
+    corners = jax.lax.stop_gradient(
+        jnp.transpose(corners, (2, 1, 0, 3)).reshape(B, 4, -1))  # (B, 4, N)
     # one scope over both implementations, so a trace reads the same work
     # under the same name whichever runs
     with jax.named_scope("best_iou"):
@@ -118,23 +152,23 @@ def yolo_scale_loss(raw, y_true, gt_boxes, gt_mask, anchors_wh,
             )
 
             if mesh is not None and mesh.devices.size > 1:
-                best_iou = best_iou_max_sharded(
-                    flat_pred, gt_boxes, gt_mask, mesh).reshape(obj.shape)
+                best_iou = best_iou_max_sharded(corners, gt_boxes, gt_mask,
+                                                mesh)
             else:
-                best_iou = best_iou_max_auto(flat_pred, gt_boxes,
-                                             gt_mask).reshape(obj.shape)
+                best_iou = best_iou_max_auto(corners, gt_boxes, gt_mask)
         else:
-            iou = broadcast_iou(flat_pred, gt_boxes)           # (B, N, M)
-            iou = jnp.where(gt_mask[:, None, :] > 0, iou, 0.0)
-            best_iou = iou.max(-1).reshape(obj.shape)
+            iou = broadcast_iou(jnp.swapaxes(corners, 1, 2), gt_boxes)
+            iou = jnp.where(gt_mask[:, None, :] > 0, iou, 0.0)  # (B, N, M)
+            best_iou = iou.max(-1)
+    best_iou = jnp.swapaxes(best_iou.reshape(B, A, -1), 0, 1)  # (A, B, G·G)
     ignore = (best_iou < ignore_thresh).astype(jnp.float32)
 
-    obj_entropy = _bce(raw[..., 4:5], true_obj, from_probs=False)[..., 0]
-    obj_loss = (obj * obj_entropy).sum((1, 2, 3))
-    noobj_loss = ((1 - obj) * obj_entropy * ignore).sum((1, 2, 3)) * lambda_noobj
+    obj_entropy = _bce(raw_obj, obj, from_probs=False)
+    obj_loss = (obj * obj_entropy).sum((0, 2))
+    noobj_loss = ((1 - obj) * obj_entropy * ignore).sum((0, 2)) * lambda_noobj
 
-    class_entropy = _bce(raw[..., 5:], true_class, from_probs=False)
-    class_loss = (true_obj * class_entropy).sum((1, 2, 3, 4))
+    class_entropy = _bce(raw[:, 5:], y_true[:, 5:], from_probs=False)
+    class_loss = (obj[:, None] * class_entropy).sum((0, 1, 3))
 
     total = xy_loss + wh_loss + obj_loss + noobj_loss + class_loss
     return total, {"xy": xy_loss, "wh": wh_loss,

@@ -233,6 +233,263 @@ def test_yolo_loss_grad_with_pallas_path():
     assert float(jnp.abs(grads).max()) > 0
 
 
+def _slice_based_scale_loss(raw, y_true, gt_boxes, gt_mask, anchors_wh,
+                            ignore_thresh=0.5, lambda_coord=5.0,
+                            lambda_noobj=0.5):
+    """The loss as it was written before it moved to channel-major planes:
+    pieces cut out of the (B,G,G,A,5+C) arrays along their last axis, the
+    box codecs called on the full-size arrays, the ignore mask from
+    ``broadcast_iou``.  Kept here as the oracle the planes are held to.
+    Returns (total, components, ignore mask (B,G,G,A))."""
+    pred_xy_rel = jax.nn.sigmoid(raw[..., 0:2])
+    pred_wh_rel = raw[..., 2:4]
+    pred_box_abs, _, _ = D.decode_boxes(raw, anchors_wh)
+    pred_corners = xywh_to_corners(pred_box_abs)
+    true_wh_abs = y_true[..., 2:4]
+    true_obj = y_true[..., 4:5]
+    true_xy_rel, true_wh_rel = D.encode_boxes(y_true[..., 0:4], anchors_wh)
+    weight = 2.0 - true_wh_abs[..., 0] * true_wh_abs[..., 1]
+    obj = true_obj[..., 0]
+    xy_loss = jnp.square(true_xy_rel - pred_xy_rel).sum(-1)
+    xy_loss = (obj * weight * xy_loss).sum((1, 2, 3)) * lambda_coord
+    wh_loss = jnp.square(true_wh_rel - pred_wh_rel).sum(-1)
+    wh_loss = (obj * weight * wh_loss).sum((1, 2, 3)) * lambda_coord
+    flat_pred = jax.lax.stop_gradient(
+        pred_corners.reshape(raw.shape[0], -1, 4))
+    iou = broadcast_iou(flat_pred, gt_boxes)
+    iou = jnp.where(gt_mask[:, None, :] > 0, iou, 0.0)
+    ignore = (iou.max(-1).reshape(obj.shape) < ignore_thresh).astype(
+        jnp.float32)
+    obj_entropy = D._bce(raw[..., 4:5], true_obj, from_probs=False)[..., 0]
+    obj_loss = (obj * obj_entropy).sum((1, 2, 3))
+    noobj_loss = ((1 - obj) * obj_entropy * ignore).sum((1, 2, 3)) \
+        * lambda_noobj
+    class_entropy = D._bce(raw[..., 5:], y_true[..., 5:], from_probs=False)
+    class_loss = (true_obj * class_entropy).sum((1, 2, 3, 4))
+    total = xy_loss + wh_loss + obj_loss + noobj_loss + class_loss
+    return total, {"xy": xy_loss, "wh": wh_loss,
+                   "obj": obj_loss + noobj_loss, "class": class_loss}, ignore
+
+
+def _scale_case(grid, num_classes, batch=2, seed=0):
+    """One scale's (raw, y_true, boxes, mask, anchors): seeded boxes
+    encoded by ``encode_labels``, and a raw head output that is noise
+    except at every box's cell, where ALL three anchors predict the box
+    within a few percent — so the two anchors the box was not assigned to
+    are background predictions above the ignore threshold, and the
+    noise around them is background below it."""
+    rng = np.random.default_rng(seed)
+    scale = {52: 0, 26: 1, 13: 2}[grid]
+    masks = np.asarray(ANCHOR_MASKS[scale])[None]
+    anchors = YOLO_ANCHORS[ANCHOR_MASKS[scale]]
+    raw = rng.normal(0, 0.5, (batch, grid, grid, 3, 5 + num_classes))
+    y_true, boxes, boxes_mask = [], [], []
+    for b in range(batch):
+        n = 6
+        xy = rng.uniform(0.15, 0.85, (n, 2))
+        # sized like this scale's anchors, so encode_labels keeps them here
+        wh = anchors[rng.integers(0, 3, n)] * rng.uniform(0.9, 1.1, (n, 2))
+        enc = D.encode_labels(
+            np.concatenate([xy, wh], 1).astype(np.float32),
+            rng.integers(0, num_classes, n), num_classes, grids=(grid,),
+            masks=masks)
+        y_true.append(enc["y_true_0"])
+        boxes.append(enc["boxes"])
+        boxes_mask.append(enc["boxes_mask"])
+        gx = np.clip((xy[:, 0] * grid).astype(int), 0, grid - 1)
+        gy = np.clip((xy[:, 1] * grid).astype(int), 0, grid - 1)
+        frac = np.clip(xy * grid - np.stack([gx, gy], 1), 0.02, 0.98)
+        raw[b, gy, gx, :, 0:2] = (np.log(frac) - np.log1p(-frac))[:, None]
+        raw[b, gy, gx, :, 2:4] = np.log(wh[:, None] / anchors[None]) \
+            + rng.normal(0, 0.03, (n, 3, 2))
+    return tuple(jnp.asarray(np.asarray(a, np.float32)) for a in
+                 (raw, y_true, boxes, boxes_mask, anchors))
+
+
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["xla", "pallas"])
+@pytest.mark.parametrize("num_classes", [3, 80])
+@pytest.mark.parametrize("grid", [13, 26, 52])
+def test_yolo_loss_equals_slice_based_oracle(grid, num_classes, use_pallas):
+    """The channel-major loss is the slice-based one: per-image total, the
+    four components and the gradient with respect to ``raw``; only the
+    order of summation may differ."""
+    raw, y_true, boxes, mask, anchors = _scale_case(grid, num_classes)
+
+    # jitted: eager, every operation of both formulations compiles alone
+    def new(r):
+        return D.yolo_scale_loss(r, y_true, boxes, mask, anchors,
+                                 use_pallas=use_pallas)
+
+    def old(r):
+        return _slice_based_scale_loss(r, y_true, boxes, mask, anchors)
+
+    want_total, want_comps, ignore = jax.jit(old)(raw)
+    # the case means something only if background predictions fall on
+    # both sides of the ignore threshold
+    background = np.asarray(y_true[..., 4]) == 0
+    ignored = np.asarray(ignore)[background]
+    assert 0 < (ignored == 0).sum() < ignored.size
+
+    got_total, got_comps = jax.jit(new)(raw)
+    assert got_total.shape == want_total.shape == (raw.shape[0],)
+    np.testing.assert_allclose(got_total, want_total, rtol=1e-5)
+    assert set(got_comps) == {"xy", "wh", "obj", "class"}
+    for k in got_comps:
+        np.testing.assert_allclose(got_comps[k], want_comps[k], rtol=1e-5,
+                                   err_msg=k)
+
+    got_grad = jax.jit(jax.grad(lambda r: new(r)[0].sum()))(raw)
+    want_grad = jax.jit(jax.grad(lambda r: old(r)[0].sum()))(raw)
+    np.testing.assert_allclose(got_grad, want_grad, rtol=1e-5, atol=1e-7)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in its
+    equations' parameters (pjit, custom_jvp, pallas_call, ...)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else (value,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub)
+
+
+def _cuts_lanes(eqn, lanes=128):
+    """Does this slice/split/concatenate/gather cut or join its operands
+    along their last axis, with a piece narrower than the chip's lanes?
+    (Dropping a padded tail — ``out[:, :n]`` of a kernel's result — keeps
+    whole lane tiles and is not the fault.)"""
+    name = eqn.primitive.name
+    if name == "gather":
+        return True
+    ins = [v.aval.shape for v in eqn.invars if v.aval.ndim]
+    outs = [v.aval.shape for v in eqn.outvars]
+    if name in ("slice", "dynamic_slice"):
+        cut = outs[0][-1] != ins[0][-1]
+    elif name == "split":
+        cut = eqn.params["axis"] == len(ins[0]) - 1
+    else:
+        cut = eqn.params["dimension"] == len(ins[0]) - 1
+    return cut and min(s[-1] for s in (outs if name != "concatenate"
+                                       else ins)) < lanes
+
+
+@pytest.mark.parametrize("with_grad", [False, True], ids=["loss", "grad"])
+def test_yolo_loss_never_slices_the_lane_axis_of_a_full_array(with_grad):
+    """Traced (not run) at the shapes of ``yolov3-416-train-b64``: no
+    slice, split, concatenate or gather takes an operand of a whole
+    plane's size or more (B·N elements) apart along its last axis into
+    pieces narrower than 128 — that axis is the lane axis on the chip, and
+    ``x[..., 0:2]`` on a (B,G,G,A,85) array is a pass over all of it that
+    fills 2 lanes."""
+    batch, num_classes = 64, 80
+    task = D.YoloTask(num_classes, use_pallas=True)
+    S = jax.ShapeDtypeStruct
+    grids = (52, 26, 13)
+    outputs = [S((batch, g, g, 3, 5 + num_classes), jnp.float32)
+               for g in grids]
+    targets = {f"y_true_{s}": o for s, o in enumerate(outputs)}
+    targets["boxes"] = S((batch, D.MAX_BOXES, 4), jnp.float32)
+    targets["boxes_mask"] = S((batch, D.MAX_BOXES), jnp.float32)
+    def fn(outputs, targets):
+        return task.loss(outputs, targets)[0]
+
+    if with_grad:
+        fn = jax.grad(fn)
+    jaxpr = jax.make_jaxpr(fn)(outputs, targets).jaxpr
+
+    smallest_plane = batch * 3 * min(grids) ** 2
+    seen, bad = set(), []
+    for eqn in _equations(jaxpr):
+        name = eqn.primitive.name
+        seen.add(name)
+        if name not in ("slice", "dynamic_slice", "split", "concatenate",
+                        "gather"):
+            continue
+        biggest = max(v.aval.size for v in eqn.invars)
+        if biggest >= smallest_plane and _cuts_lanes(eqn):
+            bad.append(f"{name} {[v.aval.shape for v in eqn.invars]} -> "
+                       f"{[v.aval.shape for v in eqn.outvars]}")
+    assert "pallas_call" in seen and "slice" in seen  # the walk saw the loss
+    assert not bad, bad
+
+
+def test_lane_axis_check_catches_the_old_formulation():
+    """The same walk over the slice-based oracle does flag it."""
+    raw = jax.ShapeDtypeStruct((4, 13, 13, 3, 8), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda r: _slice_based_scale_loss(
+        r, r, jnp.zeros((4, 5, 4)), jnp.ones((4, 5)),
+        jnp.ones((3, 2)))[0])(raw).jaxpr
+    cuts = [e for e in _equations(jaxpr)
+            if e.primitive.name in ("slice", "split", "concatenate")
+            and max(v.aval.size for v in e.invars) >= 4 * 507
+            and _cuts_lanes(e)]
+    assert cuts
+
+
+@pytest.fixture(scope="module")
+def one_v5e_chip():
+    """A described (not attached) v5e chip to compile for; nothing runs."""
+    import os
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_yolo_loss_compiles_to_one_copy_an_operand(one_v5e_chip):
+    """What the jaxpr cannot show: XLA is free to keep 5+C on the lanes
+    behind a transpose and slice it there again.  Compiled for the v5e —
+    head conv, the head's reshape and cast, loss and gradient at the 26×26
+    scale of ``yolov3-416-train-b64`` — the step moves a full-size float32
+    array exactly twice outside a fusion: ``raw`` out of the conv's layout
+    and ``y_true`` out of the host's (``_channel_major``'s two spellings;
+    either spelling used for both operands makes it four to six)."""
+    import re
+
+    batch, grid, num_classes, features = 64, 26, 80, 512
+    chans = 3 * (5 + num_classes)
+
+    def S(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    def step(kernel, x, y_true, boxes, mask):
+        def loss(kernel):
+            raw = jax.lax.conv_general_dilated(
+                x, kernel, (1, 1), "SAME",
+                dimension_numbers=("NHWC", "HWIO", "NHWC"))
+            raw = raw.reshape(batch, grid, grid, 3, 5 + num_classes)
+            return D.yolo_scale_loss(
+                raw.astype(jnp.float32), y_true, boxes, mask,
+                jnp.asarray(YOLO_ANCHORS[ANCHOR_MASKS[1]])
+            )[0].mean()  # the XLA ignore mask: Mosaic is not under test
+        return jax.value_and_grad(loss)(kernel)
+
+    hlo = jax.jit(step).lower(
+        S((1, 1, features, chans), jnp.bfloat16),
+        S((batch, grid, grid, features), jnp.bfloat16),
+        S((batch, grid, grid, 3, 5 + num_classes)),
+        S((batch, D.MAX_BOXES, 4)), S((batch, D.MAX_BOXES))).compile().as_text()
+    entry = hlo[hlo.index("ENTRY"):]
+    moves = []
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = f32\[([\d,]+)\]\S* "
+                     r"(copy|reshape|slice|transpose)\(", line)
+        if m and np.prod([int(d) for d in m.group(1).split(",")]) \
+                >= batch * grid * grid * chans:
+            moves.append(line.strip()[:120])
+    assert len(moves) == 2, moves
+
+
 def test_average_precision_perfect():
     r = np.array([0.5, 1.0])
     p = np.array([1.0, 1.0])

@@ -8,46 +8,58 @@ from deep_vision_tpu.ops.boxes import broadcast_iou
 from deep_vision_tpu.ops.pallas_ops import best_iou_max
 
 
-def _reference(pred, gt, mask):
-    iou = broadcast_iou(pred, gt)
+def _reference(planes, gt, mask):
+    """The XLA formulation on (B, N, 4) boxes, fed the kernel's (B, 4, N)
+    corner planes."""
+    iou = broadcast_iou(jnp.swapaxes(planes, 1, 2), gt)
     iou = jnp.where(mask[:, None, :] > 0, iou, 0.0)
     return iou.max(-1)
 
 
-def test_best_iou_max_matches_reference():
-    rng = np.random.default_rng(0)
-    B, N, M = 2, 700, 100  # N not a tile multiple, M not lane multiple
-    p1 = rng.uniform(0, 0.8, (B, N, 2)).astype(np.float32)
-    pred = np.concatenate([p1, p1 + rng.uniform(0.05, 0.2, (B, N, 2))
-                           .astype(np.float32)], -1)
+def _boxes(rng, B, N, M):
+    """Seeded (B, 4, N) prediction planes, (B, M, 4) gts and a (B, M) mask."""
+    p1 = rng.uniform(0, 0.8, (B, 2, N)).astype(np.float32)
+    pred = np.concatenate([p1, p1 + rng.uniform(0.05, 0.2, (B, 2, N))
+                           .astype(np.float32)], 1)
     g1 = rng.uniform(0, 0.8, (B, M, 2)).astype(np.float32)
     gt = np.concatenate([g1, g1 + rng.uniform(0.05, 0.2, (B, M, 2))
                          .astype(np.float32)], -1)
     mask = (rng.uniform(size=(B, M)) > 0.5).astype(np.float32)
-    got = best_iou_max(jnp.asarray(pred), jnp.asarray(gt),
-                       jnp.asarray(mask), interpret=True)
-    want = _reference(jnp.asarray(pred), jnp.asarray(gt), jnp.asarray(mask))
+    return jnp.asarray(pred), jnp.asarray(gt), jnp.asarray(mask)
+
+
+def test_best_iou_max_matches_reference():
+    # N not a tile multiple, M not a sublane multiple
+    pred, gt, mask = _boxes(np.random.default_rng(0), 2, 700, 100)
+    got = best_iou_max(pred, gt, mask, interpret=True)
+    want = _reference(pred, gt, mask)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-6)
 
 
 def test_best_iou_max_all_masked_is_zero():
     pred = jnp.asarray(np.random.default_rng(1)
-                       .uniform(0, 1, (1, 64, 4)).astype(np.float32))
+                       .uniform(0, 1, (1, 4, 64)).astype(np.float32))
     gt = jnp.zeros((1, 8, 4))
     mask = jnp.zeros((1, 8))
     out = best_iou_max(pred, gt, mask, interpret=True)
     assert float(jnp.abs(out).max()) == 0.0
 
 
-def test_parity_check_passes_interpret():
-    """The startup check the CLI runs before baking the Pallas path in —
-    including a batch that is not a whole number of TILE_B tiles."""
+@pytest.mark.parametrize("shape", [
+    {},
+    # a batch that is not a whole number of TILE_B tiles
+    {"batch": 12, "n_pred": 300, "n_gt": 20},
+    # what yolov3-416-train-b64 compiles: no N is a multiple of TILE_N
+    {"batch": 64, "n_pred": 8112, "n_gt": 100},
+    {"batch": 64, "n_pred": 2028, "n_gt": 100},
+    {"batch": 64, "n_pred": 507, "n_gt": 100},
+], ids=["default", "b12", "b64-52", "b64-26", "b64-13"])
+def test_parity_check_passes_interpret(shape):
+    """The startup check the CLI runs before baking the Pallas path in."""
     from deep_vision_tpu.ops.pallas_ops import best_iou_parity
 
-    assert best_iou_parity(interpret=True) < 1e-5
-    assert best_iou_parity(batch=12, n_pred=300, n_gt=20,
-                           interpret=True) < 1e-5
+    assert best_iou_parity(interpret=True, **shape) < 1e-5
 
 
 def test_best_iou_max_sharded_matches_reference(mesh8):
@@ -55,18 +67,10 @@ def test_best_iou_max_sharded_matches_reference(mesh8):
     kernel) reproduces the XLA reference on an 8-device mesh."""
     from deep_vision_tpu.ops.pallas_ops import best_iou_max_sharded
 
-    rng = np.random.default_rng(2)
-    B, N, M = 16, 300, 40  # 2 images per shard
-    p1 = rng.uniform(0, 0.8, (B, N, 2)).astype(np.float32)
-    pred = np.concatenate([p1, p1 + rng.uniform(0.05, 0.2, (B, N, 2))
-                           .astype(np.float32)], -1)
-    g1 = rng.uniform(0, 0.8, (B, M, 2)).astype(np.float32)
-    gt = np.concatenate([g1, g1 + rng.uniform(0.05, 0.2, (B, M, 2))
-                         .astype(np.float32)], -1)
-    mask = (rng.uniform(size=(B, M)) > 0.5).astype(np.float32)
-    got = best_iou_max_sharded(jnp.asarray(pred), jnp.asarray(gt),
-                               jnp.asarray(mask), mesh8)
-    want = _reference(jnp.asarray(pred), jnp.asarray(gt), jnp.asarray(mask))
+    # 2 images per shard
+    pred, gt, mask = _boxes(np.random.default_rng(2), 16, 300, 40)
+    got = best_iou_max_sharded(pred, gt, mask, mesh8)
+    want = _reference(pred, gt, mask)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-6)
 
@@ -101,7 +105,7 @@ def test_kernels_lower_for_tpu_at_zoo_shapes():
     for b in (16, 128):
         for s in (8, 16, 32):
             n = 3 * (416 // s) ** 2
-            lower(pallas_ops.best_iou_max, S((b, n, 4), jnp.float32),
+            lower(pallas_ops.best_iou_max, S((b, 4, n), jnp.float32),
                   S((b, MAX_BOXES, 4), jnp.float32),
                   S((b, MAX_BOXES), jnp.float32))
 
@@ -112,7 +116,7 @@ def test_kernels_lower_for_tpu_at_zoo_shapes():
     ("train_ingest", {"kind": "imagenet"},
      [((2, 32, 32, 3), jnp.uint8), ((2, 4), jnp.float32)]),
     ("best_iou_max", {},
-     [((2, 192, 4), jnp.float32), ((2, 100, 4), jnp.float32),
+     [((2, 4, 192), jnp.float32), ((2, 100, 4), jnp.float32),
       ((2, 100), jnp.float32)]),
 ])
 def test_kernel_lowers_under_its_name(name, kwargs, specs):
