@@ -80,6 +80,12 @@ def build_parser() -> argparse.ArgumentParser:
                         "(the load_model_weights role; any published-"
                         "accuracy arch — see models/pretrained.py); head "
                         "kept only when the class count matches")
+    p.add_argument("--override", action="append", default=[],
+                   metavar="KEY=JSON",
+                   help="set a key of the config's extra block or of its "
+                        "architecture (language models: "
+                        "num_hidden_layers=10, vocab_size=12544, "
+                        "sequence_length=1024); repeatable")
     p.add_argument("--profile", action="store_true",
                    help="jax.profiler trace of steps 10-20 → workdir/profile")
     p.add_argument("--list", action="store_true", help="list configs and exit")
@@ -128,6 +134,7 @@ def main(argv=None):
         cfg.image_size = args.image_size
     if args.prefetch_depth is not None:
         cfg.prefetch_depth = args.prefetch_depth
+    apply_overrides(cfg, args.override)
 
     from deep_vision_tpu.core.trainer import Trainer
     from deep_vision_tpu.data.loader import ArrayLoader
@@ -142,6 +149,8 @@ def main(argv=None):
         return _main_pose(args, cfg, mesh)
     if cfg.task.startswith("gan_"):
         return _main_gan(args, cfg, mesh)
+    if cfg.task == "language_modeling":
+        return _main_language(args, cfg, mesh)
     if cfg.task != "classification":
         raise NotImplementedError(
             f"task '{cfg.task}' CLI wiring lands with its stack")
@@ -250,6 +259,21 @@ def main(argv=None):
                 loader.close()
     print("final:", " ".join(f"{k}={v:.4f}" for k, v in final.items()))
     return 0
+
+
+def apply_overrides(cfg, overrides: list):
+    """``KEY=JSON`` pairs onto ``cfg.extra`` or, where the key is one of its
+    architecture's, onto that; an unknown key is an error, not a new one."""
+    import json
+
+    for item in overrides:
+        key, _, text = item.partition("=")
+        arch = cfg.extra.get("architecture", {})
+        target = arch if key in arch else cfg.extra
+        if key not in target:
+            raise SystemExit(f"--override {key}: config '{cfg.name}' has no "
+                             f"such key; have {sorted({*arch, *cfg.extra})}")
+        target[key] = json.loads(text)
 
 
 def build_classification_val_loader(cfg, data_root: str, split: str,
@@ -481,6 +505,38 @@ def _main_pose(args, cfg, mesh):
         final = trainer.evaluate(state, val_loader)
     finally:
         train_loader.close()
+    print("final:", " ".join(f"{k}={v:.4f}" for k, v in final.items()))
+    return 0
+
+
+def _main_language(args, cfg, mesh):
+    from deep_vision_tpu.core.trainer import Trainer
+    from deep_vision_tpu.data.loader import ArrayLoader
+    from deep_vision_tpu.data.text import pack_documents, synthetic_corpus
+    from deep_vision_tpu.tasks.language_modeling import LanguageModelingTask
+
+    if not args.synthetic:
+        raise SystemExit("language models train on --synthetic documents "
+                         "here; no tokenised corpus reader exists yet")
+    length = int(cfg.extra["sequence_length"])
+    vocab = int(cfg.extra["architecture"]["vocab_size"])
+
+    def rows(n, seed):
+        docs = synthetic_corpus((n + 1) * length, vocab, seed=seed,
+                                max_length=length)
+        return {k: v[:n] for k, v in pack_documents(docs, length).items()}
+
+    train_loader = ArrayLoader(rows(args.synthetic_size, 1), cfg.batch_size,
+                               seed=cfg.seed)
+    val_loader = ArrayLoader(
+        rows(max(args.synthetic_size // 4, cfg.eval_batch_size), 2),
+        cfg.eval_batch_size, shuffle=False)
+    trainer = Trainer(cfg, cfg.model(), LanguageModelingTask(), mesh=mesh,
+                      workdir=args.workdir, upload=args.upload)
+    if args.profile:
+        trainer.profile_steps = (10, 20)
+    state = trainer.fit(train_loader, val_loader, resume=args.resume)
+    final = trainer.evaluate(state, val_loader)
     print("final:", " ".join(f"{k}={v:.4f}" for k, v in final.items()))
     return 0
 

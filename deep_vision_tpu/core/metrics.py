@@ -102,6 +102,8 @@ class MetricLogger:
             "input_prep_wait_ms": float(prod.get("prep_wait", 0.0)) / n,
             "input_assemble_ms": float(prod.get("assemble", 0.0)) / n,
             "input_h2d_ms": float(prod.get("h2d", 0.0)) / n,
+            **{f"input_{name}_per_step": float(total) / n
+               for name, total in stats.get("counters", {}).items()},
         })
 
     def latest(self, name: str) -> float | None:

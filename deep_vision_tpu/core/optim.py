@@ -46,11 +46,13 @@ def _weight_decay_mask(params):
     """Decay kernels only — skip biases and BN scale/bias, matching the
     effective behavior of torch SGD weight_decay on conv/fc layers dominating
     the norm (ResNet/pytorch/train.py:166-184 uses blanket 1e-4; we use the
-    modern no-BN-decay recipe required to reach 76% top-1)."""
+    modern no-BN-decay recipe required to reach 76% top-1).  No leaf of
+    rank under 2 is decayed, whatever its name: a state-space layer's
+    ``A_log``, ``D`` and ``dt_bias`` are vectors too."""
 
     def keep(path, x):
         leaf = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
-        return leaf not in ("bias", "scale")
+        return leaf not in ("bias", "scale") and x.ndim >= 2
 
     return jax.tree_util.tree_map_with_path(keep, params)
 

@@ -143,13 +143,15 @@ class Trainer:
     def init_state(self, sample_batch: dict) -> TrainState:
         rng = jax.random.PRNGKey(self.config.seed)
         init_rng, state_rng = jax.random.split(rng)
-        image = jnp.asarray(sample_batch["image"][:1])
+        if hasattr(self.task, "model_inputs"):
+            first = {k: jnp.asarray(v[:1]) for k, v in sample_batch.items()}
+        else:
+            first = {"image": jnp.asarray(sample_batch["image"][:1])}
         if self.preprocess_fn is not None:
-            image = self.preprocess_fn({"image": image}, init_rng,
-                                       train=False)["image"]
+            first = self.preprocess_fn(first, init_rng, train=False)
         variables = jax.jit(
             functools.partial(self.model.init, train=False)
-        )({"params": init_rng, "dropout": init_rng}, image)
+        )({"params": init_rng, "dropout": init_rng}, *self._model_inputs(first))
         params = variables["params"]
         batch_stats = variables.get("batch_stats", {})
         self._has_bn = "batch_stats" in variables
@@ -158,6 +160,13 @@ class Trainer:
             batch_stats=batch_stats, rng=state_rng,
             ema=getattr(self.config, "ema_decay", 0.0) > 0)
         return self._place_state(state)
+
+    def _model_inputs(self, batch: dict) -> tuple:
+        """What the model is called with: a task says so through
+        ``model_inputs(batch)`` (tokens and segment ids, say); the image
+        tasks do not, and their model gets ``batch["image"]``."""
+        inputs = getattr(self.task, "model_inputs", None)
+        return inputs(batch) if inputs is not None else (batch["image"],)
 
     @staticmethod
     @functools.lru_cache(maxsize=None)
@@ -242,6 +251,7 @@ class Trainer:
     def _build_steps(self):
         task, has_bn = self.task, self._has_bn
         preprocess_fn = self.preprocess_fn
+        model_inputs = self._model_inputs
 
         accum = max(1, getattr(self.config, "grad_accum_steps", 1))
         ema_decay = float(getattr(self.config, "ema_decay", 0.0))
@@ -258,7 +268,7 @@ class Trainer:
                 # jvp(loss), their gradients as transpose(jvp(...))
                 with jax.named_scope("forward"):
                     out = apply_fn(
-                        variables, batch["image"], train=True,
+                        variables, *model_inputs(batch), train=True,
                         rngs={"dropout": dropout_rng},
                         mutable=["batch_stats"] if has_bn else False)
                 if has_bn:
@@ -373,7 +383,7 @@ class Trainer:
                          else state.params}
             if has_bn:
                 variables["batch_stats"] = state.batch_stats
-            out = state.apply_fn(variables, batch["image"], train=False)
+            out = state.apply_fn(variables, *model_inputs(batch), train=False)
             sums = task.eval_metrics(out, batch)
             extra = None
             if has_outputs:
@@ -525,7 +535,7 @@ class Trainer:
             f.write(json.dumps({
                 "clock": clock, "profile_steps": list(self.profile_steps),
                 "depth": stream.depth, "batches": stats["batches"],
-                "h2d_bytes": stats["h2d_bytes"]}) + "\n")
+                "h2d_bytes": stats["h2d_bytes"], **stats["counters"]}) + "\n")
             for thread, stage, batch, a, b in stream.intervals():
                 f.write(json.dumps({
                     "thread": thread, "stage": stage, "batch": batch,
@@ -548,7 +558,8 @@ class Trainer:
         # the producer thread while step N computes; the stream yields
         # already-placed device batches (shard_batch in train_step is a
         # no-op on them) that the jitted step consumes via donation
-        stream = self._get_prefetcher().iterate(train_data)
+        stream = self._get_prefetcher().iterate(
+            train_data, counters=getattr(self.task, "batch_counters", None))
         for i, batch in enumerate(stream):
             if profiling is not None:
                 if i == profiling[0]:

@@ -120,12 +120,15 @@ class _EpochStream:
 
     def __init__(self, mesh, iterable: Iterable, depth: int,
                  pool: HostStagingPool,
-                 host_transform: Callable[[Any], Any] | None = None):
+                 host_transform: Callable[[Any], Any] | None = None,
+                 counters: Callable[[Any], dict] | None = None):
         self.mesh = mesh
         self.depth = depth
         self._pool = pool
         self._iterable = iterable
         self._host_transform = host_transform
+        self._count = counters
+        self.counters: dict[str, int] = {}  # producer-side, like h2d_bytes
         self._q: queue.Queue = queue.Queue(maxsize=depth)
         self._stop = threading.Event()
         self._error: BaseException | None = None
@@ -228,6 +231,9 @@ class _EpochStream:
                 self._pspan.mark("prep_wait")
                 if self._host_transform is not None:
                     item = self._host_transform(item)
+                if self._count is not None:
+                    for name, n in self._count(item).items():
+                        self.counters[name] = self.counters.get(name, 0) + n
                 staged, bufs = self._stage(item)
                 self._pspan.mark("assemble")
                 dev = shard_batch(staged, self.mesh)
@@ -331,6 +337,7 @@ class _EpochStream:
             "h2d_bytes": self.h2d_bytes,
             "h2d_bytes_per_step": self.h2d_bytes / n,
             "h2d_bytes_by_key": dict(self.h2d_bytes_by_key),
+            "counters": dict(self.counters),
             "producer_ms": {k: round(v, 3) for k, v in prod.items()},
             "pool": self._pool.stats(),
         }
@@ -358,13 +365,16 @@ class DevicePrefetcher:
         self._epoch: _EpochStream | None = None
 
     def iterate(self, iterable: Iterable,
-                host_transform: Callable[[Any], Any] | None = None
+                host_transform: Callable[[Any], Any] | None = None,
+                counters: Callable[[Any], dict] | None = None
                 ) -> _EpochStream:
         """Start (and return) one epoch's staged stream.  At most one epoch
-        is live per prefetcher — starting a new one closes the previous."""
+        is live per prefetcher — starting a new one closes the previous.
+        ``counters`` maps a host batch to named counts (a language task's
+        tokens and documents); their sums are in the epoch's ``stats()``."""
         self.close()
         self._epoch = _EpochStream(self.mesh, iterable, self.depth,
-                                   self.pool, host_transform)
+                                   self.pool, host_transform, counters)
         return self._epoch
 
     def close(self):

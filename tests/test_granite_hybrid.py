@@ -1,0 +1,338 @@
+"""The hybrid state-space decoder at a small size on the CPU: hidden 64,
+4 heads of 16, state 16, chunk 8, rows of 64, three layers
+mamba / attention / mamba, 128 rows of vocabulary.  The chunked scan and the
+blocked attention against their plain forms, the model against the
+benchmark's plain reference, and what ties a packed row's documents apart."""
+
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deep_vision_tpu.data.text import pack_documents, synthetic_corpus
+from deep_vision_tpu.models.granite_hybrid import (
+    GraniteHybrid,
+    GraniteHybridConfig,
+)
+from deep_vision_tpu.ops.attention import causal_attention
+from deep_vision_tpu.ops.ssd import ssd_scan
+from deep_vision_tpu.zoo.language import GRANITE_4_0_H_MICRO
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SMALL = dict(GRANITE_4_0_H_MICRO, hidden_size=64, num_attention_heads=4,
+             num_key_value_heads=2, mamba_n_heads=8, mamba_d_head=16,
+             mamba_d_state=16, mamba_chunk_size=8, shared_intermediate_size=128,
+             layer_types=["mamba", "attention", "mamba"], num_hidden_layers=3,
+             vocab_size=128)
+LENGTH = 64
+
+
+def small_rows(seed=3, rows=2):
+    """Packed rows whose document boundaries fall off the chunk grid."""
+    docs = synthetic_corpus(LENGTH * (rows + 1), SMALL["vocab_size"], seed=seed,
+                            median_length=11, sigma=0.6, max_length=LENGTH)
+    batch = pack_documents(docs, LENGTH)
+    starts = np.flatnonzero(np.diff(batch["segment_ids"][0])) + 1
+    assert len(starts) >= 2 and any(s % SMALL["mamba_chunk_size"] for s in starts)
+    return {k: v[:rows] for k, v in batch.items()}
+
+
+def small_model(dtype=jnp.float32):
+    return GraniteHybrid(GraniteHybridConfig.from_dict(SMALL),
+                         attention_block=16, dtype=dtype)
+
+
+def flat(tree):
+    from flax import traverse_util
+
+    return traverse_util.flatten_dict(dict(tree), sep="/")
+
+
+@pytest.fixture(scope="module")
+def reference():
+    from benchmark.byname import load_module
+
+    module = load_module(os.path.join(
+        ROOT, "benchmark", "configs", "granite-4.0-h-micro.py"), "granite_ref")
+    return module, module.Reference(SMALL)
+
+
+def scan_inputs(seed=0, heads=8, dim=16, n=16):
+    seg = jnp.asarray(small_rows()["segment_ids"])
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    shape = seg.shape
+    x = jax.random.normal(keys[0], shape + (heads, dim))
+    dt = jax.nn.softplus(jax.random.normal(keys[1], shape + (heads,)) - 1.0)
+    a = -jnp.exp(jax.random.uniform(keys[2], (heads,), minval=0.0, maxval=2.0))
+    b = jax.random.normal(keys[3], shape + (n,))
+    c = jax.random.normal(keys[4], shape + (n,))
+    return x, dt, a, b, c, seg
+
+
+def sequential_scan(x, dt, a, b, c, seg):
+    """The recurrence as written, one token at a time, in NumPy float64."""
+    x, dt, a, b, c = (np.asarray(v, np.float64) for v in (x, dt, a, b, c))
+    out = np.zeros_like(x)
+    for r in range(x.shape[0]):
+        state = np.zeros(x.shape[2:] + (b.shape[-1],))
+        for t in range(x.shape[1]):
+            if t == 0 or seg[r, t] != seg[r, t - 1]:
+                state[:] = 0.0
+            state = (np.exp(dt[r, t] * a)[:, None, None] * state
+                     + (dt[r, t][:, None] * x[r, t])[:, :, None] * b[r, t])
+            out[r, t] = state @ c[r, t]
+    return out
+
+
+def test_chunked_scan_matches_the_sequential_recurrence():
+    x, dt, a, b, c, seg = scan_inputs()
+    got = ssd_scan(x, dt, a, b, c, seg, chunk=8)
+    want = sequential_scan(x, dt, a, b, c, np.asarray(seg))
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+
+def test_chunked_scan_gradients_match_the_sequential_recurrence(reference):
+    module, _ = reference
+    x, dt, a, b, c, seg = scan_inputs(seed=1)
+    first = jnp.concatenate([jnp.ones((seg.shape[0], 1), bool),
+                             seg[:, 1:] != seg[:, :-1]], axis=1)
+    weights = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+
+    def chunked(x, dt, a, b, c):
+        return jnp.sum(weights * ssd_scan(x, dt, a, b, c, seg, chunk=8))
+
+    def sequential(x, dt, a, b, c):
+        y = jax.vmap(module._scan, in_axes=(0, 0, None, 0, 0, 0))(
+            x, dt, a, b, c, first)
+        return jnp.sum(weights * y)
+
+    got = jax.grad(chunked, argnums=(0, 1, 2, 3, 4))(x, dt, a, b, c)
+    want = jax.grad(sequential, argnums=(0, 1, 2, 3, 4))(x, dt, a, b, c)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=2e-3, atol=2e-3)
+
+
+def test_blocked_attention_matches_plain_masked_softmax(reference):
+    module, _ = reference
+    seg = jnp.asarray(small_rows()["segment_ids"])
+    keys = jax.random.split(jax.random.PRNGKey(2), 3)
+    q = jax.random.normal(keys[0], seg.shape + (4, 16))
+    k = jax.random.normal(keys[1], seg.shape + (2, 16))
+    v = jax.random.normal(keys[2], seg.shape + (2, 16))
+
+    def blocked(q, k, v):
+        return causal_attention(q, k, v, seg, 0.25, block=16)
+
+    def plain(q, k, v):
+        return jax.vmap(lambda q, k, v, s: module._attention(q, k, v, s, 0.25))(
+            q, k, v, seg)
+
+    np.testing.assert_allclose(blocked(q, k, v), plain(q, k, v),
+                               rtol=2e-5, atol=2e-5)
+    got = jax.grad(lambda *a: jnp.sum(jnp.sin(blocked(*a))), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(jnp.sin(plain(*a))), (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5)
+
+
+def test_logits_match_the_reference_on_seeded_weights(reference):
+    _, ref = reference
+    batch = small_rows()
+    model = small_model()
+    tokens, seg = jnp.asarray(batch["tokens"]), jnp.asarray(batch["segment_ids"])
+    params = model.init(jax.random.PRNGKey(5), tokens, seg)["params"]
+    got = model.apply({"params": params}, tokens, seg)
+    want = ref.logits(flat(params), tokens, seg)
+    assert float(jnp.std(want)) > 0.01
+    np.testing.assert_allclose(got, want, rtol=1e-3, atol=1e-4)
+
+
+def test_a_document_sees_nothing_of_its_neighbours():
+    """Changing one document's tokens leaves every other document's logits
+    as they were: the conv's taps, the scan's reset and the attention mask
+    all hold at once."""
+    batch = small_rows()
+    model = small_model()
+    tokens, seg = jnp.asarray(batch["tokens"]), jnp.asarray(batch["segment_ids"])
+    params = model.init(jax.random.PRNGKey(6), tokens, seg)["params"]
+    before = model.apply({"params": params}, tokens, seg)
+    changed = (seg == 1)
+    other = jnp.where(changed, (tokens + 7) % SMALL["vocab_size"], tokens)
+    after = model.apply({"params": params}, other, seg)
+    moved = np.abs(np.asarray(after - before)).max(-1)
+    assert moved[np.asarray(changed)].min() > 1e-4
+    assert moved[~np.asarray(changed)].max() == 0.0
+
+
+def test_published_config_has_3_19_billion_parameters():
+    model = GraniteHybrid(GraniteHybridConfig.from_dict(GRANITE_4_0_H_MICRO))
+    shapes = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 256), jnp.int32),
+                           jnp.zeros((1, 256), jnp.int32)))
+    n = sum(int(np.prod(v.shape)) for v in jax.tree_util.tree_leaves(shapes))
+    assert abs(n - 3.19e9) < 0.01 * 3.19e9
+    assert len(GRANITE_4_0_H_MICRO["layer_types"]) == 40
+    assert GRANITE_4_0_H_MICRO["layer_types"].count("attention") == 4
+
+
+def test_benchmark_file_differs_from_the_published_config_only_where_it_says():
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "granite-4.0-h-micro.json")) as f:
+        cell = json.load(f)
+    assert cell["reduced"] == ["num_hidden_layers", "vocab_size"]
+    differs = [k for k, v in GRANITE_4_0_H_MICRO.items() if cell[k] != v]
+    assert sorted(differs) == cell["reduced"]
+    assert cell["published"] == {k: GRANITE_4_0_H_MICRO[k] for k in cell["reduced"]}
+    assert cell["num_hidden_layers"] == 10 and cell["vocab_size"] * 8 == 100352
+
+
+def test_zoo_holds_the_catalogs_config_key_for_key():
+    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if not os.path.exists(catalog):
+        pytest.skip("the catalog of public architectures is not on this machine")
+    with open(catalog) as f:
+        rows = [json.loads(line) for line in f if line.strip()]
+    (row,) = [r for r in rows if r["name"] == "granite-4.0-h-micro"]
+    assert row["config"] == GRANITE_4_0_H_MICRO
+
+
+def test_packed_rows_have_no_padding_and_weights_stop_at_boundaries():
+    docs = [np.arange(1, 6), np.arange(10, 13), np.arange(20, 40)]
+    out = pack_documents(docs, 8, eos_id=0)
+    np.testing.assert_array_equal(
+        out["tokens"], [[1, 2, 3, 4, 5, 0, 10, 11], [12, 0, 20, 21, 22, 23, 24, 25],
+                        [26, 27, 28, 29, 30, 31, 32, 33]])
+    np.testing.assert_array_equal(
+        out["segment_ids"], [[0] * 6 + [1] * 2, [0] * 2 + [1] * 6, [0] * 8])
+    np.testing.assert_array_equal(
+        out["loss_weights"], [[1, 1, 1, 1, 1, 0, 1, 0], [1, 0, 1, 1, 1, 1, 1, 0],
+                              [1] * 7 + [0]])
+    np.testing.assert_array_equal(out["targets"][:, :-1], out["tokens"][:, 1:])
+
+
+def test_flops_and_bytes_counted_from_shapes():
+    from benchmark import flops_lm
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "granite-4.0-h-micro.json")) as f:
+        cell = json.load(f)
+    mamba = 2048 * 8512 + 4096 * 2048
+    attention = 2 * 2048 * 2048 + 2 * 2048 * 512
+    mlp = 2048 * 16384 + 8192 * 2048
+    assert flops_lm.mamba_matmul_macs(cell) == mamba
+    assert flops_lm.attention_matmul_macs(cell) == attention
+    matmul = 9 * mamba + attention + 10 * mlp + 12544 * 2048
+    assert flops_lm.matmul_macs_per_token(cell) == matmul == cell["matmul_macs_per_token"]
+    assert flops_lm.scan_macs_per_token(cell) == 9 * 3 * 4096 * 128
+    assert cell["train_flops_per_image"] == flops_lm.train_flops_per_sequence(
+        cell) == 6 * 4096 * (matmul + 9 * 3 * 4096 * 128)
+    assert flops_lm.scan_train_flops(cell, 4096) == 6 * 4096 * 9 * 3 * 4096 * 128
+    forward = 2 * (4096 + 128 + 128) + 4 * 64 + 4 * 4096   # x, B, C; dt; y
+    assert flops_lm.scan_train_bytes(cell, 4096) == 2 * forward * 4096 * 9
+    least, bound = flops_lm.scan_roofline_seconds(
+        cell, 4096, {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9})
+    assert bound == "bytes" and 2.0e-3 < least < 2.5e-3
+
+
+def _old_decay_rule(path, x):
+    leaf = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
+    return leaf not in ("bias", "scale")
+
+
+def test_decay_mask_leaves_out_every_vector():
+    from deep_vision_tpu.core.optim import _weight_decay_mask
+
+    batch = small_rows()
+    params = jax.eval_shape(
+        lambda: small_model().init(jax.random.PRNGKey(0), batch["tokens"],
+                                   batch["segment_ids"]))["params"]
+    mask = flat(_weight_decay_mask(params))
+    decayed = {k.rsplit("/", 1)[-1] for k, v in mask.items() if v}
+    spared = {k.rsplit("/", 1)[-1] for k, v in mask.items() if not v}
+    assert decayed == {"kernel", "embedding", "conv_kernel"}
+    assert spared == {"scale", "A_log", "D", "dt_bias", "conv_bias"}
+
+
+@pytest.mark.parametrize("name", ["resnet50", "yolov3_coco"])
+def test_decay_mask_of_the_benchmarks_image_models_is_what_it_was(name):
+    from deep_vision_tpu.core.config import get_config
+    from deep_vision_tpu.core.optim import _weight_decay_mask
+
+    model = get_config(name).model()
+    variables = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 64, 64, 3), jnp.float32), train=False))
+    params = variables["params"]
+    old = jax.tree_util.tree_map_with_path(_old_decay_rule, params)
+    assert flat(_weight_decay_mask(params)) == flat(old)
+
+
+def test_prefetcher_counts_tokens_and_documents(mesh1):
+    from deep_vision_tpu.data.pipeline import DevicePrefetcher
+    from deep_vision_tpu.tasks.language_modeling import LanguageModelingTask
+
+    batch = small_rows(rows=2)
+    docs = int((batch["segment_ids"][:, -1] + 1).sum())
+    with DevicePrefetcher(mesh1) as prefetcher:
+        stream = prefetcher.iterate([batch] * 3,
+                                    counters=LanguageModelingTask.batch_counters)
+        assert len(list(stream)) == 3
+        assert stream.stats()["counters"] == {"tokens": 3 * 2 * LENGTH,
+                                              "documents": 3 * docs}
+
+
+def test_profiled_epoch_puts_the_counters_in_the_spans_header(tmp_path, mesh1):
+    from deep_vision_tpu.core.config import get_config
+    from deep_vision_tpu.core.trainer import Trainer
+    from deep_vision_tpu.tasks.language_modeling import LanguageModelingTask
+
+    cfg = get_config("granite_4_0_h_micro")
+    cfg.extra["architecture"].update(SMALL)
+    cfg.extra["sequence_length"] = LENGTH
+    cfg.half_precision, cfg.batch_size, cfg.log_every_steps = False, 2, 2
+    trainer = Trainer(cfg, cfg.model(), LanguageModelingTask(), mesh=mesh1,
+                      workdir=str(tmp_path))
+    batch = small_rows(rows=2)
+    state = trainer.init_state(batch)
+    trainer.profile_steps = (1, 3)
+    state = trainer.train_epoch(state, [batch] * 4, trainer.start_epoch)
+    assert int(state.step) == 4 and int(state.bad_steps) == 0
+    with open(tmp_path / "spans.jsonl") as f:
+        header = json.loads(f.readline())
+    assert header["tokens"] == 4 * 2 * LENGTH and header["batches"] == 4
+    assert header["documents"] == 4 * int((batch["segment_ids"][:, -1] + 1).sum())
+    series = {}
+    with open(tmp_path / "metrics.jsonl") as f:
+        for line in f:
+            row = json.loads(line)
+            series[row["name"]] = row["value"]
+    assert series["input_tokens_per_step"] == 2 * LENGTH
+    assert np.isfinite(series["train_loss"]) and "train_token_accuracy" in series
+
+
+def test_cli_trains_three_steps_at_the_test_size(tmp_path, capsys):
+    from deep_vision_tpu.cli import train
+
+    overrides = [f"{k}={json.dumps(v)}" for k, v in SMALL.items()
+                 if GRANITE_4_0_H_MICRO[k] != v]
+    overrides.append(f"sequence_length={LENGTH}")
+    argv = ["-m", "granite_4_0_h_micro", "--synthetic", "--synthetic-size", "3",
+            "--epochs", "1", "--mesh", "data=1", "--workdir", str(tmp_path)]
+    for item in overrides:
+        argv += ["--override", item]
+    assert train.main(argv) == 0
+    out = capsys.readouterr().out
+    assert "final:" in out and "token_accuracy" in out
+    steps = set()
+    with open(tmp_path / "metrics.jsonl") as f:
+        for line in f:
+            row = json.loads(line)
+            if row["name"] == "train_loss":
+                steps.add(row["step"])
+                assert np.isfinite(row["value"])
+    assert max(steps) == 3
+    with pytest.raises(SystemExit):
+        train.main(argv + ["--override", "no_such_key=1"])
