@@ -18,8 +18,19 @@ and SiLU on ``xBC``, the scan (``ops/ssd.py``), ``D`` skip, the gate before
 the norm, ``out_proj``.  The conv, the scan's state and the attention mask
 all start anew at a document's first token (``segment_ids``).
 
-Each layer is rematerialised in the backward pass (``nn.remat``): what is
-kept between the passes is one ``(B, L, hidden)`` input a layer.  Scopes
+Each layer is rematerialised in the backward pass (``RematLayer``).  What
+is kept between the passes is, a layer, its ``(B, L, hidden)`` input and the
+bfloat16 tensors ``KEPT`` names: the outputs of the products against a
+weight matrix that the backward pass reads (``in_proj`` and ``out_proj`` of
+a Mamba mixer, ``q/k/v`` and ``o_proj`` of attention, the MLP's
+``in_proj``), so that no such product runs twice, and the blocked
+attention's output, so that its blocks are computed again once (by
+``ops/attention.py``'s own checkpoint) and not twice.  Norms, conv, SiLU,
+the scan with its ``(chunk, chunk)`` decays and the SwiGLU product are
+computed again.  The kept set costs 532 KB a token for the ten layers of a
+pipeline stage at the published widths (527 of them the products'
+outputs): 2.0 GiB at 4,096 tokens a step, 4.4 GB at 8,192, which has not
+been tried.  Scopes
 ``embed``, ``mamba`` (``ssd`` inside it, around the scan only),
 ``attention``, ``mlp`` and ``lm_head`` name the parts in a device trace; a
 layer's norm and residual go by its mixer's or its MLP's scope.
@@ -34,6 +45,7 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from deep_vision_tpu.ops.attention import causal_attention
 from deep_vision_tpu.ops.ssd import ssd_scan
@@ -94,6 +106,12 @@ class GraniteHybridConfig:
         return self.mamba_n_heads * self.mamba_d_head
 
 
+# what a rematerialised layer keeps between the passes beside its input:
+# the outputs of these products against a weight matrix, and the attention's
+KEPT = ("mixer_in_proj", "mixer_out_proj", "q_proj", "k_proj", "v_proj",
+        "attention_out", "o_proj", "ffn_in_proj")
+
+
 def _normal():
     return nn.initializers.normal(0.02)
 
@@ -151,9 +169,10 @@ class MambaMixer(nn.Module):
         cfg, f32 = self.cfg, jnp.float32
         heads, dim, n = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
         inner, conv_dim = cfg.d_inner, cfg.d_inner + 2 * n
-        zxbcdt = nn.Dense(inner + conv_dim + heads, use_bias=False,
-                          dtype=self.dtype, kernel_init=_normal(),
-                          name="in_proj")(u)
+        zxbcdt = checkpoint_name(
+            nn.Dense(inner + conv_dim + heads, use_bias=False,
+                     dtype=self.dtype, kernel_init=_normal(),
+                     name="in_proj")(u), "mixer_in_proj")
         z, xbc, dt = jnp.split(zxbcdt, [inner, inner + conv_dim], axis=-1)
         # torch's Conv1d default, which the published implementation leaves:
         # uniform within 1 / sqrt(taps) of zero for a depthwise conv
@@ -171,8 +190,10 @@ class MambaMixer(nn.Module):
         y = y + self.param("D", nn.initializers.ones, (heads,))[:, None] * x.astype(f32)
         y = y.reshape(*y.shape[:2], inner) * nn.silu(z.astype(f32))
         y = RMSNorm(cfg.rms_norm_eps, self.dtype, name="norm")(y)
-        return nn.Dense(cfg.hidden_size, use_bias=False, dtype=self.dtype,
-                        kernel_init=_normal(), name="out_proj")(y)
+        return checkpoint_name(
+            nn.Dense(cfg.hidden_size, use_bias=False, dtype=self.dtype,
+                     kernel_init=_normal(), name="out_proj")(y),
+            "mixer_out_proj")
 
 
 class AttentionMixer(nn.Module):
@@ -185,18 +206,21 @@ class AttentionMixer(nn.Module):
         cfg = self.cfg
 
         def proj(heads, name):
-            y = nn.Dense(heads * cfg.head_dim, use_bias=False, dtype=self.dtype,
-                         kernel_init=_normal(), name=name)(u)
+            y = checkpoint_name(
+                nn.Dense(heads * cfg.head_dim, use_bias=False, dtype=self.dtype,
+                         kernel_init=_normal(), name=name)(u), name)
             return y.reshape(*y.shape[:2], heads, cfg.head_dim)
 
-        out = causal_attention(
+        out = checkpoint_name(causal_attention(
             proj(cfg.num_attention_heads, "q_proj"),
             proj(cfg.num_key_value_heads, "k_proj"),
             proj(cfg.num_key_value_heads, "v_proj"),
-            segment_ids, cfg.attention_multiplier, self.attention_block)
-        return nn.Dense(cfg.hidden_size, use_bias=False, dtype=self.dtype,
-                        kernel_init=_normal(), name="o_proj")(
-                            out.reshape(*out.shape[:2], -1))
+            segment_ids, cfg.attention_multiplier, self.attention_block),
+            "attention_out")
+        return checkpoint_name(
+            nn.Dense(cfg.hidden_size, use_bias=False, dtype=self.dtype,
+                     kernel_init=_normal(), name="o_proj")(
+                         out.reshape(*out.shape[:2], -1)), "o_proj")
 
 
 class SwiGLU(nn.Module):
@@ -206,9 +230,10 @@ class SwiGLU(nn.Module):
     @nn.compact
     def __call__(self, u):
         width = self.cfg.shared_intermediate_size
-        gate, value = jnp.split(
+        gate, value = jnp.split(checkpoint_name(
             nn.Dense(2 * width, use_bias=False, dtype=self.dtype,
-                     kernel_init=_normal(), name="in_proj")(u), 2, axis=-1)
+                     kernel_init=_normal(), name="in_proj")(u),
+            "ffn_in_proj"), 2, axis=-1)
         return nn.Dense(self.cfg.hidden_size, use_bias=False, dtype=self.dtype,
                         kernel_init=_normal(), name="out_proj")(nn.silu(gate) * value)
 
@@ -238,6 +263,10 @@ class GraniteLayer(nn.Module):
         return h
 
 
+RematLayer = nn.remat(
+    GraniteLayer, policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
+
+
 class GraniteHybrid(nn.Module):
     """``tokens``, ``segment_ids`` (B, L) int32 -> logits (B, L, vocab)
     float32.  ``train`` is accepted for the trainer's sake: nothing in the
@@ -255,10 +284,9 @@ class GraniteHybrid(nn.Module):
         with jax.named_scope("embed"):
             h = table.astype(self.dtype)[tokens] * jnp.asarray(
                 cfg.embedding_multiplier, self.dtype)
-        layer = nn.remat(GraniteLayer)
         for i, kind in enumerate(cfg.layer_types):
-            h = layer(cfg, kind, self.attention_block, self.dtype,
-                      name=f"layer_{i}")(h, segment_ids)
+            h = RematLayer(cfg, kind, self.attention_block, self.dtype,
+                           name=f"layer_{i}")(h, segment_ids)
         with jax.named_scope("lm_head"):
             h = RMSNorm(cfg.rms_norm_eps, self.dtype, name="final_norm")(h)
             logits = jnp.einsum("bld,vd->blv", h, table.astype(self.dtype),

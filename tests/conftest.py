@@ -42,6 +42,24 @@ def host_devices():
     return devs
 
 
+def _equations(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else (value,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub)
+
+
+@pytest.fixture(scope="session")
+def jaxpr_equations():
+    """``walk(jaxpr)``: every equation of a jaxpr and of the jaxprs nested
+    in its equations' parameters (pjit, remat, custom_jvp, pallas_call, ...)."""
+    return _equations
+
+
 # The dvtlint runtime half (docs/ANALYSIS.md): every chaos/gateway/replicas
 # test runs with DVT_LOCK_SANITIZER semantics on — serve/* locks become
 # SanitizedLocks recording acquisition order, and the test FAILS at teardown

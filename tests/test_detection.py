@@ -343,19 +343,6 @@ def test_yolo_loss_equals_slice_based_oracle(grid, num_classes, use_pallas):
     np.testing.assert_allclose(got_grad, want_grad, rtol=1e-5, atol=1e-7)
 
 
-def _equations(jaxpr):
-    """Every equation of a jaxpr and of the jaxprs nested in its
-    equations' parameters (pjit, custom_jvp, pallas_call, ...)."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for value in eqn.params.values():
-            for sub in (value if isinstance(value, (list, tuple))
-                        else (value,)):
-                sub = getattr(sub, "jaxpr", sub)
-                if hasattr(sub, "eqns"):
-                    yield from _equations(sub)
-
-
 def _cuts_lanes(eqn, lanes=128):
     """Does this slice/split/concatenate/gather cut or join its operands
     along their last axis, with a piece narrower than the chip's lanes?
@@ -377,7 +364,8 @@ def _cuts_lanes(eqn, lanes=128):
 
 
 @pytest.mark.parametrize("with_grad", [False, True], ids=["loss", "grad"])
-def test_yolo_loss_never_slices_the_lane_axis_of_a_full_array(with_grad):
+def test_yolo_loss_never_slices_the_lane_axis_of_a_full_array(
+        with_grad, jaxpr_equations):
     """Traced (not run) at the shapes of ``yolov3-416-train-b64``: no
     slice, split, concatenate or gather takes an operand of a whole
     plane's size or more (B·N elements) apart along its last axis into
@@ -402,7 +390,7 @@ def test_yolo_loss_never_slices_the_lane_axis_of_a_full_array(with_grad):
 
     smallest_plane = batch * 3 * min(grids) ** 2
     seen, bad = set(), []
-    for eqn in _equations(jaxpr):
+    for eqn in jaxpr_equations(jaxpr):
         name = eqn.primitive.name
         seen.add(name)
         if name not in ("slice", "dynamic_slice", "split", "concatenate",
@@ -416,13 +404,13 @@ def test_yolo_loss_never_slices_the_lane_axis_of_a_full_array(with_grad):
     assert not bad, bad
 
 
-def test_lane_axis_check_catches_the_old_formulation():
+def test_lane_axis_check_catches_the_old_formulation(jaxpr_equations):
     """The same walk over the slice-based oracle does flag it."""
     raw = jax.ShapeDtypeStruct((4, 13, 13, 3, 8), jnp.float32)
     jaxpr = jax.make_jaxpr(lambda r: _slice_based_scale_loss(
         r, r, jnp.zeros((4, 5, 4)), jnp.ones((4, 5)),
         jnp.ones((3, 2)))[0])(raw).jaxpr
-    cuts = [e for e in _equations(jaxpr)
+    cuts = [e for e in jaxpr_equations(jaxpr)
             if e.primitive.name in ("slice", "split", "concatenate")
             and max(v.aval.size for v in e.invars) >= 4 * 507
             and _cuts_lanes(e)]

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from deep_vision_tpu.data.text import pack_documents, synthetic_corpus
+from deep_vision_tpu.models import granite_hybrid
 from deep_vision_tpu.models.granite_hybrid import (
     GraniteHybrid,
     GraniteHybridConfig,
@@ -165,6 +166,89 @@ def test_a_document_sees_nothing_of_its_neighbours():
     moved = np.abs(np.asarray(after - before)).max(-1)
     assert moved[np.asarray(changed)].min() > 1e-4
     assert moved[~np.asarray(changed)].max() == 0.0
+
+
+def remat_layer(kind):
+    """One rematerialised layer, its float32 loss over ``(params, h)`` and
+    those arguments."""
+    cfg = GraniteHybridConfig.from_dict(SMALL)
+    seg = jnp.asarray(small_rows()["segment_ids"])
+    h = jax.random.normal(jax.random.PRNGKey(7), seg.shape + (cfg.hidden_size,))
+    layer = granite_hybrid.RematLayer(cfg, kind, 16, jnp.float32)
+    params = layer.init(jax.random.PRNGKey(8), h, seg)["params"]
+
+    def loss(params, h):
+        return jnp.sum(jnp.sin(layer.apply({"params": params}, h, seg)))
+
+    return cfg, loss, params, h
+
+
+@pytest.mark.parametrize("kind, dense", [("mamba", 4), ("attention", 6)])
+def test_backward_pass_runs_no_dense_product_twice(kind, dense,
+                                                   jaxpr_equations):
+    """A product against a weight matrix is the one ``dot_general`` without
+    batch dimensions (the scan's and the attention's own carry the batch
+    and the heads): forward, dx and dW a ``Dense`` and no fourth.  With a
+    bare ``nn.remat`` the counts were 15 and 23."""
+    _, loss, params, h = remat_layer(kind)
+    jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1)))(params, h)
+    products = [e for e in jaxpr_equations(jaxpr.jaxpr)
+                if e.primitive.name == "dot_general"
+                and not e.params["dimension_numbers"][1][0]]
+    assert len(products) == 3 * dense
+
+
+@pytest.mark.parametrize("kind", ["mamba", "attention"])
+def test_layer_keeps_its_input_and_its_dense_outputs(kind, capsys):
+    """Beside parameters, constants and the layer's input (and the cosine
+    this test's own loss keeps), what is kept between the passes is what
+    ``KEPT`` names: the output of each product against a weight matrix that
+    the backward pass reads and the blocked attention's, and nothing with a
+    ``(chunk, chunk)`` face."""
+    cfg, loss, params, h = remat_layer(kind)
+    jax.ad_checkpoint.print_saved_residuals(loss, params, h)
+    kept = []
+    for line in capsys.readouterr().out.splitlines():
+        aval, where = line.split(" ", 1)
+        if where.startswith(("from the argument", "from a constant")) \
+                or "output of cos" in where:
+            continue
+        kept.append(tuple(int(n) for n in aval.split("[")[1][:-1].split(",")))
+    rows = h.shape[:2]
+    mlp = rows + (2 * cfg.shared_intermediate_size,)
+    if kind == "mamba":
+        want = [rows + (2 * cfg.d_inner + 2 * cfg.mamba_d_state + cfg.mamba_n_heads,),
+                rows + (cfg.hidden_size,), mlp]
+    else:
+        kv = rows + (cfg.num_key_value_heads * cfg.head_dim,)
+        heads = rows + (cfg.num_attention_heads, cfg.head_dim)
+        want = [rows + (cfg.hidden_size,), kv, kv, heads,
+                rows + (cfg.hidden_size,), mlp]
+    assert kept == want
+    chunk = cfg.mamba_chunk_size
+    assert not any(shape[-2:] == (chunk, chunk) for shape in kept)
+
+
+def test_gradients_equal_those_of_the_model_without_remat(monkeypatch):
+    batch = small_rows()
+    tokens, seg = jnp.asarray(batch["tokens"]), jnp.asarray(batch["segment_ids"])
+    model = GraniteHybrid(GraniteHybridConfig.from_dict(dict(
+        SMALL, layer_types=["mamba", "attention"], num_hidden_layers=2)),
+        attention_block=16, dtype=jnp.float32)
+    params = model.init(jax.random.PRNGKey(4), tokens, seg)["params"]
+
+    def grads():
+        return flat(jax.grad(lambda p: jnp.sum(jnp.sin(
+            model.apply({"params": p}, tokens, seg))))(params))
+
+    got = grads()
+    monkeypatch.setattr(granite_hybrid, "RematLayer", granite_hybrid.GraniteLayer)
+    want = grads()
+    assert got.keys() == want.keys()
+    for leaf, w in want.items():
+        assert float(jnp.linalg.norm(w)) > 0
+        assert float(jnp.linalg.norm(got[leaf] - w)) <= 1e-6 * float(
+            jnp.linalg.norm(w)), leaf
 
 
 def test_published_config_has_3_19_billion_parameters():
