@@ -228,69 +228,12 @@ serve-chaos:
 serve_%:
 	$(PY) -m deep_vision_tpu.cli.serve -m $* --workdir $(WORKDIR)/$*
 
-bench-serve:
-	$(PY) bench.py --serve
-
-# the synchronous comparison run: same loads, in-flight window of 1
-bench-serve-sync:
-	$(PY) bench.py --serve --serve-pipeline-depth 1
-
-# device-scaling sweep: img/s + p99 at replica counts 1, 2, 4, 8
-# (docs/PERF.md "Device scaling"); >1.6x at 1->2 expected on real
-# multi-chip hardware, routing overhead on a single shared device
-bench-serve-scaling:
-	$(PY) bench.py --serve --serve-devices 8
-
-# mesh-cell sweep: 1x1 / 4x1 / 1x4 / 2x2 data x model cells over 4
-# (forced) host devices — img/s, p99, and per-chip param_shard_bytes
-# per cell (docs/PERF.md "Mesh scaling"); the 1x4 cell must report
-# per-chip bytes strictly below the replicated footprint
-bench-serve-mesh:
-	$(PY) bench.py --serve-mesh 4
-
-# wire-format comparison: {float32, uint8} wire x {float32, bfloat16,
-# int8} compute — p50/p95/p99, img/s, H2D bytes/batch, and resident
-# weight bytes per cell (docs/PERF.md "Wire format & inference
-# dtype"); the uint8 wire must show exactly 4x fewer H2D bytes than
-# float32 and the int8 cells <= 0.27x the f32 weight HBM
-bench-serve-wire:
-	$(PY) bench.py --serve --serve-wire
-
-# offline batch tier bench: bulk-job drain on the 2x2 mesh cell
-# (batch img/s, occupancy, occupancy-weighted MFU) plus the
-# interactive-vs-batch interference sweep (docs/PERF.md "Batch tier",
-# docs/BATCH.md)
-bench-serve-batch:
-	$(PY) bench.py --serve-batch
-
-# continuous-deploy reaction bench: checkpoint durable -> new version
-# ACTIVE under live load (debounce + gate + canary), plus autoscale
-# scale-up/scale-down reaction (docs/PERF.md "Deploy reaction")
-bench-deploy:
-	$(PY) bench.py --deploy
-
-# gateway failover bench: backends behind serve/gateway.py, one
-# hard-killed a third into the top load point — reports errors after
-# the kill (contract: 0), breaker-open latency, and the worst client
-# latency in the 1 s post-kill window (docs/PERF.md)
-bench-gateway:
-	$(PY) bench.py --gateway
-
+# the repo's one benchmark (BENCHMARK.json, PERF.md §2-§4): one plain
+# run of its first cell, on the chip; the last stdout line is the result.
+# The other cells are the same command under another --workload
 bench:
-	$(PY) bench.py
-
-bench-all:
-	$(PY) bench.py --all
-
-bench-pipeline:
-	$(PY) bench.py --pipeline
-
-# train-input goodput sweep: {uint8, float32} wire x prefetch depth
-# {1, 2, 4} through the staged DevicePrefetcher — img/s, input stall
-# fraction, H2D bytes/step per cell (docs/PERF.md "Input pipeline");
-# the uint8 wire must show exactly 4x fewer image H2D bytes
-bench-input:
-	$(PY) bench.py --input
+	$(PY) benchmark/run.py --workload resnet50-train-b256 \
+		--seed 2147484101 --seconds 20 --trace 0
 
 train_%:
 	$(PY) -m deep_vision_tpu.cli.train -m $* --data-root $(DATA) \
@@ -311,10 +254,7 @@ eval_%:
 list:
 	$(PY) -m deep_vision_tpu.cli.train --list -m x
 
-.PHONY: test test-all bench bench-serve bench-serve-sync \
-	bench-serve-scaling bench-serve-mesh bench-serve-wire \
-	bench-serve-batch bench-gateway bench-deploy \
-	bench-input serve-smoke \
+.PHONY: test test-all bench serve-smoke \
 	serve-multi serve-chaos gateway-smoke gateway-test obs-smoke \
 	edge-smoke edge-test input-smoke input-test \
 	obs-test model-smoke model-test quant-smoke quant-test \

@@ -197,18 +197,18 @@ def phase_kernels(sz: Sizes, interpret: bool, n_devices: int) -> list[dict]:
 # ----------------------------------------------------------------- 2. train
 
 def pack_records(tmp: str, sz: Sizes) -> str:
-    """Seeded synthetic JPEGs → raw-uint8 dvrec shards: the recipe
-    ``bench.py --coupled`` uses, so the trainer reads real records over
-    the uint8 wire instead of ``--synthetic``'s float arrays."""
-    from bench import _make_synthetic_imagenet
+    """Seeded synthetic JPEGs → raw-uint8 dvrec shards, so the trainer
+    reads real records over the uint8 wire instead of ``--synthetic``'s
+    float arrays."""
     from deep_vision_tpu.data.native import load as load_native
     from deep_vision_tpu.data.prep import prepare_imagenet
+    from deep_vision_tpu.data.synthetic import make_synthetic_imagenet
     from deep_vision_tpu.data.transforms import imagenet_resize_for
 
     t0 = time.monotonic()
     # source JPEGs at the stored size: packing decodes, never rescales
     resize = imagenet_resize_for(sz.image_size)
-    root, labels, val_root = _make_synthetic_imagenet(
+    root, labels, val_root = make_synthetic_imagenet(
         tmp, sz.train_images, resize, val_images=sz.val_images)
     recs = os.path.join(tmp, "recs")
     for split, src in (("train", root), ("val", val_root)):

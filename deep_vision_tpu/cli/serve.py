@@ -64,8 +64,7 @@ def _edge_kwargs(args):
     """Shared ServeServer edge wiring for both build paths.
 
     The selector event loop is the default front-end; --thread-server
-    restores the thread-per-request baseline (the A/B foil in
-    docs/PERF.md).  The response cache and tenant QoS stay OFF unless
+    restores the thread-per-request baseline.  The response cache and tenant QoS stay OFF unless
     asked for, so single-purpose smokes keep their exact span/counter
     expectations."""
     from deep_vision_tpu.serve.admission import TenantQoS
@@ -854,7 +853,7 @@ def main(argv=None):
     p.add_argument("--thread-server", action="store_true",
                    help="serve with the original thread-per-request "
                         "ThreadingHTTPServer instead of the selector "
-                        "event loop (the A/B baseline in docs/PERF.md; "
+                        "event loop (the baseline: "
                         "no keep-alive pooling, no connection bound)")
     p.add_argument("--max-connections", type=int, default=1024,
                    help="edge loop: open-connection ceiling — at "

@@ -31,9 +31,6 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--workdir", default=None)
     p.add_argument("--epochs", type=int, default=None, help="override config")
     p.add_argument("--batch-size", type=int, default=None, help="override config")
-    p.add_argument("--scan-steps", type=int, default=None,
-                   help="train steps per device dispatch (lax.scan "
-                        "multi-step; amortizes host dispatch overhead)")
     p.add_argument("--grad-accum", type=int, default=None,
                    help="gradient-accumulation microbatches per optimizer "
                         "update (full recipe batch on a fraction of HBM)")
@@ -122,8 +119,6 @@ def main(argv=None):
         cfg.total_epochs = args.epochs
     if args.batch_size is not None:
         cfg.batch_size = cfg.eval_batch_size = args.batch_size
-    if args.scan_steps is not None:
-        cfg.scan_steps = args.scan_steps
     if args.grad_accum is not None:
         cfg.grad_accum_steps = args.grad_accum
     if args.ema_decay is not None:
@@ -253,7 +248,7 @@ def main(argv=None):
         final = trainer.evaluate(state, val_loader)
     finally:
         # the ImageNet loaders own decode worker pools: a caller that
-        # outlives main() (bench.py, chip_smoke.py) must not inherit them
+        # outlives main() (chip_smoke.py) must not inherit them
         for loader in (train_loader, val_loader):
             if hasattr(loader, "close"):
                 loader.close()

@@ -30,7 +30,6 @@ from deep_vision_tpu.parallel import (
     make_mesh,
     replicate,
     shard_batch,
-    shard_batch_stacked,
 )
 
 
@@ -65,7 +64,6 @@ class AdversarialTrainer:
 
             self.uploader = ArtifactUploader(upload)
         self._jit_step = None
-        self._jit_multi = None
         self.start_epoch = 1
         self.start_step = 0
         self.guard = DivergenceGuard(config.max_bad_steps)
@@ -162,33 +160,6 @@ class AdversarialTrainer:
                 donate_argnums=(0, 1))
         return self._jit_step(states, shard_batch(batch, self.mesh), rng)
 
-    def train_multi(self, states, stacked, rng):
-        """K coupled G/D updates per device dispatch (``config.scan_steps``)
-        for tasks that declare ``scan_safe`` (no host state between steps:
-        DCGAN's twin-tape step; CycleGAN's per-step ImagePool exchange
-        forces per-step dispatch).  Metrics come back (K,)-leaved so the
-        divergence guard still sees every step.  The rng key threads
-        through the scan carry with the SAME per-step split as the
-        per-step path and comes back out, so scan_steps=K trains
-        identically to scan_steps=1 (up to XLA float reassociation)."""
-        if self._jit_multi is None:
-            guarded = self._guarded_step(self.task.train_step)
-
-            def multi(states, stacked, rng):
-                def body(carry, batch):
-                    states, rng = carry
-                    rng, step_rng = jax.random.split(rng)
-                    states, _, metrics = guarded(states, batch, step_rng)
-                    return (states, rng), metrics
-
-                (states, rng), metrics = jax.lax.scan(body, (states, rng),
-                                                      stacked)
-                return states, metrics, rng
-
-            self._jit_multi = jax.jit(multi, donate_argnums=0)
-        return self._jit_multi(
-            states, shard_batch_stacked(stacked, self.mesh), rng)
-
     def fit(self, train_data: Iterable, epochs: int | None = None,
             states: dict | None = None, resume: bool = False,
             sample_hook=None) -> dict:
@@ -232,8 +203,6 @@ class AdversarialTrainer:
 
     def _fit_epochs(self, train_data, epochs, states, rng, step, sample_hook):
         cfg = self.config
-        K = getattr(cfg, "scan_steps", 1) or 1
-        use_scan = K > 1 and getattr(self.task, "scan_safe", False)
         for epoch in range(self.start_epoch, epochs + 1):
             lr = self.scheduler.epoch_begin(epoch)
             states = {k: v.replace(
@@ -243,12 +212,8 @@ class AdversarialTrainer:
                 train_data.set_epoch(epoch)
             meter = ThroughputMeter()
             t0 = time.monotonic()
-            if use_scan:
-                states, rng, step, aborted = self._epoch_scan(
-                    train_data, states, rng, step, epoch, K, meter)
-            else:
-                states, rng, step, aborted = self._epoch_steps(
-                    train_data, states, rng, step, epoch, meter)
+            states, rng, step, aborted = self._epoch_steps(
+                train_data, states, rng, step, epoch, meter)
             if aborted:
                 return states
             # drain the async dispatch chain (cheap scalar that depends on
@@ -317,62 +282,3 @@ class AdversarialTrainer:
         finally:
             if stream is not None:
                 self._log_input_stats(step, stream.stats(), epoch)
-
-    def _epoch_scan(self, train_data, states, rng, step, epoch, K, meter):
-        """K-step-per-dispatch epoch for scan_safe tasks: host batches are
-        stacked K at a time, one jitted ``lax.scan`` applies all K coupled
-        G/D updates (DCGAN at 28² is dispatch-bound: its device step is
-        short next to the per-dispatch host cost).  The previous group's
-        metrics fetch stays in flight while the next group runs (the Trainer's
-        pending pattern), the guard still sees every step, and a trailing
-        ragged group falls back to per-step dispatch."""
-        import numpy as np
-
-        cfg = self.config
-        buf: list[dict] = []
-        pending = None  # (step_after_group, (K,)-leaved device metrics)
-
-        def drain(pending):
-            if pending is None:
-                return
-            at, dev_ms = pending
-            ms = {k: np.asarray(v) for k, v in jax.device_get(dev_ms).items()}
-            for j in range(next(iter(ms.values())).shape[0]):
-                self.guard.check({k: float(v[j]) for k, v in ms.items()})
-            self.logger.log_dict(at, {k: float(v[-1]) for k, v in ms.items()})
-            print(f"Epoch {epoch} Step {at} "
-                  + " ".join(f"{k}={v[-1]:.4f}" for k, v in ms.items())
-                  + f" {meter.images_per_sec:.1f} img/s", flush=True)
-
-        for batch in train_data:
-            buf.append(self.task.host_prepare(batch))
-            meter.update(len(next(iter(batch.values()))))
-            if len(buf) == K:
-                stacked = jax.tree_util.tree_map(
-                    lambda *xs: np.stack(xs), *buf)
-                states, dev_ms, rng = self.train_multi(states, stacked, rng)
-                step += len(buf)
-                buf = []
-                drain(pending)  # previous group — overlaps current dispatch
-                pending = (step, dev_ms)
-            if self._preempted:
-                drain(pending)
-                pending = None
-                for b in buf:  # partial group per-step for exactness
-                    rng, srng = jax.random.split(rng)
-                    states, _, _ = self.train_step(states, b, srng)
-                    step += 1
-                self._preempt_save(step, states, epoch)
-                return states, rng, step, True
-        drain(pending)
-        for b in buf:  # ragged tail: per-step dispatch (same logging)
-            rng, srng = jax.random.split(rng)
-            states, outputs, metrics = self.train_step(states, b, srng)
-            self.task.host_update(outputs)
-            step += 1
-            if step % cfg.log_every_steps == 0:
-                self._log_step(epoch, step, metrics, meter)
-            if self._preempted:
-                self._preempt_save(step, states, epoch)
-                return states, rng, step, True
-        return states, rng, step, False

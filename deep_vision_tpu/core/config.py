@@ -43,10 +43,6 @@ class TrainConfig:
     # divergence guard: non-finite steps are skipped + counted; the run
     # halts with a clear error once more than this many were skipped
     max_bad_steps: int = 100
-    # multi-step dispatch: run this many train steps per device program
-    # (one lax.scan) — amortizes per-dispatch host overhead; logging/
-    # guard/preemption work at K-step granularity. 1 = step-per-dispatch.
-    scan_steps: int = 1
     # gradient accumulation: split each global batch into this many
     # sequential microbatches inside the jitted step, averaging grads
     # before the single optimizer update — the full recipe batch on a

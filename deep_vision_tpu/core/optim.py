@@ -37,7 +37,7 @@ class OptimizerConfig:
     grad_clip_norm: float | None = None
     # SGD momentum accumulator storage dtype (None = param dtype, f32).
     # "bfloat16" halves the optimizer-state HBM traffic in the elementwise
-    # band of the step — a measured experiment, see docs/PERF.md; changes
+    # band of the step — its static memory count is in docs/PERF.md; changes
     # update numerics (~1e-3 relative), so NOT part of the parity recipe.
     momentum_dtype: str | None = None
 

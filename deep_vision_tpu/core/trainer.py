@@ -117,7 +117,6 @@ class Trainer:
             os.path.join(self.workdir, "checkpoints_best"), max_to_keep=1)
         self._has_bn: bool | None = None
         self._jit_train_step = None
-        self._jit_train_multi = None
         self._jit_eval_step = None
         self.start_epoch = 1
         self.guard = DivergenceGuard(config.max_bad_steps)
@@ -400,24 +399,6 @@ class Trainer:
         self._jit_train_step = jax.jit(train_step, donate_argnums=(0, 1))
         self._jit_eval_step = jax.jit(eval_step)
 
-        # multi-step dispatch (config.scan_steps > 1): K steps per device
-        # program via lax.scan over stacked batches — per-dispatch host
-        # overhead amortizes K×.
-        # Metrics come back per step ((K,)-leaved tree) so the guard still
-        # sees every step.
-        if getattr(self.config, "scan_steps", 1) > 1:
-            def multi_train_step(state: TrainState, batches: dict):
-                def body(s, b):
-                    return train_step(s, b)
-
-                # unroll=2: halves loop-trip overhead and lets XLA overlap
-                # step i's update with step i+1's first convs (bench.py:
-                # 99.6 ms/step vs 101.1 at unroll=1 on the v5e)
-                return jax.lax.scan(body, state, batches, unroll=2)
-
-            self._jit_train_multi = jax.jit(multi_train_step,
-                                            donate_argnums=(0, 1))
-
     def train_step(self, state, batch):
         if self._jit_train_step is None:
             self._build_steps()
@@ -547,8 +528,6 @@ class Trainer:
     def train_epoch(self, state: TrainState, train_data: Iterable,
                     epoch: int) -> TrainState:
         cfg = self.config
-        if getattr(cfg, "scan_steps", 1) > 1:
-            return self._train_epoch_scan(state, train_data, epoch)
         meter = ThroughputMeter()
         pending = None  # async metric fetch: log step N-1 while N runs
         profiling = self.profile_steps if epoch == self.start_epoch else None
@@ -613,76 +592,6 @@ class Trainer:
             self._write_spans(stream, clock)
         self.logger.log("images_per_sec", int(state.step), meter.images_per_sec)
         self._log_input_stats(int(state.step), stream.stats(), epoch)
-        return state
-
-    def _train_epoch_scan(self, state: TrainState, train_data: Iterable,
-                          epoch: int) -> TrainState:
-        """K-step-per-dispatch epoch (``config.scan_steps``): host batches
-        are stacked K at a time and one jitted ``lax.scan`` program applies
-        all K optimizer updates.  Logging, the divergence guard, and
-        preemption run at K-step granularity; a trailing ragged group falls
-        back to the single-step path.  ``--profile`` tracing is per-step
-        and is not supported in this mode."""
-        import numpy as np
-
-        from deep_vision_tpu.parallel import shard_batch_stacked
-
-        cfg = self.config
-        K = cfg.scan_steps
-        if self._jit_train_multi is None:
-            self._build_steps()
-        meter = ThroughputMeter()
-        pending = None  # async per-step metric fetch from the PREVIOUS group
-        group = 0
-        buf: list[dict] = []
-
-        def dispatch(state, buf):
-            stacked = jax.tree_util.tree_map(
-                lambda *xs: np.stack(xs), *buf)
-            return self._jit_train_multi(
-                state, shard_batch_stacked(stacked, self.mesh))
-
-        def log_pending(ms, last_step):
-            # ms: (K,)-leaved metric tree — guard sees EVERY step
-            ms = {k: np.asarray(v) for k, v in jax.device_get(ms).items()}
-            for j in range(next(iter(ms.values())).shape[0]):
-                self.guard.check({k: float(v[j]) for k, v in ms.items()})
-            self.logger.log_dict(
-                last_step,
-                {f"train_{k}": float(v[-1]) for k, v in ms.items()})
-            print(f"Epoch {epoch} Group {group} loss {ms['loss'][-1]:.4f} "
-                  f"lr {self.scheduler.lr:.2e} "
-                  f"{meter.images_per_sec:.1f} img/s", flush=True)
-
-        for batch in train_data:
-            buf.append(batch)
-            if len(buf) < K:
-                continue
-            n_imgs = sum(len(jax.tree_util.tree_leaves(b)[0]) for b in buf)
-            state, metrics = dispatch(state, buf)
-            buf = []
-            meter.update(n_imgs)
-            if pending is not None:
-                log_pending(pending, int(state.step) - K)
-            pending = metrics
-            group += 1
-            if self._preempted:
-                print("[preempt] SIGTERM — stopping at group boundary",
-                      flush=True)
-                break
-        if pending is not None:
-            log_pending(pending, int(state.step))
-        # ragged tail (< K batches): single-step dispatches
-        for batch in buf:
-            if self._preempted:
-                break
-            state, metrics = self.train_step(state, batch)
-            m = {k: float(v) for k, v in jax.device_get(metrics).items()}
-            self.guard.check(m)
-            self.logger.log_dict(int(state.step),
-                                 {f"train_{k}": v for k, v in m.items()})
-        self.logger.log("images_per_sec", int(state.step),
-                        meter.images_per_sec)
         return state
 
     def fit(self, train_data, val_data=None, state: TrainState | None = None,

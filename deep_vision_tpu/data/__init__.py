@@ -1,5 +1,4 @@
-__all__ = ["ArrayLoader", "prefetch_to_device", "DevicePrefetcher",
-           "HostStagingPool"]
+__all__ = ["ArrayLoader", "DevicePrefetcher", "HostStagingPool"]
 
 _PIPELINE = {"DevicePrefetcher", "HostStagingPool"}
 

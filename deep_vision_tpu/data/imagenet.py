@@ -12,7 +12,7 @@ host Python):
   never reads the same image twice per epoch;
 - a multiprocess worker pool decodes+augments (the torch
   ``DataLoader(num_workers=16)`` role, ResNet/pytorch/train.py:229-234);
-- batches flow through ``prefetch_to_device`` for double-buffered H2D.
+- batches flow through ``data.pipeline.DevicePrefetcher`` for staged H2D.
 """
 
 from __future__ import annotations
@@ -257,8 +257,8 @@ class ImageNetLoader:
     Yields {"image": (B,H,W,3), "label": (B,) i32} host batches — uint8
     images with ``device_normalize`` (the 1-byte/pixel train wire; the
     jitter/normalize runs as the jitted step's traced prologue), float32
-    otherwise.  Compose with ``data.pipeline.DevicePrefetcher`` (or the
-    legacy ``prefetch_to_device`` shim) for staged H2D.
+    otherwise.  Compose with ``data.pipeline.DevicePrefetcher`` for
+    staged H2D.
     """
 
     def __init__(self, root_dir: str | None, labels_file: str | None,
@@ -301,8 +301,8 @@ class ImageNetLoader:
                          image_size=image_size, resize=resize,
                          device_normalize=device_normalize,
                          preprocessing=preprocessing)
-        #: what this loader ships per pixel — the input-goodput logs and
-        #: bench.py --input report H2D traffic against this
+        #: what this loader ships per pixel — the input-goodput logs
+        #: report H2D traffic against this
         self.wire_dtype = np.uint8 if device_normalize else np.float32
         if isinstance(self.ds, ImageNetRecords):
             self._cfg["entries"] = self.ds.entries

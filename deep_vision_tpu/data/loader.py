@@ -9,7 +9,7 @@ already being transferred H2D, so HBM never waits on the host.
 
 from __future__ import annotations
 
-from typing import Any, Callable, Iterable, Iterator
+from typing import Any, Callable, Iterator
 
 import numpy as np
 
@@ -201,24 +201,3 @@ class ArrayLoader:
             if self.transform is not None:
                 batch = self.transform(batch, rng)
             yield batch
-
-
-def prefetch_to_device(iterable: Iterable, mesh, depth: int = 2) -> Iterator:
-    """Background device_put pipeline (the double-buffer) — legacy shim.
-
-    Now a thin generator over :class:`deep_vision_tpu.data.pipeline.DevicePrefetcher`
-    so the old call sites keep their contract (producer exceptions re-raise
-    in the consumer — a dead producer must abort the epoch, not truncate it)
-    while gaining the staged path's fix for the producer-thread leak: when
-    the consumer abandons iteration early (preemption, divergence abort,
-    mid-epoch exception) the generator's ``finally`` closes the epoch, which
-    unblocks the producer's bounded put and joins the thread instead of
-    leaving it parked on ``q.put`` forever with batches pinned in the queue.
-    """
-    from deep_vision_tpu.data.pipeline import DevicePrefetcher
-
-    pf = DevicePrefetcher(mesh, depth=depth)
-    try:
-        yield from pf.iterate(iterable)
-    finally:
-        pf.close()

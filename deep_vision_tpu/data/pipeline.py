@@ -1,8 +1,7 @@
 """Staged train-input pipeline: host staging pool + ``DevicePrefetcher``.
 
 This is the serving wire stack (PR 2's ``StagingPool``, PR 5's uint8 wire,
-the engine's pipelined H2D) ported to the *training* side, replacing the
-single background thread in :func:`deep_vision_tpu.data.loader.prefetch_to_device`.
+the engine's pipelined H2D) ported to the *training* side.
 Per batch the producer thread runs four stages:
 
     prep_wait → assemble → h2d → enqueue

@@ -38,3 +38,36 @@ def synthetic_images(n: int, image_size: int, channels: int = 3, seed: int = 0
     rng = np.random.default_rng(seed)
     return rng.uniform(-1, 1, size=(n, image_size, image_size, channels)
                        ).astype(np.float32)
+
+
+def make_synthetic_imagenet(tmp: str, n_images: int, jpeg_size: int,
+                            val_images: int = 0) -> tuple[str, str, str]:
+    """Synthetic flat-ImageNet tree: 8 synsets, 8 distinct base images
+    saved as JPEGs, labels.txt — what ``ImageNetLoader`` and
+    ``prepare_data imagenet`` read.
+    Returns (train_dir, labels_path, val_dir_or_empty)."""
+    import os
+
+    from PIL import Image
+
+    root = os.path.join(tmp, "train")
+    os.makedirs(root)
+    rng = np.random.default_rng(0)
+    synsets = [f"n{i:08d}" for i in range(8)]
+    labels = os.path.join(tmp, "labels.txt")
+    with open(labels, "w") as f:
+        for sn in synsets:
+            f.write(f"{sn} synthetic\n")
+    base = rng.integers(0, 255, (8, jpeg_size, jpeg_size, 3), dtype=np.uint8)
+    for i in range(n_images):
+        Image.fromarray(base[i % 8]).save(
+            os.path.join(root, f"{synsets[i % 8]}_{i}.JPEG"), quality=85)
+    val_root = ""
+    if val_images:
+        val_root = os.path.join(tmp, "val")
+        os.makedirs(val_root)
+        for i in range(val_images):
+            Image.fromarray(base[i % 8]).save(
+                os.path.join(val_root, f"{synsets[i % 8]}_{i}.JPEG"),
+                quality=85)
+    return root, labels, val_root

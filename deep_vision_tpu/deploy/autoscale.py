@@ -32,8 +32,7 @@ Stability is structural, not tuned: the two windows are hysteresis
 contrary tick resets the streak), and every action starts a
 ``cooldown_s`` during which no further action fires — so the replica
 count is monotone within each window and the scaler cannot flap.
-``tick()`` is public: tests (and ``bench.py --deploy``) drive it
-synchronously; production runs it on an Event-paced daemon thread.
+``tick()`` is public: tests drive it synchronously; production runs it on an Event-paced daemon thread.
 """
 
 from __future__ import annotations

@@ -187,9 +187,8 @@ class CheckpointWatcher:
     """One supervised poll thread per watched model.
 
     ``poll_once(name)`` is the whole state machine and is public: tests
-    and ``bench.py --deploy`` drive it synchronously; production runs
-    it on Event-paced daemon threads that a supervisor restarts if they
-    ever exit."""
+    drive it synchronously; production runs it on Event-paced daemon
+    threads that a supervisor restarts if they ever exit."""
 
     def __init__(self, plane, history, *, interval_s: float = 2.0,
                  gate: AccuracyGate | None = None, loader=None):

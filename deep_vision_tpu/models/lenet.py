@@ -16,7 +16,7 @@ from flax import linen as nn
 
 class LeNet5Big(nn.Module):
     """A deliberately heavy MNIST-shape classifier — the cascade's BIG
-    tier opposite LeNet-5 (serve/cascade.py, bench.py --serve-cascade).
+    tier opposite LeNet-5 (serve/cascade.py, tests/cascade_smoke.py).
 
     Same 32×32×1 input and class count as LeNet-5 so the two tiers are
     interchangeable on the wire, but VGG-style doubled-conv blocks with
@@ -48,8 +48,7 @@ class LeNet5Big(nn.Module):
 
 class LeNet5Nano(nn.Module):
     """A deliberately tiny MNIST-shape classifier — the N-tier
-    cascade's tier-0 below LeNet-5 (serve/cascade.py,
-    bench.py --serve-cascade --tiers 3).
+    cascade's tier-0 below LeNet-5 (serve/cascade.py).
 
     Same 32×32×1 input and class count as the other two so all three
     tiers are interchangeable on the wire: one strided conv8@5×5 →

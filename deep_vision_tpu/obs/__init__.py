@@ -23,8 +23,7 @@ itself) and writes their intervals beside a profiler trace
     mfu.py    serving MFU: per-bucket analytic FLOPs (XLA cost
               analysis, with a documented params-based fallback) over
               measured compute-stage seconds against the device peak —
-              a ``serving_mfu`` gauge in ``/metrics``, ``/v1/stats``
-              and ``bench.py --serve``.
+              a ``serving_mfu`` gauge in ``/metrics`` and ``/v1/stats``.
 
 The Prometheus text renderer the ``/metrics`` endpoints use lives in
 ``core/metrics.py`` (``PromText``) next to ``LatencyHistogram``, whose

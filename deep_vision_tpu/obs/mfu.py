@@ -1,8 +1,7 @@
 """Serving MFU: analytic FLOPs over measured compute-stage seconds.
 
-Training has had an MFU number since PR 1 (bench.py, 31.4% on the
-reference step); serving had none.  The meter closes that: each bucket
-program's FLOP count comes from XLA's own cost analysis on the AOT
+Training's MFU is the benchmark's ``step_mfu_pct`` (``benchmark/``, read
+from a device trace); this meter is serving's.  Each bucket program's FLOP count comes from XLA's own cost analysis on the AOT
 executable (``jax.jit(...).lower(...).compile().cost_analysis()`` —
 the registry attaches it to the bucket callable at compile time), and
 the engine feeds in the measured per-batch compute-stage seconds it
@@ -17,8 +16,8 @@ StableHLO blob has no compiled object; some backends return no
 ``flops`` key) the registry substitutes ``2 × params × batch`` — a
 dense-matmul LOWER BOUND that ignores convolution reuse — and labels
 the source ``params_lower_bound`` so a too-good-to-be-true gauge is
-never silently wrong.  Peak FLOP/s comes from the same public
-spec-sheet table bench.py has always used (bf16 dense, per chip).  A
+never silently wrong.  Peak FLOP/s comes from the public spec-sheet
+table below (bf16 dense, per chip).  A
 device that is not in the table has no peak: ``peak_tflops`` raises and
 the meter reports ``serving_mfu: None`` — a CPU run counts FLOPs and
 compute seconds but never divides them by some other chip's rate.
@@ -30,8 +29,7 @@ import threading
 
 from deep_vision_tpu.analysis.sanitizer import new_lock
 
-# peak dense bf16 TFLOP/s per chip by device kind (public spec sheets);
-# bench.py imports this table — one source of truth for both MFUs
+# peak dense bf16 TFLOP/s per chip by device kind (public spec sheets)
 PEAK_BF16_TFLOPS = {
     "TPU v4": 275.0,
     "TPU v5 lite": 197.0,   # v5e

@@ -117,7 +117,7 @@ class Tracer:
     any trace over the threshold also emits one structured JSONL line
     (``event: slow_request``) for after-the-fact tail debugging.  The
     per-stage aggregate (total seconds + samples per stage name) is
-    what ``bench.py --serve`` reports as the pipeline breakdown.
+    the pipeline breakdown ``/v1/stats`` reports.
     """
 
     def __init__(self, ring: int = 256, slow_ms: float | None = None,

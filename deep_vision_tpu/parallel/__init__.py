@@ -4,7 +4,6 @@ from deep_vision_tpu.parallel.mesh import (
     make_mesh,
     replicate,
     shard_batch,
-    shard_batch_stacked,
     batch_sharding,
     replicated_sharding,
 )
@@ -25,7 +24,6 @@ __all__ = [
     "make_mesh",
     "replicate",
     "shard_batch",
-    "shard_batch_stacked",
     "batch_sharding",
     "replicated_sharding",
 ]

@@ -124,39 +124,3 @@ def shard_batch(tree: Any, mesh: Mesh) -> Any:
         return jax.device_put(x, sharding)
 
     return jax.tree_util.tree_map(_put, tree)
-
-
-def shard_batch_stacked(tree: Any, mesh: Mesh) -> Any:
-    """:func:`shard_batch` for K-stacked batches: leaves are (K, B, ...)
-    — dim 0 is the scan/step axis (replicated), dim 1 the batch (over
-    ``data``), dim 2 image rows (over ``spatial`` where divisible).  The
-    device layout of each step's slice matches what ``shard_batch`` would
-    produce, so a ``lax.scan`` over dim 0 runs the identical sharded step
-    (the Trainer's ``scan_steps`` multi-step dispatch)."""
-    n_data = mesh.shape[DATA_AXIS]
-    n_spatial = mesh.shape.get(SPATIAL_AXIS, 1)
-    multiproc = jax.process_count() > 1
-
-    def _put(x):
-        if isinstance(x, jax.Array):
-            return x
-        x = np.asarray(x)
-        if x.ndim <= 1:  # scalars / per-step vectors: replicate
-            if multiproc:
-                return jax.make_array_from_process_local_data(
-                    replicated_sharding(mesh), x)
-            return jax.device_put(x, replicated_sharding(mesh))
-        global_batch = x.shape[1] * (jax.process_count() if multiproc else 1)
-        if global_batch % n_data != 0:
-            raise ValueError(
-                f"global batch {global_batch} (local {x.shape[1]}) not "
-                f"divisible by data axis {n_data}")
-        spec = [None, DATA_AXIS] + [None] * (x.ndim - 2)
-        if n_spatial > 1 and x.ndim >= 5 and x.shape[2] % n_spatial == 0:
-            spec[2] = SPATIAL_AXIS
-        sharding = NamedSharding(mesh, P(*spec))
-        if multiproc:  # local leaves are this process's batch shard
-            return jax.make_array_from_process_local_data(sharding, x)
-        return jax.device_put(x, sharding)
-
-    return jax.tree_util.tree_map(_put, tree)

@@ -40,9 +40,8 @@ accounting (analytic per-bucket FLOPs ÷ measured compute time).  Both
 HTTP front-ends export ``GET /metrics`` in Prometheus text format.
 
 Entry points: ``python -m deep_vision_tpu.cli.serve`` (one backend),
-``python -m deep_vision_tpu.cli.gateway`` (front tier); load generator:
-``python bench.py --serve`` / ``--gateway``; architecture notes:
-docs/SERVING.md.
+``python -m deep_vision_tpu.cli.gateway`` (front tier); architecture
+notes: docs/SERVING.md.
 """
 
 from deep_vision_tpu.serve.admission import AdmissionController, Shed

@@ -1,8 +1,8 @@
 """Pipelined background-thread dynamic micro-batcher.
 
-The training stack amortizes XLA dispatch over ``lax.scan`` steps and
-hides host work behind device compute with double-buffered prefetch
-(~2% dispatch idle, docs/PERF.md); the serving stack applies the same
+The training stack hides host work behind device compute with async
+dispatch and a staged prefetcher (the chip idles under 1% of a traced
+window, PERF.md §5); the serving stack applies the same
 argument to dynamically-formed request batches with a two-stage
 pipeline:
 
@@ -1106,8 +1106,8 @@ class BatchingEngine:
                    "infer_dtype": getattr(self.model, "infer_dtype",
                                           "float32"),
                    # the served weights' byte footprint (int8 models
-                   # report the true quantized size — bench.py's
-                   # weight-HBM pricing and the /metrics gauge).
+                   # report the true quantized size — the /metrics
+                   # gauge).
                    # param_bytes is PER-CHIP on mesh views: a leaf
                    # split over ``model`` prices its addressable shard
                    "weight_hbm_bytes": self.model.param_bytes()

@@ -52,8 +52,8 @@ survives any single backend dying:
                   bucket denies the retry; the request answers with
                   what it has (last 429/502) instead of amplifying.
                   Remaining tokens ride the ``X-DVT-Retry-Budget``
-                  response header so a cooperating client (bench.py's
-                  closed loop) suppresses ITS retries too — gateway
+                  response header so a cooperating client
+                  suppresses ITS retries too — gateway
                   and client never jointly exceed the budget.
   429s            a shed (429) is failed over once to a less-loaded
                   backend when one exists; otherwise it propagates to

@@ -124,7 +124,7 @@ way a slow-loris can't pin a handler thread forever.
 The front-end itself is the selector event loop in ``serve/edge.py``
 (HTTP/1.1 keep-alive, pipelining, bounded connections) by default;
 ``edge=False`` keeps the original thread-per-request
-``ThreadingHTTPServer`` — the A/B baseline in docs/PERF.md.  Either
+``ThreadingHTTPServer`` as the baseline.  Either
 way the routes above run unchanged.  Two optional edge services hook
 the inference POST path: a content-addressed response cache
 (``serve/cache.py`` — a repeat payload against the same model version
@@ -1507,7 +1507,7 @@ class ServeServer:
     ``edge=True`` (default) runs the selector event loop from
     ``serve/edge.py`` — keep-alive, pipelining, bounded connections;
     ``edge=False`` keeps the original thread-per-request
-    ``ThreadingHTTPServer`` (the A/B baseline in docs/PERF.md).  Both
+    ``ThreadingHTTPServer`` (the baseline).  Both
     carry the same context attributes, so ``self.httpd`` stays the
     single handle tests and the CLI reach through."""
 

@@ -643,7 +643,7 @@ class ReplicatedEngine:
         # the fleet down
         rep["can_serve"] = state != DEAD
         # fleet-wide failure accounting (same keys as a single engine's
-        # report, so bench.py / dashboards read either shape)
+        # report, so dashboards read either shape)
         rep["batch_failures"] = sum(r.batch_failures
                                     for r in self.replicas)
         rep["retry_executions"] = sum(r.retry_executions

@@ -77,9 +77,6 @@ class ImagePool:
 class DCGANTask:
     """models: generator (noise→image), discriminator (image→logit)."""
 
-    # no host state between steps → the AdversarialTrainer may scan K
-    # steps per dispatch (core/adversarial.py train_multi)
-    scan_safe = True
     # host_prepare is stateless (identity) → batches may be staged ahead
     # by the DevicePrefetcher (core/adversarial.py _epoch_steps)
     prefetch_safe = True
@@ -160,10 +157,8 @@ class CycleGANTask:
     """models: gen_a2b, gen_b2a, disc_a, disc_b."""
 
     # the per-step host ImagePool exchange (host_prepare/host_update)
-    # is semantic — scanning would replay stale pools, so: per-step
-    scan_safe = False
-    # same hazard for the staged DevicePrefetcher: host_prepare draws
-    # from the pool, so batches staged ahead would see it stale
+    # is semantic: host_prepare draws from the pool, so batches staged
+    # ahead by the DevicePrefetcher would see it stale
     prefetch_safe = False
 
     LAMBDA_CYCLE = 10.0  # train.py:16

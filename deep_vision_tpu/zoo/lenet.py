@@ -15,9 +15,8 @@ from deep_vision_tpu.models.lenet import LeNet5, LeNet5Big, LeNet5Nano
 def lenet5_nano() -> TrainConfig:
     """The N-tier cascade's tier 0 below lenet5: identical wire
     contract (32×32×1, 10 classes) at ~12× less compute than LeNet-5 —
-    the front of the lenet5_nano:lenet5:lenet5_big chain
-    ``bench.py --serve-cascade --tiers 3`` and the cascade smoke run
-    (serve/cascade.py)."""
+    the front of the lenet5_nano:lenet5:lenet5_big chain the cascade
+    tests run (serve/cascade.py)."""
     return TrainConfig(
         name="lenet5_nano",
         model=lambda: LeNet5Nano(),
@@ -56,8 +55,8 @@ def lenet5() -> TrainConfig:
 def lenet5_big() -> TrainConfig:
     """The cascade's BIG tier opposite lenet5: identical wire contract
     (32×32×1, 10 classes) at ~50× the compute — the cheap-front /
-    heavy-big pair ``bench.py --serve-cascade`` and the cascade smoke
-    serve behind one plane (serve/cascade.py)."""
+    heavy-big pair the cascade smoke serves behind one plane
+    (serve/cascade.py)."""
     return TrainConfig(
         name="lenet5_big",
         model=lambda: LeNet5Big(),

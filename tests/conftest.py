@@ -60,6 +60,34 @@ def jaxpr_equations():
     return _equations
 
 
+@pytest.fixture(scope="session")
+def images_with_margin():
+    """``pick(logits_of, n, margin)``: n uint8 32x32x1 images whose two
+    largest reference logits lie at least ``margin`` apart, so a test of
+    "top-1 intact within a tolerance" is decided by the tolerance and not
+    by a near tie.  Uniform noise gives a randomly initialised LeNet ties
+    of 1e-3; block patterns spread its logits.  ``logits_of`` maps a
+    (8, 32, 32, 1) uint8 batch to the reference's float32 logits."""
+    import numpy as np
+
+    def pick(logits_of, n, margin):
+        found = []
+        for start in range(0, 256, 8):
+            batch = np.stack([
+                np.kron(np.random.RandomState(seed).rand(4, 4) > 0.5,
+                        np.ones((8, 8))).astype(np.uint8)[..., None] * 255
+                for seed in range(start, start + 8)])
+            top2 = np.sort(logits_of(batch), axis=1)[:, -2:]
+            found += [img for img, (second, first) in zip(batch, top2)
+                      if first - second >= margin]
+            if len(found) >= n:
+                return found[:n]
+        raise AssertionError(f"{len(found)} of 256 patterns have a top-2 "
+                             f"margin of {margin}; wanted {n}")
+
+    return pick
+
+
 # The dvtlint runtime half (docs/ANALYSIS.md): every chaos/gateway/replicas
 # test runs with DVT_LOCK_SANITIZER semantics on — serve/* locks become
 # SanitizedLocks recording acquisition order, and the test FAILS at teardown

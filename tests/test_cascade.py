@@ -49,6 +49,7 @@ class FakePlane:
     def __init__(self, rows, delay_s=0.0):
         self.rows = rows
         self.delay_s = delay_s
+        self.sleep = time.sleep  # a test with its own clock replaces it
         self.calls = []
         self.listeners = []
 
@@ -58,7 +59,7 @@ class FakePlane:
     def submit(self, name, image, deadline_ms=None, span=None):
         self.calls.append((name, deadline_ms))
         if self.delay_s:
-            time.sleep(self.delay_s)
+            self.sleep(self.delay_s)
         fut = Future()
         row = self.rows[name]
         if callable(row):
@@ -363,33 +364,52 @@ def test_uncalibrated_hop_escalates_through_without_running_tier():
     assert [name for name, _ in plane2.calls] == ["large"]
 
 
-def test_twice_escalated_request_never_exceeds_original_budget():
+def test_twice_escalated_request_never_exceeds_original_budget(monkeypatch):
     """Satellite: a request escalated through BOTH cheap tiers submits
     to each next tier with strictly shrinking remainders of its ONE
-    original deadline — and sheds when the chain eats the budget."""
+    original deadline — and sheds when the chain eats the budget.  The
+    router reads an injected clock that only a tier's 20 ms of work
+    advances, so the remainders are exact and no scheduler delay on a
+    loaded host can eat a budget."""
+    from deep_vision_tpu.serve import cascade
+
+    class Clock:
+        """Stands in for the ``time`` module where the router reads it."""
+        now = 100.0
+        time = staticmethod(time.time)
+
+        def monotonic(self):
+            return self.now
+
+        def sleep(self, seconds):
+            self.now += seconds
+
+    clock = Clock()
+    monkeypatch.setattr(cascade, "time", clock)
     router, plane = _router3(
         {"small": _front_row(prob=0.1), "mid": _mid_row(prob=0.1),
          "large": _big_row()},
         thresholds=(0.5, 0.5), delay_s=0.02)
+    plane.sleep = clock.sleep
     tier, _ = router.infer(np.zeros((4, 4, 1), np.float32),
                            deadline_ms=500.0)
     assert tier == "big"
     (n0, d0), (n1, d1), (n2, d2) = plane.calls
     assert (n0, d0) == ("small", 500.0)  # hop 0 sees the EXACT budget
     assert n1 == "mid" and n2 == "large"
-    # each hop burned >= 20ms of the same 500ms budget
-    assert 0.0 < d2 < d1 <= 500.0 - 20.0
-    assert d2 <= 500.0 - 40.0
+    # each hop burned its 20ms of the same 500ms budget
+    assert d1 == pytest.approx(480.0) and d2 == pytest.approx(460.0)
     assert router.stats()["escalations"] == 2
 
-    # budget dies mid-chain: big is never submitted, the client gets a
-    # deadline Shed
+    # budget dies mid-chain (30ms: one hop fits, two do not): big is
+    # never submitted, the client gets a deadline Shed
     plane.calls.clear()
     tier, row = router.infer(np.zeros((4, 4, 1), np.float32),
                              deadline_ms=30.0)
     assert tier == "big" and isinstance(row, Shed)
     assert row.reason == "deadline"
-    assert [name for name, _ in plane.calls] == ["small", "mid"]
+    assert [(name, round(d)) for name, d in plane.calls] == [
+        ("small", 30), ("mid", 10)]
     assert router.stats()["escalated_shed"] == 1
 
 

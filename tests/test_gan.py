@@ -124,58 +124,3 @@ def test_adversarial_trainer_smoke(tmp_path):
     # samples come out image-shaped
     img = task.sample(states, 2, jax.random.PRNGKey(1))
     assert img.shape == (2, 28, 28, 1)
-
-
-@pytest.mark.slow
-def test_adversarial_scan_steps_dcgan(tmp_path):
-    """DCGAN (scan_safe) under scan_steps=2: 5 batches → 2 scanned groups
-    + 1 ragged per-step tail, guard sees every step, losses stay finite."""
-    from deep_vision_tpu.core.adversarial import AdversarialTrainer
-    from deep_vision_tpu.core.config import get_config
-    from deep_vision_tpu.data.gan import GANLoader, mnist_gan_data
-
-    cfg = get_config("dcgan")
-    cfg.batch_size = 8
-    cfg.total_epochs = 1
-    cfg.checkpoint_every_epochs = 1
-    cfg.log_every_steps = 1
-    cfg.scan_steps = 2
-    images = mnist_gan_data(None, n_synthetic=40)  # 5 batches of 8
-    loader = GANLoader(images, cfg.batch_size)
-    task = DCGANTask(DCGANGenerator(), DCGANDiscriminator(), latent_dim=8)
-    trainer = AdversarialTrainer(cfg, task, workdir=str(tmp_path))
-    g0 = jax.device_get(
-        trainer.init_states(next(iter(loader)))["generator"].params)
-    states = trainer.fit(loader, epochs=1)
-    # both nets updated and finite after scanned training
-    g1 = jax.device_get(states["generator"].params)
-    diff = jax.tree_util.tree_map(
-        lambda a, b: float(np.abs(a - b).max()), g0, g1)
-    assert max(jax.tree_util.tree_leaves(diff)) > 0
-    for leaf in jax.tree_util.tree_leaves(jax.device_get(states)):
-        assert np.all(np.isfinite(np.asarray(leaf, np.float64)))
-
-    # rng threads through the scan carry with the per-step split order,
-    # so scan_steps=2 must train IDENTICALLY to scan_steps=1
-    cfg1 = get_config("dcgan")
-    cfg1.batch_size = 8
-    cfg1.total_epochs = 1
-    cfg1.checkpoint_every_epochs = 1000
-    cfg1.log_every_steps = 1000
-    cfg1.scan_steps = 1
-    task1 = DCGANTask(DCGANGenerator(), DCGANDiscriminator(), latent_dim=8)
-    t1 = AdversarialTrainer(cfg1, task1, workdir=str(tmp_path / "s1"))
-    s1 = t1.fit(GANLoader(images, cfg1.batch_size), epochs=1)
-    a = jax.device_get(s1["generator"].params)
-    b = jax.device_get(states["generator"].params)
-    # same rng stream, same batches; tolerance covers scan-vs-unrolled
-    # XLA float reassociation through Adam only (observed max |d| ~1e-5
-    # over 5 steps; a stream mismatch would diverge everywhere at O(1e-3))
-    jax.tree_util.tree_map(
-        lambda x, y: np.testing.assert_allclose(x, y, atol=1e-4), a, b)
-
-
-def test_cyclegan_not_scan_safe():
-    from deep_vision_tpu.tasks.gan import CycleGANTask, DCGANTask
-
-    assert DCGANTask.scan_safe and not CycleGANTask.scan_safe

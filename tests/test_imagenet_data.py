@@ -191,7 +191,7 @@ def test_prefetch_propagates_producer_errors():
     import jax
     import pytest as _pytest
 
-    from deep_vision_tpu.data.loader import prefetch_to_device
+    from deep_vision_tpu.data.pipeline import DevicePrefetcher
     from deep_vision_tpu.parallel import make_mesh
 
     mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
@@ -200,10 +200,37 @@ def test_prefetch_propagates_producer_errors():
         yield {"image": np.zeros((2, 4, 4, 1), np.float32)}
         raise RuntimeError("decode failed")
 
-    it = prefetch_to_device(bad_iter(), mesh)
-    next(it)
-    with _pytest.raises(RuntimeError, match="decode failed"):
+    pf = DevicePrefetcher(mesh)
+    try:
+        it = pf.iterate(bad_iter())
         next(it)
+        with _pytest.raises(RuntimeError, match="decode failed"):
+            next(it)
+    finally:
+        pf.close()
+
+
+def test_synthetic_imagenet_tree_is_read_by_the_loader(tmp_path):
+    """``data/synthetic.make_synthetic_imagenet`` (chip_smoke.py packs its
+    records from it) writes a tree ``ImageNetLoader`` reads: every image
+    counted once, labels among the 8 synsets, the asked size."""
+    from deep_vision_tpu.data.synthetic import make_synthetic_imagenet
+
+    root, labels, val_root = make_synthetic_imagenet(
+        str(tmp_path), n_images=20, jpeg_size=40, val_images=6)
+    assert len(os.listdir(root)) == 20 and len(os.listdir(val_root)) == 6
+    for split, count in ((root, 20), (val_root, 6)):
+        loader = ImageNetLoader(split, labels, batch_size=4, train=False,
+                                image_size=32, resize=36, num_workers=0,
+                                process_index=0, process_count=1)
+        batches = list(loader)
+        weight = np.concatenate([b["weight"] for b in batches])
+        label = np.concatenate([b["label"] for b in batches])
+        assert weight.sum() == count
+        assert all(b["image"].shape == (4, 32, 32, 3) for b in batches)
+        # file i carries synset i % 8, so the first `count` labels cycle
+        assert sorted(label[weight > 0]) == sorted(
+            i % 8 for i in range(count))
 
 
 def test_tf_preprocessing_semantics():

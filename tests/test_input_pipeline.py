@@ -154,7 +154,7 @@ def test_consumer_marks_keep_the_sums_and_share_batch_numbers(mesh1):
 def test_abandoned_epoch_leaks_nothing(mesh1):
     """Abandoning iteration mid-epoch (preemption, divergence abort) must
     not leave a producer thread behind nor device batches pinned in the
-    queue — the legacy `prefetch_to_device` bug this PR fixes."""
+    queue."""
     gc.collect()
     base_threads = threading.active_count()
     base_arrays = len(jax.live_arrays())
@@ -171,25 +171,27 @@ def test_abandoned_epoch_leaks_nothing(mesh1):
     assert len(jax.live_arrays()) <= base_arrays + 2
 
 
-def test_legacy_shim_closes_producer_and_propagates_errors(mesh1):
-    """The kept `prefetch_to_device` generator shim rides the new
-    prefetcher: abandoning it tears the producer down, and a producer
-    exception surfaces at the consumer."""
-    from deep_vision_tpu.data.loader import prefetch_to_device
-
+def test_early_stop_joins_producer_and_errors_propagate(mesh1):
+    """A consumer that stops early and closes the prefetcher joins the
+    producer thread, and a producer exception surfaces at the consumer."""
     base = threading.active_count()
-    gen = prefetch_to_device(_batches(64), mesh1, depth=2)
-    next(gen)
-    gen.close()
+    pf = DevicePrefetcher(mesh1, depth=2)
+    stream = pf.iterate(_batches(64))
+    next(stream)
+    pf.close()
     assert threading.active_count() == base
 
     def poisoned():
         yield from _batches(2)
         raise RuntimeError("loader exploded")
 
-    with pytest.raises(RuntimeError, match="loader exploded"):
-        for _ in prefetch_to_device(poisoned(), mesh1, depth=2):
-            pass
+    pf = DevicePrefetcher(mesh1, depth=2)
+    try:
+        with pytest.raises(RuntimeError, match="loader exploded"):
+            for _ in pf.iterate(poisoned()):
+                pass
+    finally:
+        pf.close()
 
 
 def test_donated_batches_stay_correct_across_epochs(mesh1):

@@ -8,8 +8,7 @@ top-1, bucket divisibility errors must name both mesh axes, per-chip
 ``param_bytes()`` must price one chip's shard (strictly below the
 replicated footprint when the model axis is real), and the weight cache
 must spill/re-admit a model-sharded view bit-identically with zero
-recompiles.  Correctness only — the 8 "devices" share one host;
-bench.py --serve-mesh measures the actual cells."""
+recompiles.  Correctness only — the 8 "devices" share one host."""
 
 import numpy as np
 import pytest

@@ -59,7 +59,7 @@ def test_build_scheduler_registry():
 
 def test_momentum_dtype_bf16_accumulator():
     """momentum_dtype='bfloat16' stores the SGD trace in bf16 (the
-    optimizer-state bandwidth experiment, docs/PERF.md) and is rejected
+    optimizer-state bandwidth lever, docs/PERF.md) and is rejected
     for anything but sgd / any other dtype string."""
     import jax
     import jax.numpy as jnp

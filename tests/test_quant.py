@@ -237,14 +237,19 @@ def test_ingest_parity_check():
 # -- the int8 serving path end to end --------------------------------------
 
 
-def test_int8_top1_agreement(quant_serving):
+def test_int8_top1_agreement(quant_serving, images_with_margin):
     """Acceptance gate: int8 engines return FLOAT32 outputs within
     loose tolerance of the f32 path with top-1 intact (the bf16 bar),
     and the Pallas-ingest and XLA-ingest engines agree with each other
     to the tight tolerance (same quantized weights, ≤1-step ingest
-    difference)."""
+    difference).  The inputs' top-2 f32 logits lie further apart than
+    two logits may move inside the loose tolerance, so top-1 is the
+    tolerance's to keep."""
     sm_f32, sm_i8, sm_i8_xla = quant_serving
-    raw = _raw_images(12)
+    f32_program = sm_f32.compile_bucket(8)
+    raw = images_with_margin(
+        lambda r: np.asarray(f32_program(np.stack(_host_normalized(r)))),
+        12, margin=0.2)
     kw = dict(buckets=[4, 8], max_wait_ms=150, watchdog_interval_s=0)
     with BatchingEngine(sm_f32, **kw) as eng:
         ref = _serve_all(eng, _host_normalized(raw[:8]))
@@ -258,6 +263,7 @@ def test_int8_top1_agreement(quant_serving):
     for a, b in zip(ref, got):
         assert b.dtype == np.float32
         np.testing.assert_allclose(a, b, atol=5e-2, rtol=5e-2)
+        assert 2 * (5e-2 + 5e-2 * np.abs(b).max()) < 0.2
         assert int(np.argmax(a)) == int(np.argmax(b))
     with BatchingEngine(sm_i8_xla, **kw) as eng:
         got_x = _serve_all(eng, raw[:8])

@@ -5,7 +5,7 @@ the single-engine path, a replica killed mid-load loses zero admitted
 requests, and the sharded mega-batch path matches the unsharded
 reference.  CPU-only and deterministic — the 8 "devices" share one
 host, so these tests verify CORRECTNESS of placement/routing/failover,
-not speedup (bench.py --serve --serve-devices measures that)."""
+not speedup."""
 
 from concurrent.futures import wait
 

@@ -20,59 +20,6 @@ def make_trainer(tmp_path, mesh, epochs=2):
     return cfg, Trainer(cfg, model, task, mesh=mesh, workdir=str(tmp_path))
 
 
-def test_scan_steps_smoke(tmp_path, mesh1):
-    """Fast-lane coverage of the scanned multi-step dispatch: one epoch at
-    scan_steps=2 over 4 batches (2 scanned groups) trains to the right
-    step count with finite params.  The exact scan-vs-single trajectory
-    equivalence — including the ragged tail — lives in the slow lane
-    below."""
-    import jax
-
-    cfg = get_config("lenet5")
-    cfg.total_epochs = 1
-    cfg.batch_size = 32
-    cfg.scan_steps = 2
-    trainer = Trainer(cfg, cfg.model(), ClassificationTask(10),
-                      mesh=mesh1, workdir=str(tmp_path))
-    data = synthetic_mnist(128)  # 4 batches of 32 → exactly 2 scanned groups
-    state = trainer.fit(ArrayLoader(data, cfg.batch_size, seed=1))
-    assert int(jax.device_get(state.step)) == 4
-    for leaf in jax.tree_util.tree_leaves(jax.device_get(state.params)):
-        assert np.all(np.isfinite(leaf))
-
-
-@pytest.mark.slow
-def test_scan_steps_matches_single_step(tmp_path, mesh1):
-    """config.scan_steps=K (K steps per device dispatch via lax.scan) must
-    reproduce the step-per-dispatch trajectory EXACTLY — same data order,
-    same updates, same final params — including the ragged tail (epoch
-    length not divisible by K)."""
-    import jax
-
-    data = synthetic_mnist(160)  # 5 batches of 32 → K=2 leaves a tail of 1
-
-    def run(workdir, scan_steps):
-        cfg = get_config("lenet5")
-        cfg.total_epochs = 2
-        cfg.batch_size = 32
-        cfg.scan_steps = scan_steps
-        trainer = Trainer(cfg, cfg.model(), ClassificationTask(10),
-                          mesh=mesh1, workdir=workdir)
-        train = ArrayLoader(data, cfg.batch_size, seed=1)
-        val = ArrayLoader(data, cfg.batch_size, shuffle=False)
-        state = trainer.fit(train, val)
-        return state, trainer.evaluate(state, val)
-
-    s1, m1 = run(str(tmp_path / "single"), 1)
-    sK, mK = run(str(tmp_path / "scan"), 2)
-    assert int(jax.device_get(sK.step)) == int(jax.device_get(s1.step)) == 10
-    np.testing.assert_allclose(mK["loss"], m1["loss"], rtol=1e-5)
-    jax.tree_util.tree_map(
-        lambda a, b: np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6),
-        jax.device_get(sK.params), jax.device_get(s1.params))
-
-
 @pytest.mark.slow
 def test_overfits_synthetic(tmp_path, mesh8):
     cfg, trainer = make_trainer(tmp_path, mesh8, epochs=3)
