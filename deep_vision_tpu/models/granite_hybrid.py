@@ -26,8 +26,9 @@ a Mamba mixer, ``q/k/v`` and ``o_proj`` of attention, the MLP's
 ``in_proj``), so that no such product runs twice, and the blocked
 attention's output, so that its blocks are computed again once (by
 ``ops/attention.py``'s own checkpoint) and not twice.  Norms, conv, SiLU,
-the scan with its ``(chunk, chunk)`` decays and the SwiGLU product are
-computed again.  The kept set costs 532 KB a token for the ten layers of a
+the scan's forward kernel (``ops/ssd.py``: a chunk's ``(chunk, chunk)``
+decays and scores live in VMEM inside it and in the backward kernel, and
+nowhere else) and the SwiGLU product are computed again.  The kept set costs 532 KB a token for the ten layers of a
 pipeline stage at the published widths (527 of them the products'
 outputs): 2.0 GiB at 4,096 tokens a step, 4.4 GB at 8,192, which has not
 been tried.  Scopes

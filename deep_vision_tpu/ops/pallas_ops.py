@@ -28,6 +28,9 @@ Layout notes (TPU tiling):
   sublanes (M padded to a multiple of 8), the (M, TILE_N) broadcast needs
   no in-kernel transpose and the max runs over the sublane axis;
 - CPU tests run the same kernel via ``interpret=True``.
+
+The state-space scan's two kernels (``ssd_chunk_fwd``, ``ssd_chunk_bwd``)
+live with the scan they are, in ``ops/ssd.py``.
 """
 
 from __future__ import annotations

@@ -42,21 +42,25 @@ def host_devices():
     return devs
 
 
-def _equations(jaxpr):
+def _equations(jaxpr, closed=()):
     for eqn in jaxpr.eqns:
         yield eqn
+        if eqn.primitive.name in closed:
+            continue
         for value in eqn.params.values():
             for sub in (value if isinstance(value, (list, tuple))
                         else (value,)):
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    yield from _equations(sub)
+                    yield from _equations(sub, closed)
 
 
 @pytest.fixture(scope="session")
 def jaxpr_equations():
-    """``walk(jaxpr)``: every equation of a jaxpr and of the jaxprs nested
-    in its equations' parameters (pjit, remat, custom_jvp, pallas_call, ...)."""
+    """``walk(jaxpr, closed=())``: every equation of a jaxpr and of the
+    jaxprs nested in its equations' parameters (pjit, remat, custom_jvp,
+    pallas_call, ...), those of the primitives named in ``closed`` left
+    unopened."""
     return _equations
 
 

@@ -478,6 +478,37 @@ def test_yolo_loss_compiles_to_one_copy_an_operand(one_v5e_chip):
     assert len(moves) == 2, moves
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_scan_kernels_compile_for_the_chip_at_published_widths(
+        one_v5e_chip, monkeypatch, dtype):
+    """Here, beside the file's other compile for the described chip (one
+    process may hold the TPU's compiler, so such tests share a file): the
+    state-space scan's two kernels (``ops/ssd.py``) at the widths of
+    ``granite-4.0-h-micro-train-packed4k`` -- a row of 4,096 tokens, 64
+    heads of 64, state 128, chunk 256 -- go through Mosaic, tiling and VMEM
+    included, which interpret mode says nothing about; and the program
+    around them holds no ``(256, 256)`` float32 value."""
+    import re
+
+    from deep_vision_tpu.ops import ssd
+
+    length, heads, dim, n, chunk = 4096, 64, 64, 128, 256
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def S(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    def loss(x, dt, a, b, c, seg):
+        return jnp.sum(ssd.ssd_scan(x, dt, a, b, c, seg, chunk))
+
+    hlo = jax.jit(jax.grad(loss, (0, 1, 2, 3, 4))).lower(
+        S((1, length, heads, dim), dtype), S((1, length, heads)), S((heads,)),
+        S((1, length, n), dtype), S((1, length, n), dtype),
+        S((1, length), jnp.int32)).compile().as_text()
+    assert hlo.count("tpu_custom_call") == 2
+    assert not re.search(rf"f32\[[\d,]*{chunk},{chunk}\]", hlo)
+
+
 def test_average_precision_perfect():
     r = np.array([0.5, 1.0])
     p = np.array([1.0, 1.0])

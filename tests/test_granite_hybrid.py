@@ -61,8 +61,21 @@ def reference():
     return module, module.Reference(SMALL)
 
 
-def scan_inputs(seed=0, heads=8, dim=16, n=16):
-    seg = jnp.asarray(small_rows()["segment_ids"])
+def scan_documents(rows):
+    """Two rows of ``LENGTH`` segment ids: boundaries off the chunk grid of
+    8 (the packed rows), none at all, or all of them on it."""
+    if rows == "off_the_grid":
+        return jnp.asarray(small_rows()["segment_ids"])
+    if rows == "one_document":
+        return jnp.zeros((2, LENGTH), jnp.int32)
+    starts = np.zeros((2, LENGTH), np.int32)
+    starts[0, [16, 32, 40]] = 1
+    starts[1, [8, 48]] = 1
+    return jnp.asarray(np.cumsum(starts, axis=1, dtype=np.int32))
+
+
+def scan_inputs(seed=0, heads=8, dim=16, n=16, rows="off_the_grid"):
+    seg = scan_documents(rows)
     keys = jax.random.split(jax.random.PRNGKey(seed), 5)
     shape = seg.shape
     x = jax.random.normal(keys[0], shape + (heads, dim))
@@ -71,6 +84,17 @@ def scan_inputs(seed=0, heads=8, dim=16, n=16):
     b = jax.random.normal(keys[3], shape + (n,))
     c = jax.random.normal(keys[4], shape + (n,))
     return x, dt, a, b, c, seg
+
+
+def in_bfloat16(x, dt, a, b, c):
+    """The scan's arguments as the model hands them over in bfloat16, and
+    the same values in float32."""
+    low = (x.astype(jnp.bfloat16), dt, a, b.astype(jnp.bfloat16),
+           c.astype(jnp.bfloat16))
+    return low, tuple(v.astype(jnp.float32) for v in low)
+
+
+ROWS = ["off_the_grid", "one_document", "on_the_grid"]
 
 
 def sequential_scan(x, dt, a, b, c, seg):
@@ -88,32 +112,89 @@ def sequential_scan(x, dt, a, b, c, seg):
     return out
 
 
-def test_chunked_scan_matches_the_sequential_recurrence():
-    x, dt, a, b, c, seg = scan_inputs()
+@pytest.mark.parametrize("rows", ROWS)
+def test_chunked_scan_matches_the_sequential_recurrence(rows):
+    x, dt, a, b, c, seg = scan_inputs(rows=rows)
     got = ssd_scan(x, dt, a, b, c, seg, chunk=8)
     want = sequential_scan(x, dt, a, b, c, np.asarray(seg))
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
 
-def test_chunked_scan_gradients_match_the_sequential_recurrence(reference):
+def relative(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return np.linalg.norm(got - want) / np.linalg.norm(want)
+
+
+def test_chunked_scan_in_bfloat16_is_the_float32_scan_within_its_rounding():
+    """Operands of the products rounded to 8 bits of mantissa, sums and
+    decays and state in float32: the result stays float32 and lies within
+    a few 2^-9 of the float32 scan of the same values, by norm."""
+    *args, seg = scan_inputs()
+    low, full = in_bfloat16(*args)
+    got = ssd_scan(*low, seg, chunk=8)
+    assert got.dtype == jnp.float32
+    assert relative(got, ssd_scan(*full, seg, chunk=8)) < 4e-3
+
+
+def scan_gradients(args, seg, weights):
+    def loss(*args):
+        return jnp.sum(weights * ssd_scan(*args, seg, chunk=8))
+
+    return jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
+
+
+@pytest.mark.parametrize("rows", ROWS)
+def test_chunked_scan_gradients_match_the_sequential_recurrence(reference, rows):
     module, _ = reference
-    x, dt, a, b, c, seg = scan_inputs(seed=1)
+    x, dt, a, b, c, seg = scan_inputs(seed=1, rows=rows)
     first = jnp.concatenate([jnp.ones((seg.shape[0], 1), bool),
                              seg[:, 1:] != seg[:, :-1]], axis=1)
     weights = jax.random.normal(jax.random.PRNGKey(9), x.shape)
-
-    def chunked(x, dt, a, b, c):
-        return jnp.sum(weights * ssd_scan(x, dt, a, b, c, seg, chunk=8))
 
     def sequential(x, dt, a, b, c):
         y = jax.vmap(module._scan, in_axes=(0, 0, None, 0, 0, 0))(
             x, dt, a, b, c, first)
         return jnp.sum(weights * y)
 
-    got = jax.grad(chunked, argnums=(0, 1, 2, 3, 4))(x, dt, a, b, c)
+    got = scan_gradients((x, dt, a, b, c), seg, weights)
     want = jax.grad(sequential, argnums=(0, 1, 2, 3, 4))(x, dt, a, b, c)
     for g, w in zip(got, want):
         np.testing.assert_allclose(g, w, rtol=2e-3, atol=2e-3)
+
+
+def test_chunked_scan_gradients_in_bfloat16_are_the_float32_ones_within_rounding():
+    """Each cotangent in its argument's dtype, and within bfloat16's
+    rounding of the float32 kernel's, by norm."""
+    *args, seg = scan_inputs(seed=1)
+    low, full = in_bfloat16(*args)
+    weights = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
+    got = scan_gradients(low, seg, weights)
+    want = scan_gradients(full, seg, weights)
+    for g, w, arg in zip(got, want, low):
+        assert g.dtype == arg.dtype and g.shape == arg.shape
+        assert relative(g.astype(jnp.float32), w) < 1e-2
+
+
+def test_no_chunk_by_chunk_face_outside_the_kernels(jaxpr_equations):
+    """Forward and backward, the decays and scores of a chunk exist inside
+    the two ``pallas_call``s alone: no equation around them reads or writes
+    a value whose last two dimensions are ``(chunk, chunk)``.  Chunk 16 of
+    4 chunks, 8 heads of 32, state 24, so that no other pair of dimensions
+    reads as one."""
+    chunk = 16
+    x, dt, a, b, c, seg = scan_inputs(dim=32, n=24)
+
+    def loss(x, dt, a, b, c):
+        return jnp.sum(ssd_scan(x, dt, a, b, c, seg, chunk))
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1, 2, 3, 4)))(x, dt, a, b, c)
+    outside = list(jaxpr_equations(jaxpr.jaxpr, closed=("pallas_call",)))
+    kernels = [e.params["name"] for e in outside
+               if e.primitive.name == "pallas_call"]
+    assert kernels == ["ssd_chunk_fwd", "ssd_chunk_bwd"]
+    faces = [v.aval.shape[-2:] for e in outside for v in e.invars + e.outvars
+             if hasattr(v.aval, "shape")]
+    assert faces and (chunk, chunk) not in faces
 
 
 def test_blocked_attention_matches_plain_masked_softmax(reference):
@@ -187,15 +268,23 @@ def remat_layer(kind):
 def test_backward_pass_runs_no_dense_product_twice(kind, dense,
                                                    jaxpr_equations):
     """A product against a weight matrix is the one ``dot_general`` without
-    batch dimensions (the scan's and the attention's own carry the batch
-    and the heads): forward, dx and dW a ``Dense`` and no fourth.  With a
-    bare ``nn.remat`` the counts were 15 and 23."""
+    batch dimensions outside the scan's kernels (the attention's own carry
+    the batch and the heads; the kernels' two-dimensional ones are theirs):
+    forward, dx and dW a ``Dense`` and no fourth.  With a bare ``nn.remat``
+    the counts were 15 and 23."""
     _, loss, params, h = remat_layer(kind)
     jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1)))(params, h)
-    products = [e for e in jaxpr_equations(jaxpr.jaxpr)
+    products = [e for e in jaxpr_equations(jaxpr.jaxpr, closed=("pallas_call",))
                 if e.primitive.name == "dot_general"
                 and not e.params["dimension_numbers"][1][0]]
     assert len(products) == 3 * dense
+    # the scan's result and states are not kept (PERF.md §6, PR 32: keeping
+    # them cost more in layout copies than the forward kernel they save):
+    # the forward kernel runs again under the recomputation
+    kernels = [e.params["name"] for e in jaxpr_equations(jaxpr.jaxpr)
+               if e.primitive.name == "pallas_call"]
+    assert kernels == (["ssd_chunk_fwd", "ssd_chunk_fwd", "ssd_chunk_bwd"]
+                       if kind == "mamba" else [])
 
 
 @pytest.mark.parametrize("kind", ["mamba", "attention"])

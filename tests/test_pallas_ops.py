@@ -132,3 +132,31 @@ def test_kernel_lowers_under_its_name(name, kwargs, specs):
     exported = jax.export.export(jax.jit(fn), platforms=["tpu"])(
         *[jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in specs])
     assert f'kernel_name = "{name}"' in exported.mlir_module()
+
+
+def test_scan_kernels_lower_under_their_names(monkeypatch):
+    """``ops/ssd.py`` chooses interpret mode by the backend and takes no
+    flag, so the test says the backend is a TPU: forward and backward at
+    the granite cell's widths lower through Mosaic's rules, and the two
+    custom calls carry the names a device trace shows them under."""
+    import jax
+
+    from deep_vision_tpu.ops import ssd
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    length, heads, dim, n = 4096, 64, 64, 128
+    S = jax.ShapeDtypeStruct
+
+    def loss(x, dt, a, b, c, seg):
+        return jnp.sum(ssd.ssd_scan(x, dt, a, b, c, seg, 256))
+
+    exported = jax.export.export(
+        jax.jit(jax.grad(loss, (0, 1, 2, 3, 4))), platforms=["tpu"])(
+            S((1, length, heads, dim), jnp.bfloat16),
+            S((1, length, heads), jnp.float32), S((heads,), jnp.float32),
+            S((1, length, n), jnp.bfloat16), S((1, length, n), jnp.bfloat16),
+            S((1, length), jnp.int32))
+    text = exported.mlir_module()
+    assert 'kernel_name = "ssd_chunk_fwd"' in text
+    assert 'kernel_name = "ssd_chunk_bwd"' in text
+
