@@ -81,8 +81,10 @@ def build_parser() -> argparse.ArgumentParser:
                    metavar="KEY=JSON",
                    help="set a key of the config's extra block or of its "
                         "architecture (language models: "
-                        "num_hidden_layers=10, vocab_size=12544, "
-                        "sequence_length=1024); repeatable")
+                        "num_hidden_layers=10, vocab_size=8192, "
+                        "sequence_length=1024; a routed model's share of "
+                        "each layer's experts: expert_first=0, "
+                        "expert_count=16); repeatable")
     p.add_argument("--profile", action="store_true",
                    help="jax.profiler trace of steps 10-20 → workdir/profile")
     p.add_argument("--list", action="store_true", help="list configs and exit")

@@ -1,14 +1,34 @@
-"""Language-model experiments.  ``granite_4_0_h_micro`` is IBM's Granite
-4.0-H Micro at its published size: every key of
-https://huggingface.co/ibm-granite/granite-4.0-h-micro/blob/main/config.json
-that shapes the model (40 layers = 36 Mamba-2 + 4 attention in a period of
-ten, hidden 2048, a tied embedding of 100,352 rows: 3.19 B parameters).  It
-need not fit one chip; a deployment states its cut (``cli.train --override``,
-or the benchmark's configuration file).
+"""Language-model experiments, each at its published size: every key of the
+model's ``config.json`` that shapes it.  Neither need fit one chip; a
+deployment states its cut (``cli.train --override``, or the benchmark's
+configuration file).
 
-The published config gives no optimizer.  Assumed: AdamW 3e-4, b1 0.9,
-b2 0.95, eps 1e-8, decay 0.1 on every leaf of rank 2 and more, clip 1.0;
-rows of 4,096 tokens (the pre-training stage's length).
+``granite_4_0_h_micro``: IBM's Granite 4.0-H Micro,
+https://huggingface.co/ibm-granite/granite-4.0-h-micro/blob/main/config.json
+(40 layers = 36 Mamba-2 + 4 attention in a period of ten, hidden 2048, a
+tied embedding of 100,352 rows: 3.19 B parameters); rows of 4,096 tokens
+(the pre-training stage's length).
+
+``lfm2_24b_a2b``: Liquid AI's LFM2-24B-A2B,
+https://huggingface.co/LiquidAI/LFM2-24B-A2B/blob/main/config.json
+(``lfm2_moe``: 40 layers = 30 gated short convs + 10 rotary GQA in a period
+of four, hidden 2048, two dense layers then 38 of 64 routed experts, four a
+token, a table of 65,536 rows taken as tied: 23.8 B parameters); rows of
+8,192 tokens.  ``extra["expert_first"]`` and ``extra["expert_count"]`` say
+which experts of every layer this chip holds (default: all 64).
+``use_expert_bias`` names a selection bias and the published config and code
+give no rule that moves it; assumed: balancing without an auxiliary loss
+(Wang et al., arXiv:2408.15664; DeepSeek-V3, arXiv:2412.19437 s2.1.2), a
+training step adds ``extra["expert_bias_update_rate"]`` to the bias of every
+expert under the mean load and takes it from every one over it.  The rate
+is 3e-2, thirty times both papers': theirs is for a router under a warmed-up
+rate deep into a run; from a random start at a constant 3e-4 the router
+moves by tenths of a score within ten steps, 1e-3 does not hold the loads
+and 3e-2 settles them inside thirty steps (PERF.md s6, PR 33).
+
+Neither published config gives an optimizer.  Assumed for both: AdamW 3e-4,
+b1 0.9, b2 0.95, eps 1e-8, decay 0.1 on every leaf of rank 2 and more,
+clip 1.0.
 """
 
 import jax.numpy as jnp
@@ -39,10 +59,11 @@ GRANITE_4_0_H_MICRO = {
 }
 
 
-@register_config("granite_4_0_h_micro")
-def granite_4_0_h_micro() -> TrainConfig:
-    cfg = TrainConfig(
-        name="granite_4_0_h_micro",
+def _language_config(name: str, architecture: dict, sequence_length: int,
+                     **extra) -> TrainConfig:
+    """One packed row a step, the optimizer both models are assumed to share."""
+    return TrainConfig(
+        name=name,
         model=None,
         task="language_modeling",
         batch_size=1,  # rows of sequence_length tokens a step
@@ -50,10 +71,15 @@ def granite_4_0_h_micro() -> TrainConfig:
         optimizer=OptimizerConfig(name="adam", learning_rate=3e-4, b1=0.9,
                                   b2=0.95, eps=1e-8, weight_decay=0.1,
                                   grad_clip_norm=1.0),
-        num_classes=GRANITE_4_0_H_MICRO["vocab_size"],
-        extra={"architecture": dict(GRANITE_4_0_H_MICRO),
-               "sequence_length": 4096},
+        num_classes=architecture["vocab_size"],
+        extra={"architecture": dict(architecture),
+               "sequence_length": sequence_length, **extra},
     )
+
+
+@register_config("granite_4_0_h_micro")
+def granite_4_0_h_micro() -> TrainConfig:
+    cfg = _language_config("granite_4_0_h_micro", GRANITE_4_0_H_MICRO, 4096)
 
     def model():
         # built when asked for, so that an override of ``extra`` counts
@@ -64,6 +90,41 @@ def granite_4_0_h_micro() -> TrainConfig:
 
         return GraniteHybrid(
             GraniteHybridConfig.from_dict(cfg.extra["architecture"]),
+            dtype=jnp.bfloat16 if cfg.half_precision else jnp.float32)
+
+    cfg.model = model
+    return cfg
+
+
+_LFM2_PERIOD = ["conv", "conv", "full_attention", "conv"]
+
+LFM2_24B_A2B = {
+    "conv_L_cache": 3, "conv_bias": False, "hidden_size": 2048,
+    "intermediate_size": 11776, "layer_types": _LFM2_PERIOD * 10,
+    "max_position_embeddings": 128000, "model_type": "lfm2_moe",
+    "moe_intermediate_size": 1536, "norm_eps": 1e-05, "norm_topk_prob": True,
+    "num_attention_heads": 32, "num_dense_layers": 2, "num_experts": 64,
+    "num_experts_per_tok": 4, "num_hidden_layers": 40,
+    "num_key_value_heads": 8,
+    "rope_parameters": {"rope_theta": 1000000, "rope_type": "default"},
+    "routed_scaling_factor": 1, "use_expert_bias": True, "vocab_size": 65536,
+}
+
+
+@register_config("lfm2_24b_a2b")
+def lfm2_24b_a2b() -> TrainConfig:
+    cfg = _language_config("lfm2_24b_a2b", LFM2_24B_A2B, 8192,
+                           expert_first=0, expert_count=None,
+                           expert_bias_update_rate=3e-2)
+
+    def model():
+        from deep_vision_tpu.models.lfm2_moe import Lfm2Moe, Lfm2MoeConfig
+
+        return Lfm2Moe(
+            Lfm2MoeConfig.from_dict(cfg.extra["architecture"],
+                                    cfg.extra["expert_first"],
+                                    cfg.extra["expert_count"],
+                                    cfg.extra["expert_bias_update_rate"]),
             dtype=jnp.bfloat16 if cfg.half_precision else jnp.float32)
 
     cfg.model = model
