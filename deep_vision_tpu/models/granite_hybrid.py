@@ -23,10 +23,11 @@ is kept between the passes is, a layer, its ``(B, L, hidden)`` input and the
 bfloat16 tensors ``KEPT`` names: the outputs of the products against a
 weight matrix that the backward pass reads (``in_proj`` and ``out_proj`` of
 a Mamba mixer, ``q/k/v`` and ``o_proj`` of attention, the MLP's
-``in_proj``), so that no such product runs twice, and the blocked
-attention's output, so that its blocks are computed again once (by
-``ops/attention.py``'s own checkpoint) and not twice.  Norms, conv, SiLU,
-the scan's forward kernel (``ops/ssd.py``: a chunk's ``(chunk, chunk)``
+``in_proj``), so that no such product runs twice, and the attention
+kernel's output with its float32 log-sum-exp a row and head
+(``ops/attention.py`` names both in its forward rule), so that the forward
+kernel runs once and the backward kernel builds a block's probabilities
+from them.  Norms, conv, SiLU, the scan's forward kernel (``ops/ssd.py``: a chunk's ``(chunk, chunk)``
 decays and scores live in VMEM inside it and in the backward kernel, and
 nowhere else) and the SwiGLU product are computed again.  The kept set costs 532 KB a token for the ten layers of a
 pipeline stage at the published widths (527 of them the products'
@@ -48,7 +49,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from deep_vision_tpu.ops.attention import causal_attention
+from deep_vision_tpu.ops import attention
 from deep_vision_tpu.ops.ssd import ssd_scan
 
 
@@ -108,9 +109,10 @@ class GraniteHybridConfig:
 
 
 # what a rematerialised layer keeps between the passes beside its input:
-# the outputs of these products against a weight matrix, and the attention's
+# the outputs of these products against a weight matrix, and the attention
+# kernel's output and log-sum-exp
 KEPT = ("mixer_in_proj", "mixer_out_proj", "q_proj", "k_proj", "v_proj",
-        "attention_out", "o_proj", "ffn_in_proj")
+        attention.OUT, attention.LSE, "o_proj", "ffn_in_proj")
 
 
 def _normal():
@@ -212,12 +214,11 @@ class AttentionMixer(nn.Module):
                          kernel_init=_normal(), name=name)(u), name)
             return y.reshape(*y.shape[:2], heads, cfg.head_dim)
 
-        out = checkpoint_name(causal_attention(
+        out = attention.causal_attention(
             proj(cfg.num_attention_heads, "q_proj"),
             proj(cfg.num_key_value_heads, "k_proj"),
             proj(cfg.num_key_value_heads, "v_proj"),
-            segment_ids, cfg.attention_multiplier, self.attention_block),
-            "attention_out")
+            segment_ids, cfg.attention_multiplier, self.attention_block)
         return checkpoint_name(
             nn.Dense(cfg.hidden_size, use_bias=False, dtype=self.dtype,
                      kernel_init=_normal(), name="o_proj")(

@@ -42,8 +42,10 @@ sum goes on to the next layer.
 Each layer is rematerialised in the backward pass.  Kept between the
 passes, beside a layer's ``(B, L, hidden)`` input, are the bfloat16 outputs
 ``KEPT`` names: the products against a weight matrix of the operators and of
-the dense block, and the blocked attention's output, so that none of them
-runs twice.  The routed block keeps its routing (``ops/moe.py``'s
+the dense block, and the attention kernel's output with its float32
+log-sum-exp a row and head (``ops/attention.py`` names both in its forward
+rule), so that none of them runs twice and the backward kernel builds a
+block's probabilities from the log-sum-exp.  The routed block keeps its routing (``ops/moe.py``'s
 ``moe_routing``: the chosen experts and the sort, integers of 0.5 MB a
 layer), so the top-k and the sort run once; its rows are not kept: the
 buffers are sized for the worst case (``L x k`` rows, 0.33 GB a layer for
@@ -74,8 +76,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from deep_vision_tpu.models.granite_hybrid import RMSNorm, causal_conv
-from deep_vision_tpu.ops import moe
-from deep_vision_tpu.ops.attention import causal_attention
+from deep_vision_tpu.ops import attention, moe
 
 
 @dataclasses.dataclass(frozen=True)
@@ -143,8 +144,8 @@ class Lfm2MoeConfig:
 
 
 # what a rematerialised layer keeps between the passes beside its input
-KEPT = ("conv_in_proj", "q_proj", "k_proj", "v_proj", "attention_out",
-        "operator_out_proj", "ffn_w1", "ffn_w3", moe.ROUTING)
+KEPT = ("conv_in_proj", "q_proj", "k_proj", "v_proj", attention.OUT,
+        attention.LSE, "operator_out_proj", "ffn_w1", "ffn_w3", moe.ROUTING)
 COUNTERS = ("assignments", "max_load", "unrouted_tokens", "dropped", "bias_lift")
 
 
@@ -216,12 +217,11 @@ class GroupedQueryAttention(nn.Module):
             y = RMSNorm(cfg.norm_eps, jnp.float32, name=norm)(y)
             return rotary(y, positions, cfg.rope_theta).astype(self.dtype)
 
-        out = checkpoint_name(causal_attention(
+        out = attention.causal_attention(
             heads(cfg.num_attention_heads, "q_proj", "q_layernorm"),
             heads(cfg.num_key_value_heads, "k_proj", "k_layernorm"),
             heads(cfg.num_key_value_heads, "v_proj", None),
-            segment_ids, cfg.head_dim ** -0.5, self.attention_block),
-            "attention_out")
+            segment_ids, cfg.head_dim ** -0.5, self.attention_block)
         return checkpoint_name(
             _dense(cfg.hidden_size, self.dtype, "out_proj")(
                 out.reshape(*out.shape[:2], -1)), "operator_out_proj")

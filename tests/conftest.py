@@ -64,6 +64,26 @@ def jaxpr_equations():
     return _equations
 
 
+@pytest.fixture
+def kept_between_passes(capsys):
+    """``kept(loss, *args)``: dtype and shape of what autodiff keeps of
+    ``loss`` between the passes, in the order computed, beside its
+    arguments, constants and the cosine a test's own ``sin`` keeps."""
+    def kept(loss, *args):
+        jax.ad_checkpoint.print_saved_residuals(loss, *args)
+        found = []
+        for line in capsys.readouterr().out.splitlines():
+            aval, where = line.split(" ", 1)
+            if where.startswith(("from the argument", "from a constant")) \
+                    or "output of cos" in where:
+                continue
+            dtype, shape = aval[:-1].split("[")
+            found.append((dtype, tuple(int(n) for n in shape.split(","))))
+        return found
+
+    return kept
+
+
 @pytest.fixture(scope="session")
 def images_with_margin():
     """``pick(logits_of, n, margin)``: n uint8 32x32x1 images whose two

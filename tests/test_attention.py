@@ -1,0 +1,167 @@
+"""``ops/attention.py``'s two kernels, interpreted, against a plain masked
+softmax: heads of 16, rows of 64 in blocks of 16 or one block of 64, a
+batch of two rows.  Row 0 of ``DOCUMENTS`` holds a document that starts on a
+block's first row (16), one inside a block (20..22) and one that spans three
+blocks (23..57); row 1 is one document."""
+
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deep_vision_tpu.ops import attention
+from deep_vision_tpu.ops.attention import causal_attention
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+LENGTH, DIM = 64, 16
+HEADS = [(4, 2), (4, 4), (8, 1)]
+STARTS = [16, 20, 23, 58]
+SCALE = 0.3   # no power of two
+
+
+def documents():
+    first = np.zeros((2, LENGTH), np.int32)
+    first[0, STARTS] = 1
+    return jnp.asarray(np.cumsum(first, axis=1, dtype=np.int32))
+
+
+def inputs(heads, kv_heads, dtype=jnp.float32, seed=2):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return tuple(jax.random.normal(key, (2, LENGTH, n, DIM)).astype(dtype)
+                 for key, n in zip(keys, (heads, kv_heads, kv_heads)))
+
+
+def plain(q, k, v, seg, scale):
+    """Every score of the row at once, float32 at full precision."""
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    group = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision="highest") * scale
+    at = jnp.arange(q.shape[1])
+    visible = (at[:, None] >= at[None, :]) & (seg[:, :, None] == seg[:, None, :])
+    p = jax.nn.softmax(jnp.where(visible[:, None], s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v, precision="highest")
+
+
+def gradients(fn, q, k, v):
+    return jax.grad(lambda *a: jnp.sum(jnp.sin(fn(*a).astype(jnp.float32))),
+                    (0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("block", [16, 64], ids=["four_blocks", "one_block"])
+@pytest.mark.parametrize("heads, kv_heads", HEADS)
+def test_kernels_match_plain_masked_softmax(heads, kv_heads, block):
+    """Float32, at ``tests/test_granite_hybrid.py``'s limits for the loop
+    these kernels replaced."""
+    seg = documents()
+    q, k, v = inputs(heads, kv_heads)
+    got = causal_attention(q, k, v, seg, SCALE, block)
+    np.testing.assert_allclose(got, plain(q, k, v, seg, SCALE),
+                               rtol=2e-5, atol=2e-5)
+    got = gradients(lambda *a: causal_attention(*a, seg, SCALE, block), q, k, v)
+    want = gradients(lambda *a: plain(*a, seg, SCALE), q, k, v)
+    for g, w in zip(got, want):
+        assert float(jnp.abs(w).max()) > 0.1
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("heads, kv_heads", HEADS)
+def test_bfloat16_operands_are_the_float32_result_within_their_rounding(
+        heads, kv_heads):
+    """The same bfloat16 values through the kernels (bfloat16 operands,
+    float32 scores, probabilities and ``ds`` rounded to bfloat16 before
+    their products, results rounded to bfloat16) and through the plain form
+    in float32: the output within 2e-2, a gradient within 3% of its largest
+    entry (both are of order one here; bfloat16 keeps eight bits)."""
+    seg = documents()
+    q, k, v = inputs(heads, kv_heads, jnp.bfloat16)
+    got = causal_attention(q, k, v, seg, SCALE, 16)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(got.astype(jnp.float32),
+                               plain(q, k, v, seg, SCALE), atol=2e-2)
+    got = gradients(lambda *a: causal_attention(*a, seg, SCALE, 16), q, k, v)
+    want = gradients(lambda *a: plain(*a, seg, SCALE), q, k, v)
+    for g, w in zip(got, want):
+        assert g.dtype == jnp.bfloat16
+        error = jnp.abs(g.astype(jnp.float32) - w.astype(jnp.float32)).max()
+        assert float(error) <= 0.03 * float(jnp.abs(w).max())
+
+
+@pytest.mark.parametrize("heads, kv_heads", HEADS)
+def test_a_later_document_leaves_an_earlier_one_bit_identical(heads, kv_heads):
+    seg = documents()
+    q, k, v = inputs(heads, kv_heads)
+    before = causal_attention(q, k, v, seg, SCALE, 16)
+    later = (seg == 3)[:, :, None, None]   # rows 23..57 of row 0
+    after = causal_attention(q, jnp.where(later, k + 1.5, k),
+                             jnp.where(later, 2.0 * v, v), seg, SCALE, 16)
+    earlier = np.asarray(seg < 3) & (np.arange(LENGTH) < STARTS[2])
+    moved = np.abs(np.asarray(after - before)).max(axis=(2, 3))
+    assert moved[np.asarray(later[:, :, 0, 0])].min() > 1e-4
+    assert np.array_equal(np.asarray(after)[earlier], np.asarray(before)[earlier])
+
+
+def test_heads_that_do_not_divide_and_blocks_that_do_not_are_refused():
+    q, k, v = inputs(4, 2)
+    with pytest.raises(ValueError, match="query heads"):
+        causal_attention(q, k[:, :, :1].repeat(3, axis=2), v, documents(), SCALE, 16)
+    with pytest.raises(ValueError, match="not a multiple"):
+        causal_attention(q, k, v, documents(), SCALE, 48)
+
+
+def test_no_block_by_block_face_outside_the_kernels(jaxpr_equations):
+    """Forward and backward, a block's scores and probabilities exist inside
+    the two ``pallas_call``s alone, and ``k`` and ``v`` are never repeated to
+    the query heads' count: no value around the kernels has a
+    ``(block, block)`` face or more elements than ``q``.  Heads of 32 and
+    blocks of 16, so that no other pair of dimensions reads as one."""
+    block, seg = 16, documents()
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    q, k, v = (jax.random.normal(key, (2, LENGTH, n, 32))
+               for key, n in zip(keys, (4, 2, 2)))
+
+    def loss(q, k, v):
+        return jnp.sum(causal_attention(q, k, v, seg, SCALE, block))
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, v)
+    outside = list(jaxpr_equations(jaxpr.jaxpr, closed=("pallas_call",)))
+    kernels = [e.params["name"] for e in outside
+               if e.primitive.name == "pallas_call"]
+    assert kernels == ["causal_gqa_fwd", "causal_gqa_bwd"]
+    shapes = [v.aval.shape for e in outside for v in e.invars + e.outvars
+              if hasattr(v.aval, "shape")]
+    assert shapes and not any(s[-2:] == (block, block) for s in shapes)
+    assert max(int(np.prod(s)) for s in shapes) == q.size
+
+
+@pytest.mark.parametrize("cell, model_block", [
+    ("LFM2-24B-A2B", 1024), ("granite-4.0-h-micro", 512)])
+def test_blocks_and_vmem_at_the_cells_shapes(cell, model_block):
+    """At the rows and heads of the two language cells the kernels' blocks
+    divide the row, fill whole 128-lane tiles, and what the kernels hold in
+    VMEM by the module's own reckoning is under the limit they ask for, which
+    is a quarter of a v5e core's 128 MiB at most."""
+    with open(os.path.join(ROOT, "benchmark", "configs", cell + ".json")) as f:
+        cfg = json.load(f)
+    length = cfg["sequence_length"]
+    heads, kv_heads = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+    dim = cfg.get("head_dim") or cfg["hidden_size"] // heads
+    assert (length, heads, kv_heads, dim) == (
+        {"LFM2-24B-A2B": 8192, "granite-4.0-h-micro": 4096}[cell], 32, 8, 64)
+    per_tile = attention._heads_per_tile(kv_heads, dim)
+    block_q, block_k = attention._blocks(length, model_block)
+    assert per_tile * dim == attention.LANE
+    assert length % block_q == 0 and length % block_k == 0
+    assert block_q % attention.LANE == 0 and block_k % 16 == 0
+    assert max(block_q, block_k) <= model_block
+    held = attention.vmem_bytes(length, heads // kv_heads, per_tile, dim,
+                                block_q, block_k, itemsize=2)
+    assert set(held) == {"causal_gqa_fwd", "causal_gqa_bwd"}
+    for kernel, n in held.items():
+        asked = attention.vmem_limit(n)
+        assert 0 < n <= asked <= 32 * attention.MIB, (kernel, n, asked)
+    # the forward kernel lives within Mosaic's default
+    assert attention.vmem_limit(held["causal_gqa_fwd"]) == 16 * attention.MIB

@@ -509,6 +509,36 @@ def test_scan_kernels_compile_for_the_chip_at_published_widths(
     assert not re.search(rf"f32\[[\d,]*{chunk},{chunk}\]", hlo)
 
 
+@pytest.mark.parametrize("length, block", [(8192, 1024), (4096, 512)])
+def test_attention_kernels_compile_for_the_chip_at_the_cells_shapes(
+        one_v5e_chip, monkeypatch, length, block):
+    """Beside the scan's: the attention's two kernels (``ops/attention.py``)
+    at the shapes of the two language cells -- rows of 8,192 and 4,096, 32 / 8
+    heads of 64, bfloat16, the models' ``attention_block`` -- go through
+    Mosaic, tiling and VMEM included; ``k`` and ``v`` reach the kernels at
+    their own eight heads; and the program around them holds no float32
+    value with a face of the kernels' blocks."""
+    import re
+
+    from deep_vision_tpu.ops import attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def S(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    def loss(q, k, v, seg):
+        return jnp.sum(attention.causal_attention(
+            q, k, v, seg, 0.125, block).astype(jnp.float32))
+
+    hlo = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        S((1, length, 32, 64)), S((1, length, 8, 64)), S((1, length, 8, 64)),
+        S((1, length), jnp.int32)).compile().as_text()
+    assert hlo.count("tpu_custom_call") == 2
+    block_q, block_k = attention._blocks(length, block)
+    assert not re.search(rf"f32\[[\d,]*({block_k},{block_q}|{length},{length})\]", hlo)
+
+
 def test_average_precision_perfect():
     r = np.array([0.5, 1.0])
     p = np.array([1.0, 1.0])

@@ -1,7 +1,7 @@
 """The hybrid state-space decoder at a small size on the CPU: hidden 64,
 4 heads of 16, state 16, chunk 8, rows of 64, three layers
 mamba / attention / mamba, 128 rows of vocabulary.  The chunked scan and the
-blocked attention against their plain forms, the model against the
+attention kernels against their plain forms, the model against the
 benchmark's plain reference, and what ties a packed row's documents apart."""
 
 import json
@@ -268,10 +268,10 @@ def remat_layer(kind):
 def test_backward_pass_runs_no_dense_product_twice(kind, dense,
                                                    jaxpr_equations):
     """A product against a weight matrix is the one ``dot_general`` without
-    batch dimensions outside the scan's kernels (the attention's own carry
-    the batch and the heads; the kernels' two-dimensional ones are theirs):
-    forward, dx and dW a ``Dense`` and no fourth.  With a bare ``nn.remat``
-    the counts were 15 and 23."""
+    batch dimensions outside the scan's and the attention's kernels (the
+    kernels' two-dimensional ones are theirs): forward, dx and dW a
+    ``Dense`` and no fourth.  With a bare ``nn.remat`` the counts were 15
+    and 23."""
     _, loss, params, h = remat_layer(kind)
     jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1)))(params, h)
     products = [e for e in jaxpr_equations(jaxpr.jaxpr, closed=("pallas_call",))
@@ -280,29 +280,25 @@ def test_backward_pass_runs_no_dense_product_twice(kind, dense,
     assert len(products) == 3 * dense
     # the scan's result and states are not kept (PERF.md §6, PR 32: keeping
     # them cost more in layout copies than the forward kernel they save):
-    # the forward kernel runs again under the recomputation
+    # the forward kernel runs again under the recomputation.  The
+    # attention's output and log-sum-exp are kept (PR 34), so its forward
+    # kernel runs once
     kernels = [e.params["name"] for e in jaxpr_equations(jaxpr.jaxpr)
                if e.primitive.name == "pallas_call"]
     assert kernels == (["ssd_chunk_fwd", "ssd_chunk_fwd", "ssd_chunk_bwd"]
-                       if kind == "mamba" else [])
+                       if kind == "mamba"
+                       else ["causal_gqa_fwd", "causal_gqa_bwd"])
 
 
 @pytest.mark.parametrize("kind", ["mamba", "attention"])
-def test_layer_keeps_its_input_and_its_dense_outputs(kind, capsys):
+def test_layer_keeps_its_input_and_its_dense_outputs(kind, kept_between_passes):
     """Beside parameters, constants and the layer's input (and the cosine
     this test's own loss keeps), what is kept between the passes is what
     ``KEPT`` names: the output of each product against a weight matrix that
-    the backward pass reads and the blocked attention's, and nothing with a
-    ``(chunk, chunk)`` face."""
+    the backward pass reads, the attention kernel's output and its float32
+    log-sum-exp a row and head, and nothing with a ``(chunk, chunk)`` face."""
     cfg, loss, params, h = remat_layer(kind)
-    jax.ad_checkpoint.print_saved_residuals(loss, params, h)
-    kept = []
-    for line in capsys.readouterr().out.splitlines():
-        aval, where = line.split(" ", 1)
-        if where.startswith(("from the argument", "from a constant")) \
-                or "output of cos" in where:
-            continue
-        kept.append(tuple(int(n) for n in aval.split("[")[1][:-1].split(",")))
+    kept = [shape for _, shape in kept_between_passes(loss, params, h)]
     rows = h.shape[:2]
     mlp = rows + (2 * cfg.shared_intermediate_size,)
     if kind == "mamba":
@@ -312,6 +308,7 @@ def test_layer_keeps_its_input_and_its_dense_outputs(kind, capsys):
         kv = rows + (cfg.num_key_value_heads * cfg.head_dim,)
         heads = rows + (cfg.num_attention_heads, cfg.head_dim)
         want = [rows + (cfg.hidden_size,), kv, kv, heads,
+                (rows[0], cfg.num_attention_heads, rows[1]),
                 rows + (cfg.hidden_size,), mlp]
     assert kept == want
     chunk = cfg.mamba_chunk_size

@@ -439,6 +439,47 @@ def test_gradients_equal_those_of_the_model_without_remat(monkeypatch):
             float(jnp.linalg.norm(w)), 1e-30), leaf
 
 
+def test_attention_layer_launches_each_kernel_once_and_keeps_what_kept_names(
+        jaxpr_equations, kept_between_passes):
+    """The ``full_attention`` layer with routed experts under ``RematLayer``:
+    a gradient launches the attention's forward kernel once and its backward
+    kernel once (the output and the log-sum-exp are kept, so the
+    recomputation has no use for a second forward), and what is kept between
+    the passes beside parameters, constants, the layer's input and this
+    test's own cosine is what ``KEPT`` names, in the order computed."""
+    from deep_vision_tpu.models import lfm2_moe
+
+    cfg = Lfm2MoeConfig.from_dict(SMALL, 4, 4)
+    seg = jnp.asarray(small_rows()["segment_ids"])
+    h = jax.random.normal(jax.random.PRNGKey(7), seg.shape + (cfg.hidden_size,))
+    positions = jnp.broadcast_to(jnp.arange(LENGTH), seg.shape)
+    layer = lfm2_moe.RematLayer(cfg, "full_attention", True, 16, jnp.float32)
+    variables = layer.init(jax.random.PRNGKey(8), h, seg, positions)
+
+    def loss(params, h):
+        return jnp.sum(jnp.sin(layer.apply(
+            {**variables, "params": params}, h, seg, positions)[0]))
+
+    params = variables["params"]
+    jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1)))(params, h)
+    kernels = [e.params["name"] for e in jaxpr_equations(jaxpr.jaxpr)
+               if e.primitive.name == "pallas_call"]
+    assert [k for k in kernels if k and k.startswith("causal_gqa")] == [
+        "causal_gqa_fwd", "causal_gqa_bwd"]
+    kept = kept_between_passes(loss, params, h)
+    rows, k = h.shape[:2], cfg.num_experts_per_tok
+    hidden = ("f32", rows + (cfg.hidden_size,))
+    kv = ("f32", rows + (cfg.num_key_value_heads * cfg.head_dim,))
+    assert kept[:6] == [
+        hidden, kv, kv,                                        # q, k, v
+        ("f32", rows + (cfg.num_attention_heads, cfg.head_dim)),  # the output
+        ("f32", (rows[0], cfg.num_attention_heads, rows[1])),  # log-sum-exp
+        hidden]                                                # out_proj
+    # the routing: integers of the chosen experts and of the sort
+    assert kept[6:] and all(dtype == "i32" and np.prod(shape) <= np.prod(rows) * k
+                            for dtype, shape in kept[6:])
+
+
 def op_names(lowered_text):
     return set(re.findall(r'loc\("([^"]*)"', lowered_text))
 
