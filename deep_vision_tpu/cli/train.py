@@ -84,7 +84,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "num_hidden_layers=10, vocab_size=8192, "
                         "sequence_length=1024; a routed model's share of "
                         "each layer's experts: expert_first=0, "
-                        "expert_count=16); repeatable")
+                        "expert_count=16; a prediction module's "
+                        "mtp_loss_weight=0.3); repeatable")
     p.add_argument("--profile", action="store_true",
                    help="jax.profiler trace of steps 10-20 → workdir/profile")
     p.add_argument("--list", action="store_true", help="list configs and exit")
@@ -528,7 +529,8 @@ def _main_language(args, cfg, mesh):
     val_loader = ArrayLoader(
         rows(max(args.synthetic_size // 4, cfg.eval_batch_size), 2),
         cfg.eval_batch_size, shuffle=False)
-    trainer = Trainer(cfg, cfg.model(), LanguageModelingTask(), mesh=mesh,
+    task = LanguageModelingTask(cfg.extra.get("mtp_loss_weight", 0.0))
+    trainer = Trainer(cfg, cfg.model(), task, mesh=mesh,
                       workdir=args.workdir, upload=args.upload)
     if args.profile:
         trainer.profile_steps = (10, 20)

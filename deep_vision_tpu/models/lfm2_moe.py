@@ -21,7 +21,10 @@ holds it); the forms follow the published implementation:
 The conv is depthwise, causal, ``conv_L_cache`` taps, no bias, no
 activation.  Conv, rotary positions and the attention mask all start anew
 at a document's first token (``segment_ids``).  The router's product, the
-sigmoid and the top-k are float32 (``ops/moe.py``).
+sigmoid and the top-k are float32 (``ops/moe.py``).  The published
+``routed_scaling_factor`` is 1 and ``REQUIRED`` refuses another: the model
+calls ``ops/moe.py::route`` with its defaults (``scale`` 1, ``eps`` 1e-6),
+which give the weights above to the bit.
 
 **The selection bias** ``b`` (``use_expert_bias``) chooses and does not weigh.
 It is no parameter: no gradient reaches it and the optimizer never sees it.
@@ -147,6 +150,17 @@ class Lfm2MoeConfig:
 KEPT = ("conv_in_proj", "q_proj", "k_proj", "v_proj", attention.OUT,
         attention.LSE, "operator_out_proj", "ffn_w1", "ffn_w3", moe.ROUTING)
 COUNTERS = ("assignments", "max_load", "unrouted_tokens", "dropped", "bias_lift")
+
+
+def model_counters(per_layer: list) -> dict:
+    """The routed layers' ``COUNTERS`` as the model hands them on."""
+    stacked = {k: jnp.stack([c[k] for c in per_layer]) if per_layer
+               else jnp.zeros((1,), jnp.float32) for k in COUNTERS}
+    return {"moe_assignments": stacked["assignments"].sum(),
+            "moe_max_load": stacked["max_load"].max(),
+            "moe_unrouted_tokens": stacked["unrouted_tokens"].mean(),
+            "moe_dropped": stacked["dropped"].sum(),
+            "moe_bias_lift": stacked["bias_lift"].mean()}
 
 
 def _normal():
@@ -336,10 +350,4 @@ class Lfm2Moe(nn.Module):
             h = RMSNorm(cfg.norm_eps, self.dtype, name="final_norm")(h)
             logits = jnp.einsum("bld,vd->blv", h, table.astype(self.dtype),
                                 preferred_element_type=jnp.float32)
-        stacked = {k: jnp.stack([c[k] for c in per_layer]) if per_layer
-                   else jnp.zeros((1,), jnp.float32) for k in COUNTERS}
-        return logits, {"moe_assignments": stacked["assignments"].sum(),
-                        "moe_max_load": stacked["max_load"].max(),
-                        "moe_unrouted_tokens": stacked["unrouted_tokens"].mean(),
-                        "moe_dropped": stacked["dropped"].sum(),
-                        "moe_bias_lift": stacked["bias_lift"].mean()}
+        return logits, model_counters(per_layer)

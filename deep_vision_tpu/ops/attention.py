@@ -25,7 +25,10 @@ one visit: ``dq`` is carried in VMEM over a query block's keys, ``dk`` and
 step's key heads and are summed over the query heads that share them.
 
 Layout inside the kernels.  A grid step takes the key/value heads that fill
-one 128-lane tile (two heads of 64) and the query heads that share them;
+one 128-lane tile (two heads of 64; one head where a head is a tile wide or
+wider, as a latent-attention model's 256, and then no lanes are zeroed and
+a product runs over the head's whole width) and the query heads that share
+them;
 ``k`` and ``v`` are read once for all of those and never repeated in HBM.
 Every block of scores is held transposed, keys down the sublanes and queries
 along the lanes, so that the softmax's maximum and sum run down the sublanes
@@ -63,7 +66,11 @@ from deep_vision_tpu.ops.ssd import F32, LANE, NT, _dot, _interpret, _lanes_of
 _MASKED = -1e30  # finite: a row whose keys are all masked so far stays finite
 #: names of what the backward pass reads beside ``q``, ``k``, ``v``
 OUT, LSE = "attention_out", "attention_lse"
-#: the largest block of queries (lanes of a face) and of keys (sublanes)
+#: the largest block of queries (lanes of a face) and of keys (sublanes).
+#: On the chip (PERF.md s6: PR 34 at 32 / 8 heads of 64, PR 35 at 20 / 20
+#: heads of 256, rows of 8,192, forward + backward with the layout passes)
+#: 512 x 512 read 12.8 and 19.9 ms a layer; at heads of 256, 256 x 512 read
+#: 22.8, 512 x 256 22.0, 256 x 256 27.3, and 1,024 x 512 19.1
 MAX_BLOCK_Q, MAX_BLOCK_K = 512, 512
 MIB = 2 ** 20
 

@@ -48,16 +48,18 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 HIGHEST = jax.lax.Precision.HIGHEST
-NORM_EPS = 1e-6  # the published code's, added to the chosen scores' sum
+NORM_EPS = 1e-6  # the LFM2 code's, added to the chosen scores' sum (route's default)
 ROUTING = "moe_routing"  # checkpoint name of the choice and of the sort
 
 
-def route(u, router, bias, k: int):
+def route(u, router, bias, k: int, scale: float = 1.0, eps: float = NORM_EPS):
     """``u`` (T, D), ``router`` (D, E), ``bias`` (E,).  Returns the chosen
     experts (T, k) int32 and their weights (T, k) float32: sigmoid scores,
     chosen by ``score + bias`` (the bias chooses and does not weigh),
-    normalised over all ``k`` chosen.  The gradient reaches ``router``
-    through the weights alone; none flows through the choice or ``bias``."""
+    normalised over all ``k`` chosen (their sum + ``eps``) and multiplied by
+    ``scale`` (a model's ``routed_scaling_factor``).  The gradient reaches
+    ``router`` through the weights alone; none flows through the choice or
+    ``bias``."""
     f32 = jnp.float32
     with jax.named_scope("moe_route"):
         scores = jax.nn.sigmoid(jnp.dot(u.astype(f32), router.astype(f32),
@@ -65,7 +67,8 @@ def route(u, router, bias, k: int):
         _, indices = jax.lax.top_k(jax.lax.stop_gradient(scores + bias), k)
         indices = checkpoint_name(indices, ROUTING)
         chosen = jnp.take_along_axis(scores, indices, axis=-1)
-        return indices, chosen / (chosen.sum(-1, keepdims=True) + NORM_EPS)
+        weights = chosen / (chosen.sum(-1, keepdims=True) + eps)
+        return indices, weights if scale == 1.0 else weights * scale
 
 
 def balanced_bias(bias, indices, rate: float):

@@ -1,5 +1,5 @@
 """Language-model experiments, each at its published size: every key of the
-model's ``config.json`` that shapes it.  Neither need fit one chip; a
+model's ``config.json`` that shapes it.  None need fit one chip; a
 deployment states its cut (``cli.train --override``, or the benchmark's
 configuration file).
 
@@ -24,9 +24,30 @@ expert under the mean load and takes it from every one over it.  The rate
 is 3e-2, thirty times both papers': theirs is for a router under a warmed-up
 rate deep into a run; from a random start at a constant 3e-4 the router
 moves by tenths of a score within ten steps, 1e-3 does not hold the loads
-and 3e-2 settles them inside thirty steps (PERF.md s6, PR 33).
+and 3e-2 settles them inside thirty steps (PERF.md s6, PR 33).  The model
+calls ``ops/moe.py::route`` with its defaults (``scale`` 1, ``eps`` 1e-6):
+its config's ``routed_scaling_factor`` is 1 and the model refuses another.
 
-Neither published config gives an optimizer.  Assumed for both: AdamW 3e-4,
+``glm_4_7_flash``: Z.ai's GLM-4.7-Flash,
+https://huggingface.co/zai-org/GLM-4.7-Flash/blob/main/config.json
+(``glm4_moe_lite``, 30B-A3B: 47 layers of latent attention (MLA: queries
+through a latent of 768, keys and values through one of 512 with a single
+rotary key of 64 shared by the 20 heads, heads of 192 + 64 and values of
+256), hidden 2048, one dense layer of 10,240 then 46 of 64 routed experts of
+1,536, four a token, beside one shared expert, an untied table and head of
+154,880 rows: 29.94 B parameters, and one multi-token-prediction module of
+0.7 B more); rows of 8,192 tokens.  The share of the experts and the
+selection bias's rule and rate are as for ``lfm2_24b_a2b`` and for its
+reason; the router's weights are ``route(..., scale=1.8, eps=1e-20)`` (sigmoid
+scores, ``noaux_tc``: DeepSeek-V3 arXiv:2412.19437 s2.1.2, as transformers'
+``glm4_moe`` router is remembered).  The config names one prediction module
+and gives neither its form nor its loss weight; assumed: DeepSeek-V3's
+(arXiv:2412.19437 s2.2: the next token's embedding and the last layer's
+stream, each normed, joined and projected, one decoder layer, the shared
+head) at ``extra["mtp_loss_weight"]`` 0.3 (s4.2's first value, which GLM-4.5
+arXiv:2508.06471 follows).
+
+No published config gives an optimizer.  Assumed for all: AdamW 3e-4,
 b1 0.9, b2 0.95, eps 1e-8, decay 0.1 on every leaf of rank 2 and more,
 clip 1.0.
 """
@@ -61,7 +82,7 @@ GRANITE_4_0_H_MICRO = {
 
 def _language_config(name: str, architecture: dict, sequence_length: int,
                      **extra) -> TrainConfig:
-    """One packed row a step, the optimizer both models are assumed to share."""
+    """One packed row a step, the optimizer the models are assumed to share."""
     return TrainConfig(
         name=name,
         model=None,
@@ -125,6 +146,46 @@ def lfm2_24b_a2b() -> TrainConfig:
                                     cfg.extra["expert_first"],
                                     cfg.extra["expert_count"],
                                     cfg.extra["expert_bias_update_rate"]),
+            dtype=jnp.bfloat16 if cfg.half_precision else jnp.float32)
+
+    cfg.model = model
+    return cfg
+
+
+GLM_4_7_FLASH = {
+    "attention_bias": False, "hidden_act": "silu", "hidden_size": 2048,
+    "intermediate_size": 10240, "max_position_embeddings": 202752,
+    "model_type": "glm4_moe_lite", "moe_intermediate_size": 1536,
+    "topk_method": "noaux_tc", "norm_topk_prob": True,
+    "num_attention_heads": 20, "n_group": 1, "topk_group": 1,
+    "n_routed_experts": 64, "n_shared_experts": 1,
+    "routed_scaling_factor": 1.8, "num_experts_per_tok": 4,
+    "first_k_dense_replace": 1, "num_hidden_layers": 47,
+    "num_key_value_heads": 20, "num_nextn_predict_layers": 1,
+    "partial_rotary_factor": 1, "rms_norm_eps": 1e-05, "rope_scaling": None,
+    "rope_theta": 1000000, "tie_word_embeddings": False, "q_lora_rank": 768,
+    "kv_lora_rank": 512, "qk_nope_head_dim": 192, "qk_rope_head_dim": 64,
+    "v_head_dim": 256, "vocab_size": 154880,
+}
+
+
+@register_config("glm_4_7_flash")
+def glm_4_7_flash() -> TrainConfig:
+    cfg = _language_config("glm_4_7_flash", GLM_4_7_FLASH, 8192,
+                           expert_first=0, expert_count=None,
+                           expert_bias_update_rate=3e-2, mtp_loss_weight=0.3)
+
+    def model():
+        from deep_vision_tpu.models.glm4_moe_lite import (
+            Glm4MoeLite,
+            Glm4MoeLiteConfig,
+        )
+
+        return Glm4MoeLite(
+            Glm4MoeLiteConfig.from_dict(cfg.extra["architecture"],
+                                        cfg.extra["expert_first"],
+                                        cfg.extra["expert_count"],
+                                        cfg.extra["expert_bias_update_rate"]),
             dtype=jnp.bfloat16 if cfg.half_precision else jnp.float32)
 
     cfg.model = model

@@ -104,6 +104,37 @@ def test_a_later_document_leaves_an_earlier_one_bit_identical(heads, kv_heads):
     assert np.array_equal(np.asarray(after)[earlier], np.asarray(before)[earlier])
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_heads_of_256_with_group_one_match_the_plain_form(dtype):
+    """The latent-attention cell's layout: every query head its own key
+    head, a head two 128-lane tiles wide (one head a grid step, no lanes
+    zeroed), scale 1/16; forward and backward, at the float32 test's and at
+    the bfloat16 test's limits (the scores sum 256 products, so the values
+    are drawn at a quarter of the other tests')."""
+    seg = documents()
+    keys = jax.random.split(jax.random.PRNGKey(6), 3)
+    q, k, v = (jax.random.normal(key, (2, LENGTH, 3, 256)).astype(dtype)
+               for key in keys)
+    assert attention._heads_per_tile(3, 256) == 1
+    got = causal_attention(q, k, v, seg, 1 / 16, 16)
+    want = plain(q, k, v, seg, 1 / 16)
+    assert got.dtype == dtype and float(jnp.abs(want).max()) > 1.0
+    got_g = gradients(lambda *a: causal_attention(*a, seg, 1 / 16, 16), q, k, v)
+    want_g = gradients(lambda *a: plain(*a, seg, 1 / 16), q, k, v)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+        for g, w in zip(got_g, want_g):
+            assert float(jnp.abs(w).max()) > 0.1
+            np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5)
+    else:
+        np.testing.assert_allclose(got.astype(jnp.float32), want, atol=3e-2)
+        for g, w in zip(got_g, want_g):
+            assert g.dtype == jnp.bfloat16
+            error = jnp.abs(g.astype(jnp.float32) - w.astype(jnp.float32)).max()
+            assert float(error) <= 0.03 * float(jnp.abs(w).max())
+
+
 def test_heads_that_do_not_divide_and_blocks_that_do_not_are_refused():
     q, k, v = inputs(4, 2)
     with pytest.raises(ValueError, match="query heads"):
@@ -165,3 +196,27 @@ def test_blocks_and_vmem_at_the_cells_shapes(cell, model_block):
         assert 0 < n <= asked <= 32 * attention.MIB, (kernel, n, asked)
     # the forward kernel lives within Mosaic's default
     assert attention.vmem_limit(held["causal_gqa_fwd"]) == 16 * attention.MIB
+
+
+def test_blocks_and_vmem_at_the_latent_attention_cells_shapes():
+    """Rows of 8,192, 20 heads of 192 + 64 each with its own key head, the
+    model's block: one head a grid step (two whole tiles wide), blocks of
+    512 x 512, and the backward kernel, whose ``dk`` and ``dv`` for the whole
+    row are 8 MiB each and held twice, asks for 44 MiB of a v5e core's 128."""
+    from deep_vision_tpu.models.glm4_moe_lite import LatentAttention
+
+    with open(os.path.join(ROOT, "benchmark", "configs", "GLM-4.7-Flash.json")) as f:
+        cfg = json.load(f)
+    length, heads = cfg["sequence_length"], cfg["num_attention_heads"]
+    dim = cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"]
+    assert (length, heads, cfg["num_key_value_heads"], dim, cfg["v_head_dim"]) == (
+        8192, 20, 20, 256, 256)
+    per_tile = attention._heads_per_tile(heads, dim)
+    block_q, block_k = attention._blocks(length, LatentAttention.attention_block)
+    assert per_tile == 1 and dim % attention.LANE == 0
+    assert (block_q, block_k) == (512, 512)
+    held = attention.vmem_bytes(length, 1, per_tile, dim, block_q, block_k,
+                                itemsize=2)
+    assert 2 * 2 * 4 * length * dim <= held["causal_gqa_bwd"]
+    assert attention.vmem_limit(held["causal_gqa_fwd"]) == 16 * attention.MIB
+    assert attention.vmem_limit(held["causal_gqa_bwd"]) == 44 * attention.MIB

@@ -509,15 +509,17 @@ def test_scan_kernels_compile_for_the_chip_at_published_widths(
     assert not re.search(rf"f32\[[\d,]*{chunk},{chunk}\]", hlo)
 
 
-@pytest.mark.parametrize("length, block", [(8192, 1024), (4096, 512)])
+@pytest.mark.parametrize("length, block, heads, kv_heads, dim", [
+    (8192, 1024, 32, 8, 64), (4096, 512, 32, 8, 64), (8192, 512, 20, 20, 256)],
+    ids=["lfm2", "granite", "glm"])
 def test_attention_kernels_compile_for_the_chip_at_the_cells_shapes(
-        one_v5e_chip, monkeypatch, length, block):
+        one_v5e_chip, monkeypatch, length, block, heads, kv_heads, dim):
     """Beside the scan's: the attention's two kernels (``ops/attention.py``)
-    at the shapes of the two language cells -- rows of 8,192 and 4,096, 32 / 8
-    heads of 64, bfloat16, the models' ``attention_block`` -- go through
-    Mosaic, tiling and VMEM included; ``k`` and ``v`` reach the kernels at
-    their own eight heads; and the program around them holds no float32
-    value with a face of the kernels' blocks."""
+    at the shapes of the three language cells -- rows of 8,192 and 4,096, 32
+    / 8 heads of 64 and 20 / 20 heads of 256, bfloat16, the models'
+    ``attention_block`` -- go through Mosaic, tiling and VMEM included; ``k``
+    and ``v`` reach the kernels at their own heads; and the program around
+    them holds no float32 value with a face of the kernels' blocks."""
     import re
 
     from deep_vision_tpu.ops import attention
@@ -532,8 +534,8 @@ def test_attention_kernels_compile_for_the_chip_at_the_cells_shapes(
             q, k, v, seg, 0.125, block).astype(jnp.float32))
 
     hlo = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
-        S((1, length, 32, 64)), S((1, length, 8, 64)), S((1, length, 8, 64)),
-        S((1, length), jnp.int32)).compile().as_text()
+        S((1, length, heads, dim)), S((1, length, kv_heads, dim)),
+        S((1, length, kv_heads, dim)), S((1, length), jnp.int32)).compile().as_text()
     assert hlo.count("tpu_custom_call") == 2
     block_q, block_k = attention._blocks(length, block)
     assert not re.search(rf"f32\[[\d,]*({block_k},{block_q}|{length},{length})\]", hlo)

@@ -450,8 +450,12 @@ def test_prefetcher_counts_tokens_and_documents(mesh1):
         stream = prefetcher.iterate([batch] * 3,
                                     counters=LanguageModelingTask.batch_counters)
         assert len(list(stream)) == 3
+        seg = batch["segment_ids"]
+        pairs = int(((seg[:, :, None] == seg[:, None, :])
+                     & np.tril(np.ones((LENGTH, LENGTH), bool))).sum())
         assert stream.stats()["counters"] == {"tokens": 3 * 2 * LENGTH,
-                                              "documents": 3 * docs}
+                                              "documents": 3 * docs,
+                                              "pairs": 3 * pairs}
 
 
 def test_profiled_epoch_puts_the_counters_in_the_spans_header(tmp_path, mesh1):

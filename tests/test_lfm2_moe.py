@@ -145,6 +145,26 @@ def test_route_chooses_by_the_biased_scores_and_weighs_by_the_scores():
     np.testing.assert_allclose(weights.sum(-1), 1.0, atol=1e-5)
 
 
+def test_route_with_scale_one_is_bit_for_bit_what_it_was():
+    """``scale`` and ``eps`` came with a model whose router scales its
+    weights; left at their defaults, or given as 1 and 1e-6, the weights are
+    the bits of ``chosen / (sum + 1e-6)``, and a scale multiplies them."""
+    u, router, bias = route_inputs()
+    indices, weights = moe.route(u, router, bias, 4)
+    scores = jax.nn.sigmoid(jnp.dot(u, router, precision=moe.HIGHEST))
+    chosen = jnp.take_along_axis(scores, indices, axis=-1)
+    was = chosen / (chosen.sum(-1, keepdims=True) + 1e-6)
+    np.testing.assert_array_equal(weights, was)
+    same_indices, same = moe.route(u, router, bias, 4, scale=1.0, eps=moe.NORM_EPS)
+    np.testing.assert_array_equal(same_indices, indices)
+    np.testing.assert_array_equal(same, was)
+    scaled_indices, scaled = moe.route(u, router, bias, 4, scale=1.8, eps=1e-20)
+    np.testing.assert_array_equal(scaled_indices, indices)
+    np.testing.assert_allclose(scaled, 1.8 * chosen / chosen.sum(-1, keepdims=True),
+                               rtol=1e-6)
+    assert float(jnp.abs(scaled.sum(-1) - 1.8).max()) < 1e-5
+
+
 def test_route_stays_float32_under_a_bfloat16_model():
     u, router, bias = route_inputs()
     indices, weights = moe.route(u.astype(jnp.bfloat16), router, bias, 4)
