@@ -13,7 +13,21 @@ Queries are taken a block of rows at a time; each block walks the blocks of
 keys that hold a row at or before its last one, in order, with a running
 maximum and a running sum (the online softmax of Milakov & Gimelshein
 arXiv:1805.02867, as flash attention uses it, Dao et al. arXiv:2205.14135).
-Key blocks wholly above the diagonal are neither read nor computed.  A
+Key blocks wholly above the diagonal are neither read nor computed, and
+neither are the key blocks of earlier documents: a small int32 table, made
+from the segment ids outside the kernels and handed to both by scalar
+prefetch, gives each block of queries its first block of keys, the first
+whose largest id reaches the queries' smallest; a grid step stands for the
+block that many after it, and the steps past the diagonal's block do
+nothing.  No key of a block before the first shares an id with a query of
+the block, whatever the ids' order, so such a block would come out all
+masked, and a masked score contributes exactly nothing: in the forward
+kernel whatever a row gathered over masked keys is multiplied by
+``exp(-1e30 - m) = 0`` at its first visible key, in the backward kernel a
+masked probability is ``exp(-1e30 - lse) = 0`` and every term it enters is a
+zero added to a sum.  Leaving those blocks out therefore changes no bit of
+the output or of a cotangent; rows of one document visit every block at or
+below the diagonal, as they have to (``visited_blocks`` counts both).  A
 block's scores, its mask, the maximum, the sum and the probabilities live in
 VMEM and nowhere else: HBM sees ``q``, ``k``, ``v``, the output and one
 float32 log-sum-exp a query row and head, and in the backward pass ``do``,
@@ -57,6 +71,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -70,7 +85,11 @@ OUT, LSE = "attention_out", "attention_lse"
 #: On the chip (PERF.md s6: PR 34 at 32 / 8 heads of 64, PR 35 at 20 / 20
 #: heads of 256, rows of 8,192, forward + backward with the layout passes)
 #: 512 x 512 read 12.8 and 19.9 ms a layer; at heads of 256, 256 x 512 read
-#: 22.8, 512 x 256 22.0, 256 x 256 27.3, and 1,024 x 512 19.1
+#: 22.8, 512 x 256 22.0, 256 x 256 27.3, and 1,024 x 512 19.1.  With the
+#: earlier documents' blocks skipped (PR 36, the cells' packed rows, 42% of
+#: the 512 x 512 causal pairs visited): 6.0 and 11.6 ms at 512 x 512; at heads
+#: of 256 256 x 512 14.0, 512 x 256 13.5, 256 x 256 17.7, 1,024 x 512 11.5; at
+#: heads of 64 256 x 256 9.3 and 1,024 x 512 6.6
 MAX_BLOCK_Q, MAX_BLOCK_K = 512, 512
 MIB = 2 ** 20
 
@@ -132,14 +151,39 @@ def vmem_limit(held: int) -> int:
     return max(16 * MIB, -(-held // (4 * MIB)) * 4 * MIB)
 
 
-def _bias(segq_ref, segk_ref, qi, ki, causal: bool):
+def _first_blocks(seg, block_q: int, block_k: int):
+    """(B, L) ids -> (B, L / block_q) int32: for each block of queries the
+    first block of keys whose largest id reaches the queries' smallest.  No
+    key of an earlier block shares an id with any query of the block, whatever
+    the ids' order; the block that holds the queries' last row always reaches
+    it.  Array methods only, so that the kernels' caller (traced) and
+    ``visited_blocks`` (NumPy, on the host) read one definition."""
+    bsz, length = seg.shape
+    low = seg.reshape(bsz, length // block_q, block_q).min(axis=-1)
+    high = seg.reshape(bsz, length // block_k, block_k).max(axis=-1)
+    return (high[:, None, :] >= low[:, :, None]).argmax(axis=-1).astype("int32")
+
+
+def visited_blocks(segment_ids, block: int = 512) -> tuple[int, int]:
+    """Pairs of (block of queries, block of keys) the two kernels compute for
+    these rows at ``block``, summed over the rows, and the pairs at or below
+    the diagonal, which is what rows of one document each would visit.  A
+    count a tile of key heads; on the host."""
+    seg = np.asarray(segment_ids)
+    block_q, block_k = _blocks(seg.shape[1], block)
+    last = _last_block(np.arange(seg.shape[1] // block_q), block_q, block_k)
+    first = _first_blocks(seg, block_q, block_k)
+    return int((last - first + 1).sum()), int(seg.shape[0] * (last + 1).sum())
+
+
+def _bias(segq_ref, segk_ref, qi, kb, causal: bool):
     """0 where the key (sublane) is visible to the query (lane), ``_MASKED``
     elsewhere: the same document and, in a block that crosses the diagonal,
     not later in the row."""
     visible = segk_ref[0] == segq_ref[0]
     if causal:
         block_k, block_q = visible.shape
-        key = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
+        key = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
         query = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (1, block_q), 1)
         visible &= query >= key
     return jnp.where(visible, 0.0, _MASKED)
@@ -154,13 +198,13 @@ def _only_head(tile, n: int, per_tile: int, dim: int):
     return jnp.where(_lanes_of(lane, n, dim), tile, jnp.zeros_like(tile))
 
 
-def _visited(qi, ki, block_q: int, block_k: int, body):
-    """Run ``body(causal)`` where the block of keys holds a row the block
-    of queries sees; the comparison of positions only where it crosses the
-    diagonal."""
-    crosses = (ki + 1) * block_k - 1 > qi * block_q
+def _visited(qi, kb, block_q: int, block_k: int, body):
+    """Run ``body(causal)`` where the block of keys ``kb`` holds a row the
+    block of queries sees; the comparison of positions only where it crosses
+    the diagonal."""
+    crosses = (kb + 1) * block_k - 1 > qi * block_q
 
-    @pl.when((ki <= _last_block(qi, block_q, block_k)) & crosses)
+    @pl.when((kb <= _last_block(qi, block_q, block_k)) & crosses)
     def _():
         body(True)
 
@@ -169,10 +213,12 @@ def _visited(qi, ki, block_q: int, block_k: int, body):
         body(False)
 
 
-def _fwd_kernel(q_ref, k_ref, vt_ref, segq_ref, segk_ref, o_ref, lse_ref,
-                top, total, acc, *, scale: float, dim: int, per_tile: int):
+def _fwd_kernel(first_ref, q_ref, k_ref, vt_ref, segq_ref, segk_ref, o_ref,
+                lse_ref, top, total, acc, *, scale: float, dim: int,
+                per_tile: int):
     # dvtlint: traced
     qi, ki = pl.program_id(2), pl.program_id(3)
+    kb = first_ref[pl.program_id(0), qi] + ki   # the step's block of keys
     group, block_q = q_ref.shape[2], q_ref.shape[3]
     block_k, dtype = k_ref.shape[2], vt_ref.dtype
 
@@ -183,7 +229,7 @@ def _fwd_kernel(q_ref, k_ref, vt_ref, segq_ref, segk_ref, o_ref, lse_ref,
         acc[...] = jnp.zeros(acc.shape, F32)
 
     def attend(causal):
-        bias = _bias(segq_ref, segk_ref, qi, ki, causal)
+        bias = _bias(segq_ref, segk_ref, qi, kb, causal)
         keys = [_only_head(k_ref[0, 0], n, per_tile, dim) for n in range(per_tile)]
 
         def head(g, carry):
@@ -203,9 +249,9 @@ def _fwd_kernel(q_ref, k_ref, vt_ref, segq_ref, segk_ref, o_ref, lse_ref,
 
         jax.lax.fori_loop(0, group, head, 0)
 
-    _visited(qi, ki, block_q, block_k, attend)
+    _visited(qi, kb, block_q, block_k, attend)
 
-    @pl.when(ki == _last_block(qi, block_q, block_k))
+    @pl.when(kb == _last_block(qi, block_q, block_k))
     def _():
         for g in range(group):
             for n in range(per_tile):
@@ -215,11 +261,12 @@ def _fwd_kernel(q_ref, k_ref, vt_ref, segq_ref, segk_ref, o_ref, lse_ref,
                 lse_ref[0, 0, r] = top[r] + jnp.log(total[r])
 
 
-def _bwd_kernel(q_ref, k_ref, kt_ref, v_ref, do_ref, lse_ref, delta_ref,
-                segq_ref, segk_ref, dq_ref, dk_ref, dv_ref, dq_acc, *,
+def _bwd_kernel(first_ref, q_ref, k_ref, kt_ref, v_ref, do_ref, lse_ref,
+                delta_ref, segq_ref, segk_ref, dq_ref, dk_ref, dv_ref, dq_acc, *,
                 scale: float, dim: int, per_tile: int):
     # dvtlint: traced
     qi, ki = pl.program_id(2), pl.program_id(3)
+    kb = first_ref[pl.program_id(0), qi] + ki   # the step's block of keys
     group, block_q = q_ref.shape[2], q_ref.shape[3]
     block_k, width, dtype = k_ref.shape[2], k_ref.shape[3], q_ref.dtype
 
@@ -233,7 +280,7 @@ def _bwd_kernel(q_ref, k_ref, kt_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_acc[...] = jnp.zeros(dq_acc.shape, F32)
 
     def visit(causal):
-        bias = _bias(segq_ref, segk_ref, qi, ki, causal)
+        bias = _bias(segq_ref, segk_ref, qi, kb, causal)
         keys = [_only_head(k_ref[0, 0], n, per_tile, dim) for n in range(per_tile)]
         values = [_only_head(v_ref[0, 0], n, per_tile, dim) for n in range(per_tile)]
 
@@ -254,13 +301,13 @@ def _bwd_kernel(q_ref, k_ref, kt_ref, v_ref, do_ref, lse_ref, delta_ref,
 
         zero = jnp.zeros((block_k, width), F32)
         dk, dv = jax.lax.fori_loop(0, group, head, (zero, zero))
-        here = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
+        here = pl.ds(pl.multiple_of(kb * block_k, block_k), block_k)
         dk_ref[0, 0, here] += dk * scale
         dv_ref[0, 0, here] += dv
 
-    _visited(qi, ki, block_q, block_k, visit)
+    _visited(qi, kb, block_q, block_k, visit)
 
-    @pl.when(ki == _last_block(qi, block_q, block_k))
+    @pl.when(kb == _last_block(qi, block_q, block_k))
     def _():
         dq_ref[0, 0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
@@ -307,28 +354,34 @@ def _rows_of_heads(x, tiles: int):
 
 def _specs(group, per_tile, width, length, block_q, block_k):
     """Block specs over the grid (row, tile of key heads, block of queries,
-    block of keys); a block of keys past the last one visited is the last
-    one again, so that nothing is read for it."""
-    def key(qi, ki):
-        return jnp.minimum(ki, _last_block(qi, block_q, block_k))
+    step over its blocks of keys), every index map with the prefetched table
+    of first blocks after the grid's indices: step ``ki`` takes block
+    ``first[b, qi] + ki``, and a step past the last block visited takes the
+    last one again, so that nothing is read for it."""
+    def key(b, qi, ki, first):
+        return jnp.minimum(first[b, qi] + ki, _last_block(qi, block_q, block_k))
 
     heads = group * per_tile
     return dict(
         q=pl.BlockSpec((1, 1, group, block_q, width),
-                       lambda b, j, qi, ki: (b, j, 0, qi, 0)),
+                       lambda b, j, qi, ki, first: (b, j, 0, qi, 0)),
         qt=pl.BlockSpec((1, 1, group, width, block_q),
-                        lambda b, j, qi, ki: (b, j, 0, 0, qi)),
-        k=pl.BlockSpec((1, 1, block_k, width),
-                       lambda b, j, qi, ki: (b, j, key(qi, ki), 0)),
-        kt=pl.BlockSpec((1, 1, width, block_k),
-                        lambda b, j, qi, ki: (b, j, 0, key(qi, ki))),
+                        lambda b, j, qi, ki, first: (b, j, 0, 0, qi)),
+        k=pl.BlockSpec(
+            (1, 1, block_k, width),
+            lambda b, j, qi, ki, first: (b, j, key(b, qi, ki, first), 0)),
+        kt=pl.BlockSpec(
+            (1, 1, width, block_k),
+            lambda b, j, qi, ki, first: (b, j, 0, key(b, qi, ki, first))),
         row=pl.BlockSpec((1, 1, heads, 1, block_q),
-                         lambda b, j, qi, ki: (b, j, 0, 0, qi)),
-        segq=pl.BlockSpec((1, 1, block_q), lambda b, j, qi, ki: (b, 0, qi)),
-        segk=pl.BlockSpec((1, block_k, 1),
-                          lambda b, j, qi, ki: (b, key(qi, ki), 0)),
+                         lambda b, j, qi, ki, first: (b, j, 0, 0, qi)),
+        segq=pl.BlockSpec((1, 1, block_q),
+                          lambda b, j, qi, ki, first: (b, 0, qi)),
+        segk=pl.BlockSpec(
+            (1, block_k, 1),
+            lambda b, j, qi, ki, first: (b, key(b, qi, ki, first), 0)),
         whole=pl.BlockSpec((1, 1, length, width),
-                           lambda b, j, qi, ki: (b, j, 0, 0)))
+                           lambda b, j, qi, ki, first: (b, j, 0, 0)))
 
 
 def _compiler_params(carried: int, held: int):
@@ -356,15 +409,18 @@ def _forward(q, k, v, segment_ids, scale: float, block: int, interpret: bool):
         functools.partial(_fwd_kernel, scale=scale, dim=dim, per_tile=per_tile),
         out_shape=(jax.ShapeDtypeStruct((bsz, tiles, group, width, length), v.dtype),
                    jax.ShapeDtypeStruct((bsz, tiles, heads, 1, length), F32)),
-        grid=(bsz, tiles, length // block_q, length // block_k),
-        in_specs=[s["q"], s["k"], s["kt"], s["segq"], s["segk"]],
-        out_specs=(s["qt"], s["row"]),
-        scratch_shapes=[pltpu.VMEM((heads, 1, block_q), F32),
-                        pltpu.VMEM((heads, 1, block_q), F32),
-                        pltpu.VMEM((group, width, block_q), F32)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bsz, tiles, length // block_q, length // block_k),
+            in_specs=[s["q"], s["k"], s["kt"], s["segq"], s["segk"]],
+            out_specs=(s["qt"], s["row"]),
+            scratch_shapes=[pltpu.VMEM((heads, 1, block_q), F32),
+                            pltpu.VMEM((heads, 1, block_q), F32),
+                            pltpu.VMEM((group, width, block_q), F32)]),
         name="causal_gqa_fwd", interpret=interpret,
         compiler_params=_compiler_params(1, held["causal_gqa_fwd"]),
-    )(_gather_queries(q, tiles, per_tile, group), _gather_keys(k, tiles),
+    )(_first_blocks(seg, block_q, block_k),
+      _gather_queries(q, tiles, per_tile, group), _gather_keys(k, tiles),
       jnp.swapaxes(_gather_keys(v, tiles), 2, 3), seg[:, None, :], seg[:, :, None])
     return _scatter_queries(out, dim), lse.reshape(bsz, tiles * heads, length)
 
@@ -385,14 +441,17 @@ def _backward(q, k, v, segment_ids, out, lse, do, scale: float, block: int,
         functools.partial(_bwd_kernel, scale=scale, dim=dim, per_tile=per_tile),
         out_shape=(jax.ShapeDtypeStruct((bsz, tiles, group, width, length), q.dtype),
                    whole, whole),
-        grid=(bsz, tiles, length // block_q, length // block_k),
-        in_specs=[s["q"], s["k"], s["kt"], s["k"], s["q"], s["row"], s["row"],
-                  s["segq"], s["segk"]],
-        out_specs=(s["qt"], s["whole"], s["whole"]),
-        scratch_shapes=[pltpu.VMEM((group, width, block_q), F32)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bsz, tiles, length // block_q, length // block_k),
+            in_specs=[s["q"], s["k"], s["kt"], s["k"], s["q"], s["row"],
+                      s["row"], s["segq"], s["segk"]],
+            out_specs=(s["qt"], s["whole"], s["whole"]),
+            scratch_shapes=[pltpu.VMEM((group, width, block_q), F32)]),
         name="causal_gqa_bwd", interpret=interpret,
         compiler_params=_compiler_params(2, held["causal_gqa_bwd"]),
-    )(_gather_queries(q, tiles, per_tile, group), keys, jnp.swapaxes(keys, 2, 3),
+    )(_first_blocks(seg, block_q, block_k),
+      _gather_queries(q, tiles, per_tile, group), keys, jnp.swapaxes(keys, 2, 3),
       _gather_keys(v, tiles), _gather_queries(do.astype(q.dtype), tiles, per_tile, group),
       lse.reshape(bsz, tiles, heads, 1, length), _rows_of_heads(delta, tiles),
       seg[:, None, :], seg[:, :, None])

@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deep_vision_tpu.ops.attention import visited_blocks
+
 
 def _token_xent(logits, targets):
     logits = logits.astype(jnp.float32)
@@ -59,14 +61,24 @@ class LanguageModelingTask:
         """Host-side counts of one batch for the input block of
         ``metrics.jsonl``: segment ids count up from each row's first.
         ``pairs`` are the (query, key) pairs a causal document mask leaves
-        visible, what an attention layer's score and value products need."""
+        visible, what an attention layer's score and value products need;
+        ``attn_blocks`` the pairs of blocks ``ops/attention.py``'s kernels
+        compute for these rows at the op's default ``block`` (a count a tile
+        of key heads a layer), ``attn_blocks_causal`` what they would compute
+        were every row one document; neither for rows that block does not
+        divide."""
         seg = batch["segment_ids"]
         # a document of n tokens leaves n (n + 1) / 2; every row starts one
         first = np.diff(seg, axis=1, prepend=seg[:, :1] - 1) != 0
         n = np.diff(np.flatnonzero(first.ravel()), append=seg.size).astype(np.int64)
-        return {"tokens": int(seg.size),
-                "documents": int((seg[:, -1] - seg[:, 0] + 1).sum()),
-                "pairs": int((n * (n + 1) // 2).sum())}
+        counts = {"tokens": int(seg.size),
+                  "documents": int((seg[:, -1] - seg[:, 0] + 1).sum()),
+                  "pairs": int((n * (n + 1) // 2).sum())}
+        try:
+            counts["attn_blocks"], counts["attn_blocks_causal"] = visited_blocks(seg)
+        except ValueError:
+            pass
+        return counts
 
     @staticmethod
     def _split(outputs) -> tuple:

@@ -2,7 +2,9 @@
 softmax: heads of 16, rows of 64 in blocks of 16 or one block of 64, a
 batch of two rows.  Row 0 of ``DOCUMENTS`` holds a document that starts on a
 block's first row (16), one inside a block (20..22) and one that spans three
-blocks (23..57); row 1 is one document."""
+blocks (23..57); row 1 is one document.  ``packings`` adds a row in which
+every token is its own document: the key blocks of earlier documents, which
+the kernels skip, are all but the diagonal's there."""
 
 import json
 import os
@@ -11,6 +13,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from deep_vision_tpu.ops import attention
 from deep_vision_tpu.ops.attention import causal_attention
@@ -32,6 +36,29 @@ def inputs(heads, kv_heads, dtype=jnp.float32, seed=2):
     keys = jax.random.split(jax.random.PRNGKey(seed), 3)
     return tuple(jax.random.normal(key, (2, LENGTH, n, DIM)).astype(dtype)
                  for key, n in zip(keys, (heads, kv_heads, kv_heads)))
+
+
+def packings():
+    """``documents()`` and a third row of ``LENGTH`` documents."""
+    return jnp.concatenate(
+        [documents(), jnp.arange(LENGTH, dtype=jnp.int32)[None]], axis=0)
+
+
+def no_skipping(seg, block_q, block_k):
+    """A table that starts every block of queries at the row's first block
+    of keys: every block at or below the diagonal is visited."""
+    return jnp.zeros((seg.shape[0], seg.shape[1] // block_q), jnp.int32)
+
+
+def needed_blocks(seg, block_q, block_k):
+    """(B, blocks of queries, blocks of keys) bool by brute force: some key
+    of the block is visible to some query of the block."""
+    seg = np.asarray(seg)
+    at = np.arange(seg.shape[1])
+    visible = (at[:, None] >= at[None, :]) & (seg[:, :, None] == seg[:, None, :])
+    bsz, length = seg.shape
+    return visible.reshape(bsz, length // block_q, block_q,
+                           length // block_k, block_k).any(axis=(2, 4))
 
 
 def plain(q, k, v, seg, scale):
@@ -133,6 +160,142 @@ def test_heads_of_256_with_group_one_match_the_plain_form(dtype):
             assert g.dtype == jnp.bfloat16
             error = jnp.abs(g.astype(jnp.float32) - w.astype(jnp.float32)).max()
             assert float(error) <= 0.03 * float(jnp.abs(w).max())
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("heads, kv_heads, dim",
+                         [(*h, DIM) for h in HEADS] + [(3, 3, 256)])
+def test_skipping_earlier_documents_changes_no_bit(monkeypatch, heads, kv_heads,
+                                                   dim, dtype):
+    """Both kernels with the table computed from the ids and with a table of
+    zeros (every causal block visited): the output, the log-sum-exp and the
+    three cotangents are the same bits, on rows whose documents end on a
+    block's edge, inside a block, span several blocks, fill the row, and are
+    one token each."""
+    seg = packings()
+    keys = jax.random.split(jax.random.PRNGKey(4), 4)
+    q, k, v, do = (jax.random.normal(key, (3, LENGTH, n, dim)).astype(dtype)
+                   for key, n in zip(keys, (heads, kv_heads, kv_heads, heads)))
+    first = attention._first_blocks(seg, 16, 16)
+    assert first[0].tolist() == [0, 1, 1, 1] and first[2].tolist() == [0, 1, 2, 3]
+    assert not first[1].any()
+    got = {}
+    for name, table in [("computed", attention._first_blocks),
+                        ("zeros", no_skipping)]:
+        monkeypatch.setattr(attention, "_first_blocks", table)
+        # traced afresh: the module's jitted halves keep their first trace
+        static = ("scale", "block", "interpret")
+        forward = jax.jit(attention._forward.__wrapped__, static_argnames=static)
+        backward = jax.jit(attention._backward.__wrapped__, static_argnames=static)
+        out, lse = forward(q, k, v, seg, SCALE, 16, True)
+        got[name] = (out, lse) + backward(q, k, v, seg, out, lse, do, SCALE,
+                                          16, True)[:3]
+    for a, b in zip(got["computed"], got["zeros"]):
+        assert a.dtype == b.dtype and float(jnp.abs(a.astype(jnp.float32)).max()) > 0.1
+        assert np.array_equal(np.asarray(a.astype(jnp.float32)),
+                              np.asarray(b.astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("ids", ["falling", "shuffled", "returning"])
+@pytest.mark.parametrize("heads, kv_heads", HEADS[:2])
+def test_ids_in_any_order_match_the_plain_masked_softmax(heads, kv_heads, ids):
+    """The table of first blocks only ever drops blocks none of whose keys
+    shares an id with a query of the block, so ids that fall, ids drawn a
+    token at a time and a document that comes back after another are masked
+    as the plain form masks them, values and gradients."""
+    rng = np.random.default_rng(5)
+    rising = np.asarray(documents())
+    seg = {"falling": rising.max() - rising,
+           "shuffled": rng.integers(0, 3, size=(2, LENGTH)),
+           "returning": np.where((np.arange(LENGTH) // 8) % 3 == 1, 7, rising),
+           }[ids].astype(np.int32)
+    first = np.asarray(attention._first_blocks(seg, 16, 16))
+    if ids == "returning":
+        assert first[0].tolist() == [0, 0, 0, 0] and first[1].tolist() == [0, 0, 0, 0]
+    seg = jnp.asarray(seg)
+    q, k, v = inputs(heads, kv_heads)
+    got = causal_attention(q, k, v, seg, SCALE, 16)
+    np.testing.assert_allclose(got, plain(q, k, v, seg, SCALE),
+                               rtol=2e-5, atol=2e-5)
+    got = gradients(lambda *a: causal_attention(*a, seg, SCALE, 16), q, k, v)
+    want = gradients(lambda *a: plain(*a, seg, SCALE), q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("block_q, block_k", [(16, 16), (32, 16), (16, 32), (8, 64)])
+def test_first_block_against_every_visible_pair(block_q, block_k):
+    """On random packings (ids that count up) the blocks from the table's to
+    the diagonal's are exactly those that hold a visible pair; on ids in any
+    order no block that holds one lies before the table's.  As traced values
+    and as NumPy arrays the table is the same."""
+    rng = np.random.default_rng(7)
+    starts = rng.random((6, 256)) < rng.choice([0.01, 0.03, 0.2], size=(6, 1))
+    rising = np.cumsum(starts, axis=1).astype(np.int32)
+    any_order = rng.integers(0, 4, size=(6, 256)).astype(np.int32)
+    at = np.arange(256 // block_k)
+    last = attention._last_block(np.arange(256 // block_q), block_q, block_k)
+    for seg, exact in [(rising, True), (any_order, False)]:
+        first = attention._first_blocks(seg, block_q, block_k)
+        assert isinstance(first, np.ndarray) and first.dtype == np.int32
+        assert np.array_equal(
+            first, jax.jit(attention._first_blocks, static_argnums=(1, 2))(
+                jnp.asarray(seg), block_q, block_k))
+        assert (first <= last).all()
+        visited = (at >= first[:, :, None]) & (at <= last[:, None])
+        needed = needed_blocks(seg, block_q, block_k)
+        assert not (needed & ~visited).any()
+        if exact:
+            assert np.array_equal(needed, visited)
+            # the packings leave work to skip and work to do
+            causal = len(seg) * (at <= last[:, None]).sum()
+            assert 0.2 < needed.sum() / causal < 0.8
+
+
+@pytest.mark.parametrize("block", [16, 32, 64])
+def test_host_count_is_what_the_kernels_admit(block):
+    """``visited_blocks`` against the grid as the kernels walk it: the op's
+    own table, prefetched, and the op's own ``_visited`` over the op's grid,
+    each admitted step counted once."""
+    seg = packings()
+    block_q, block_k = attention._blocks(LENGTH, block)
+    grid = (seg.shape[0], LENGTH // block_q, LENGTH // block_k)
+
+    def kernel(first_ref, o_ref):
+        qi = pl.program_id(1)
+        kb = first_ref[pl.program_id(0), qi] + pl.program_id(2)
+        o_ref[...] = jnp.zeros(o_ref.shape, jnp.int32)
+
+        def count(causal):
+            o_ref[...] += 1
+
+        attention._visited(qi, kb, block_q, block_k, count)
+
+    admitted = pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct(grid, jnp.int32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=[],
+            out_specs=pl.BlockSpec((1, 1, 1), lambda b, qi, ki, first: (b, qi, ki))),
+        interpret=True)(attention._first_blocks(seg, block_q, block_k))
+    assert int(admitted.max()) == 1
+    visited, causal = attention.visited_blocks(seg, block)
+    assert visited == int(admitted.sum())
+    per_row = sum(int(attention._last_block(qi, block_q, block_k)) + 1
+                  for qi in range(grid[1]))
+    assert causal == 3 * per_row
+    assert visited == int(needed_blocks(seg, block_q, block_k).sum())
+    assert attention.visited_blocks(seg[1:2], block) == (per_row, per_row)
+    assert attention.visited_blocks(seg[2:], block) == (grid[1], per_row)
+
+
+def test_block_counts_at_the_cells_rows():
+    """Rows of 8,192 at the op's default block: 136 pairs of 512 x 512 at or
+    below the diagonal, all of them for one document, the diagonal's 16 where
+    every token is its own."""
+    assert attention.visited_blocks(np.zeros((1, 8192), np.int32)) == (136, 136)
+    assert attention.visited_blocks(np.arange(8192, dtype=np.int32)[None]) == (16, 136)
+    assert attention.visited_blocks(np.zeros((2, 4096), np.int32)) == (72, 72)
 
 
 def test_heads_that_do_not_divide_and_blocks_that_do_not_are_refused():

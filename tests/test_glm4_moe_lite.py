@@ -532,6 +532,8 @@ def test_three_steps_through_train_epoch_log_the_counters(tmp_path, mesh1):
     assert "train_token_accuracy" in series
     want = LanguageModelingTask.batch_counters(batch)
     assert series["input_pairs_per_step"] == [float(want["pairs"])]
+    assert series["input_attn_blocks_per_step"] == [2.0]
+    assert series["input_attn_blocks_causal_per_step"] == [2.0]
     assert series["input_tokens_per_step"] == [float(2 * LENGTH)]
 
 
@@ -543,9 +545,25 @@ def test_pairs_counts_what_a_causal_document_mask_leaves_visible():
     got = LanguageModelingTask.batch_counters(batch)
     assert got["pairs"] == int(visible.sum())
     assert got["tokens"] == 2 * LENGTH
+    # a row of LENGTH is one face of the attention kernels' default block
+    assert (got["attn_blocks"], got["attn_blocks_causal"]) == (2, 2)
     one = LanguageModelingTask.batch_counters(
         {"segment_ids": np.zeros((1, LENGTH), np.int32)})
     assert one["pairs"] == LENGTH * (LENGTH + 1) // 2 and one["documents"] == 1
+
+
+def test_attention_blocks_reach_the_input_series_beside_pairs():
+    """Rows of 1,024, two faces of 512 a side: a row whose second half is
+    another document leaves the face below the diagonal unvisited."""
+    seg = np.zeros((2, 1024), np.int32)
+    seg[1, 512:] = 1
+    got = LanguageModelingTask.batch_counters({"segment_ids": seg})
+    visible = (seg[:, :, None] == seg[:, None, :]) & np.tril(
+        np.ones((1024, 1024), bool))
+    faces = visible.reshape(2, 2, 512, 2, 512).any(axis=(2, 4))
+    assert faces.sum() == 5
+    assert got == {"tokens": 2048, "documents": 3, "pairs": int(visible.sum()),
+                   "attn_blocks": 5, "attn_blocks_causal": 6}
 
 
 def test_cli_trains_three_steps_at_the_test_size(tmp_path, capsys):

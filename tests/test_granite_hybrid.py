@@ -453,9 +453,58 @@ def test_prefetcher_counts_tokens_and_documents(mesh1):
         seg = batch["segment_ids"]
         pairs = int(((seg[:, :, None] == seg[:, None, :])
                      & np.tril(np.ones((LENGTH, LENGTH), bool))).sum())
+        # rows of LENGTH are one face of the attention kernels' default block
         assert stream.stats()["counters"] == {"tokens": 3 * 2 * LENGTH,
                                               "documents": 3 * docs,
-                                              "pairs": 3 * pairs}
+                                              "pairs": 3 * pairs,
+                                              "attn_blocks": 3 * 2,
+                                              "attn_blocks_causal": 3 * 2}
+
+
+def _blocks_by_brute_force(seg, face=512):
+    """Faces of ``face`` x ``face`` at or below the diagonal that hold a
+    visible (query, key) pair, and all faces at or below it."""
+    at = np.arange(seg.shape[1])
+    n = seg.shape[1] // face
+    visited = 0
+    for row in seg:
+        for qi in range(n):
+            rows = slice(qi * face, (qi + 1) * face)
+            for ki in range(qi + 1):
+                cols = slice(ki * face, (ki + 1) * face)
+                visited += bool(((row[rows, None] == row[None, cols])
+                                 & (at[rows, None] >= at[None, cols])).any())
+    return visited, len(seg) * n * (n + 1) // 2
+
+
+@pytest.mark.parametrize("rows, want", [
+    ("one_document", (136, 136)), ("every_token_its_own", (16, 136)),
+    ("packed", None)])
+def test_batch_counters_count_the_attention_kernels_blocks(rows, want):
+    """Rows of 8,192 at the kernels' 512 x 512 faces: every causal face for
+    one document, the diagonal's for documents of one token, and for packed
+    documents what a brute-force look at every face gives."""
+    from deep_vision_tpu.tasks.language_modeling import LanguageModelingTask
+
+    if rows == "packed":
+        rng = np.random.default_rng(11)
+        seg = np.cumsum(rng.random((2, 8192)) < 1 / 600, axis=1).astype(np.int32)
+        want = _blocks_by_brute_force(seg)
+        assert 2 * 16 < want[0] < want[1] == 2 * 136
+    else:
+        seg = (np.zeros((1, 8192), np.int32) if rows == "one_document"
+               else np.arange(8192, dtype=np.int32)[None])
+    got = LanguageModelingTask.batch_counters({"segment_ids": seg})
+    assert (got["attn_blocks"], got["attn_blocks_causal"]) == want
+    assert got["tokens"] == seg.size
+
+
+def test_batch_counters_leave_the_blocks_out_where_the_block_does_not_divide():
+    from deep_vision_tpu.tasks.language_modeling import LanguageModelingTask
+
+    got = LanguageModelingTask.batch_counters(
+        {"segment_ids": np.zeros((1, 768), np.int32)})
+    assert got == {"tokens": 768, "documents": 1, "pairs": 768 * 769 // 2}
 
 
 def test_profiled_epoch_puts_the_counters_in_the_spans_header(tmp_path, mesh1):
