@@ -83,43 +83,6 @@ REHEARSE = Sizes(train_flags=("--image-size", "32", "--batch-size", "8"),
                  int8_bucket=2, yolo_size=64, yolo_batches=(2, 12))
 
 
-class CompileLog:
-    """Backend-compile seconds by jitted-function name and persistent
-    cache hits/misses, from JAX's own monitoring events."""
-
-    def __init__(self):
-        import jax.monitoring as mon
-
-        self.compiles: list[tuple[str, float]] = []
-        self.hits = self.misses = 0
-        mon.register_event_duration_secs_listener(self._duration)
-        mon.register_event_listener(self._event)
-
-    def _duration(self, event, secs, **kw):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compiles.append((str(kw.get("fun_name")), float(secs)))
-
-    def _event(self, event, **kw):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def mark(self) -> tuple[int, int, int]:
-        return len(self.compiles), self.hits, self.misses
-
-    def since(self, mark, name: str | None = None) -> dict:
-        n, hits, misses = mark
-        secs = [s for f, s in self.compiles[n:]
-                if name is None or f == f"jit({name})"]
-        out = {"programs": len(secs), "total_s": round(sum(secs), 2),
-               "cache_hits": self.hits - hits,
-               "cache_misses": self.misses - misses}
-        if name is not None:  # one by one only for a named program
-            out["seconds"] = [round(s, 2) for s in secs]
-        return out
-
-
 def versions() -> dict:
     from importlib.metadata import PackageNotFoundError, version
 
@@ -275,7 +238,7 @@ def observed_trainer(seen: dict):
 
 
 def phase_train(sz: Sizes, recs: str, workdir: str,
-                log: CompileLog) -> tuple[dict, str]:
+                log) -> tuple[dict, str]:
     """Returns (what the report keeps, the trained params' digest)."""
     import jax
     import numpy as np
@@ -394,7 +357,7 @@ def http_json(url: str, body: bytes | None = None):
 def serve_once(workdir: str, sz: Sizes, infer_dtype: str,
                buckets: str | None, images, reference, tol: float,
                trained_step: int, trained_digest: str,
-               log: CompileLog) -> dict:
+               log) -> dict:
     """Boot ``build_server``, answer ``images`` over HTTP, check every
     contract the serving path makes, shut down."""
     import jax
@@ -519,7 +482,7 @@ def serve_once(workdir: str, sz: Sizes, infer_dtype: str,
 
 
 def phase_serve(sz: Sizes, workdir: str, trained_step: int,
-                trained_digest: str, log: CompileLog) -> list[dict]:
+                trained_digest: str, log) -> list[dict]:
     import jax
     import numpy as np
 
@@ -588,6 +551,11 @@ def main(argv=None) -> int:
     from deep_vision_tpu.core.compile_cache import enable_compile_cache
 
     cache_dir = enable_compile_cache()
+    # the program's own record of its compiles (obs/launch.py), which
+    # enable_compile_cache has started
+    from deep_vision_tpu.obs import launch
+
+    log = launch.start().listen()
     dev = jax.devices()[0]
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices())}
@@ -603,7 +571,6 @@ def main(argv=None) -> int:
     if os.path.isfile(report_path):
         with open(report_path) as f:
             previous = json.load(f)
-    log = CompileLog()
     t_start = time.monotonic()
     tmp = tempfile.mkdtemp(prefix="chip_smoke_")
     try:

@@ -14,4 +14,10 @@ model zoo (classification / detection / pose / GANs), built TPU-first:
   replacing torch DataLoader / tf.data.
 """
 
+import time
+
+#: ``time.monotonic`` as the package was first imported: where the launch
+#: record (obs/launch.py) ends ``outside`` and begins ``import``
+IMPORTED_AT = time.monotonic()
+
 __version__ = "0.1.0"

@@ -13,6 +13,11 @@ from __future__ import annotations
 
 import argparse
 
+#: --profile: past step 20, so that a logged step's ``fetch`` (a log every
+#: 10 steps) falls inside the traced span and ``benchmark/spans.py`` can
+#: number the executions by it instead of refusing the spans
+PROFILE_STEPS = (10, 25)
+
 
 def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(description="deep_vision_tpu trainer")
@@ -87,7 +92,11 @@ def build_parser() -> argparse.ArgumentParser:
                         "expert_count=16; a prediction module's "
                         "mtp_loss_weight=0.3); repeatable")
     p.add_argument("--profile", action="store_true",
-                   help="jax.profiler trace of steps 10-20 → workdir/profile")
+                   help="jax.profiler trace of steps 10-25 → workdir/profile, "
+                        "with the loop's spans (spans.jsonl) and the launch "
+                        "record (launch.jsonl) beside it; the span holds a "
+                        "logged step (one every 10), whose fetch numbers the "
+                        "device's executions for a reader")
     p.add_argument("--list", action="store_true", help="list configs and exit")
     return p
 
@@ -241,7 +250,7 @@ def main(argv=None):
     trainer = Trainer(cfg, cfg.model(), task, mesh=mesh, workdir=args.workdir,
                       preprocess_fn=preprocess_fn, upload=args.upload)
     if args.profile:
-        trainer.profile_steps = (10, 20)
+        trainer.profile_steps = PROFILE_STEPS
     state = None
     try:
         if args.pretrained:
@@ -533,7 +542,7 @@ def _main_language(args, cfg, mesh):
     trainer = Trainer(cfg, cfg.model(), task, mesh=mesh,
                       workdir=args.workdir, upload=args.upload)
     if args.profile:
-        trainer.profile_steps = (10, 20)
+        trainer.profile_steps = PROFILE_STEPS
     state = trainer.fit(train_loader, val_loader, resume=args.resume)
     final = trainer.evaluate(state, val_loader)
     print("final:", " ".join(f"{k}={v:.4f}" for k, v in final.items()))

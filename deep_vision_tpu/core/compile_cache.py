@@ -25,14 +25,21 @@ def enable_compile_cache() -> str | None:
     """Turn the on-disk program cache on (idempotent); returns the
     directory in use, or None when ``DEEP_VISION_TPU_NO_COMPILE_CACHE=1``
     opted out (measuring true cold compiles)."""
-    if os.environ.get("DEEP_VISION_TPU_NO_COMPILE_CACHE"):
-        return None
-    import jax
+    from deep_vision_tpu.obs import launch
 
-    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if not path:
-        path = DEFAULT_DIR
-        jax.config.update("jax_compilation_cache_dir", path)
-    # only persist programs worth the disk round-trip
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
-    return path
+    # every entry point calls this first: the launch record's ``outside``
+    # ends here, and the first call is its ``cache`` stage
+    record = launch.start()
+    with record.once("cache"):
+        import jax
+
+        record.listen()
+        if os.environ.get("DEEP_VISION_TPU_NO_COMPILE_CACHE"):
+            return None
+        path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        if not path:
+            path = DEFAULT_DIR
+            jax.config.update("jax_compilation_cache_dir", path)
+        # only persist programs worth the disk round-trip
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
+        return path

@@ -90,9 +90,9 @@ class MetricLogger:
     def log_input_block(self, step: int, stats: dict):
         """The trainer's per-epoch input-goodput block (docs/OBSERVABILITY.md
         "Trainer input-goodput series"): stall fraction, H2D traffic, and
-        per-stage producer timers from ``DevicePrefetcher`` epoch stats.
-        Exporters prefix these with ``dvt_train_`` (e.g.
-        ``dvt_train_input_stall_frac``)."""
+        per-stage producer timers from ``DevicePrefetcher`` epoch stats, as
+        series of ``metrics.jsonl`` under the names ``input_*`` (no exporter
+        in the tree serves them: ``/metrics`` is the serving plane's)."""
         n = max(1, int(stats.get("batches", 0)))
         prod = stats.get("producer_ms", {})
         self.log_dict(step, {

@@ -40,6 +40,7 @@ from deep_vision_tpu.core.optim import (
     set_learning_rate,
 )
 from deep_vision_tpu.core.state import DivergenceGuard, TrainState
+from deep_vision_tpu.obs import launch
 from deep_vision_tpu.parallel import make_mesh, replicate, shard_batch
 
 
@@ -69,6 +70,7 @@ class Trainer:
     pose).  Adversarial multi-model training lives in
     :class:`deep_vision_tpu.core.adversarial.AdversarialTrainer`."""
 
+    @launch.staged("build")
     def __init__(self, config: TrainConfig, model, task, mesh=None,
                  workdir: str | None = None, preprocess_fn=None,
                  upload: str | None = None):
@@ -139,6 +141,7 @@ class Trainer:
 
     # ------------------------------------------------------------------ init
 
+    @launch.staged("init")
     def init_state(self, sample_batch: dict) -> TrainState:
         rng = jax.random.PRNGKey(self.config.seed)
         init_rng, state_rng = jax.random.split(rng)
@@ -220,6 +223,10 @@ class Trainer:
         ``-c`` flag, ResNet/pytorch/train.py:381-388)."""
         if self.checkpointer.latest_step() is None:
             return state
+        return self._restore(state)
+
+    @launch.staged("restore")
+    def _restore(self, state: TrainState) -> TrainState:
         # reconcile EMA with what the checkpoint actually stores: enabling
         # --ema-decay on a checkpoint trained without it must seed the EMA
         # from the RESTORED params (not the fresh random init the template
@@ -507,8 +514,12 @@ class Trainer:
         line per interval of the prefetcher's producer and of this loop,
         in ``time.time_ns`` terms.  A trace's ``Task Environment`` plane
         gives its start on that clock, so a reader can lay these on the
-        device's timeline (docs/OBSERVABILITY.md has the schema)."""
+        device's timeline (docs/OBSERVABILITY.md has the schema).  The
+        process's launch record goes beside it under the same clock rule,
+        as ``workdir/launch.jsonl`` (obs/launch.py)."""
         t0 = time.perf_counter()
+        launch.start().write(os.path.join(self.workdir, "launch.jsonl"), clock)
+        t1 = time.perf_counter()
         stats = stream.stats()
         mono_ns, wall_ns = clock[0]
         path = os.path.join(self.workdir, "spans.jsonl")
@@ -523,10 +534,16 @@ class Trainer:
                     "t0_ns": round(a * 1e9) - mono_ns + wall_ns,
                     "t1_ns": round(b * 1e9) - mono_ns + wall_ns}) + "\n")
         print(f"[profile] spans written to {path} in "
-              f"{(time.perf_counter() - t0) * 1e3:.1f} ms", flush=True)
+              f"{(time.perf_counter() - t0) * 1e3:.1f} ms, launch.jsonl "
+              f"{(t1 - t0) * 1e3:.1f} ms of it", flush=True)
 
     def train_epoch(self, state: TrainState, train_data: Iterable,
                     epoch: int) -> TrainState:
+        with launch.start().listen().epoch() as record:
+            return self._train_epoch(state, train_data, epoch, record)
+
+    def _train_epoch(self, state: TrainState, train_data: Iterable,
+                     epoch: int, record: launch.LaunchLog) -> TrainState:
         cfg = self.config
         meter = ThroughputMeter()
         pending = None  # async metric fetch: log step N-1 while N runs
@@ -539,6 +556,7 @@ class Trainer:
         # no-op on them) that the jitted step consumes via donation
         stream = self._get_prefetcher().iterate(
             train_data, counters=getattr(self.task, "batch_counters", None))
+        record.watch(stream)
         for i, batch in enumerate(stream):
             if profiling is not None:
                 if i == profiling[0]:
@@ -561,10 +579,13 @@ class Trainer:
             # (the host's wait for the device), the guard and the logger;
             # the rest of the iteration closes as "step" at the next dequeue
             stream.mark("dispatch")
+            if i == 0:
+                record.first("first_dispatch")
             meter.update(bs)
             if pending is not None and (i % cfg.log_every_steps == 0):
                 m = {k: float(v) for k, v in jax.device_get(pending).items()}
                 stream.mark("fetch")
+                record.first("first_fetch")
                 self.guard.check(m)
                 self.logger.log_dict(int(state.step) - 1,
                                      {f"train_{k}": v for k, v in m.items()})
@@ -585,14 +606,29 @@ class Trainer:
                   f"{self.workdir}/profile", flush=True)
         if pending is not None:
             m = {k: float(v) for k, v in jax.device_get(pending).items()}
+            record.first("first_fetch")
             self.guard.check(m)
             self.logger.log_dict(int(state.step),
                                  {f"train_{k}": v for k, v in m.items()})
         if clock:
             self._write_spans(stream, clock)
         self.logger.log("images_per_sec", int(state.step), meter.images_per_sec)
+        self._log_launch(int(state.step), epoch, record)
         self._log_input_stats(int(state.step), stream.stats(), epoch)
         return state
+
+    def _log_launch(self, step: int, epoch: int, record: launch.LaunchLog):
+        """What an operator gets without a trace: the process's first epoch
+        prints where the time before its first step went, and any epoch in
+        which a program compiled after its first dispatch names it."""
+        if record.epochs == 1:
+            print(record.summary(), flush=True)
+        late = record.late
+        self.logger.log("train_compiles", step, len(late))
+        if late:
+            print(f"[compile] epoch {epoch} " + "; ".join(
+                f"batch {batch}: {fun} {secs:.1f}s {cache or 'uncached'}"
+                for fun, secs, cache, batch in late), flush=True)
 
     def fit(self, train_data, val_data=None, state: TrainState | None = None,
             resume: bool = False, monitor: str | None = None) -> TrainState:

@@ -1,6 +1,6 @@
 """Observability: spans, structured logging, MFU.
 
-Three small, dependency-free pieces.  The serving stack
+Four small, dependency-free pieces.  The serving stack
 (``deep_vision_tpu/serve``) threads them through every layer — batcher,
 drainer, router, watchdog, prober — without perturbing the clean hot
 path (the same discipline as ``faults.py``: one ``enabled``/``is None``
@@ -16,6 +16,13 @@ itself) and writes their intervals beside a profiler trace
               Request ids arrive at the edge (``X-DVT-Request-Id``,
               generated at gateway or backend, propagated via header);
               ``?debug=1`` echoes a request's own breakdown.
+    launch.py the process's launch record: one more ``Span`` from the
+              process's start through the stages the program marks (the
+              package's import, ``enable_compile_cache``, the trainer's
+              build and init, every ``train_epoch`` call) and every
+              compile as an interval from JAX's own monitoring; an epoch's
+              ``[launch]`` / ``[compile]`` lines and, beside a profiled
+              epoch's ``spans.jsonl``, ``launch.jsonl``.
     log.py    ``logging``-based structured one-line-JSON events under
               the ``dvt.serve.*`` namespaces (watchdog restarts,
               breaker transitions, quarantines, evacuations each emit

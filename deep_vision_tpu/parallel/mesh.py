@@ -22,6 +22,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from deep_vision_tpu.obs import launch
+
 DATA_AXIS = "data"
 MODEL_AXIS = "model"
 SPATIAL_AXIS = "spatial"  # image-row (context) axis — see parallel/spatial.py
@@ -37,7 +39,10 @@ def make_mesh(
     A size of -1 means "all remaining devices".
     """
     if devices is None:
-        devices = jax.devices()
+        # the package's first look at the devices starts the runtime where
+        # nobody has: the launch record's ``backend`` stage
+        with launch.start().once("backend"):
+            devices = jax.devices()
     if axis_sizes is None:
         axis_sizes = {DATA_AXIS: len(devices)}
     names = tuple(axis_sizes.keys())

@@ -679,3 +679,212 @@ def test_overload_logging_is_edge_triggered(caplog):
               for r in caplog.records]
     assert events == ["overload_shed_start", "overload_cleared"]
     assert adm.stats()["shed_queue_full"] == 5
+
+
+# -- the launch record (obs/launch.py) ---------------------------------------
+
+TRACE = "/jax/core/compile/jaxpr_trace_duration"
+LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+BACKEND = "/jax/core/compile/backend_compile_duration"
+
+
+def compiled(log, fun: str, t0: float, secs: float, cache: str | None = None):
+    """One program through the log's listeners, as JAX fires them: trace,
+    lowering, then (the cache's word and its read's duration, then) the
+    backend compile."""
+    log._time_span(TRACE, t0, t0 + 0.1, fun_name=fun)
+    log._time_span(LOWER, t0 + 0.1, t0 + 0.2, fun_name=f"jit({fun})")
+    if cache == "hit":
+        log._event("/jax/compilation_cache/cache_hits")
+        log._duration("/jax/compilation_cache/cache_retrieval_time_sec", secs)
+    elif cache == "miss":
+        log._event("/jax/compilation_cache/cache_misses")
+    log._time_span(BACKEND, t0 + 0.2, t0 + 0.2 + secs, fun_name=f"jit({fun})")
+
+
+def test_launch_log_mark_and_since_give_what_chip_smoke_reads():
+    from deep_vision_tpu.obs.launch import LaunchLog
+
+    log = LaunchLog()
+    compiled(log, "apply", 10.0, 3.0, "miss")
+    mark = log.mark()
+    compiled(log, "train_step", 20.0, 2.5, "miss")
+    compiled(log, "eval_step", 30.0, 0.25, "hit")
+    compiled(log, "train_step", 40.0, 0.75, "hit")
+    compiled(log, "convert_element_type", 50.0, 0.01)
+    assert log.since(mark, "train_step") == {
+        "programs": 2, "total_s": 3.25, "cache_hits": 2, "cache_misses": 1,
+        "seconds": [2.5, 0.75]}
+    assert log.since(mark, "apply")["programs"] == 0
+    assert log.since(mark) == {
+        "programs": 4, "total_s": 3.51, "cache_hits": 2, "cache_misses": 1,
+        "half_second_or_more": {"jit(train_step)": [2.5, 0.75]}}
+    assert log.since((0, 0, 0))["programs"] == 5
+    # what JAX said of the cache rides on the program's compile, and the
+    # cache's read is an interval named after the program it served
+    by_kind = {}
+    for c in log.compiles:
+        by_kind.setdefault(c[0], []).append(c)
+    assert [c[4] for c in by_kind["backend_compile"]] == [
+        "miss", "miss", "hit", "hit", None]
+    assert [(c[1], c[4]) for c in by_kind["cache_retrieval"]] == [
+        ("jit(eval_step)", "hit"), ("jit(train_step)", "hit")]
+    assert all(c[5] == "caller" and c[6] is None for c in log.compiles)
+
+
+def test_launch_log_keeps_the_outermost_trace():
+    from deep_vision_tpu.obs.launch import LaunchLog
+
+    log = LaunchLog()
+
+    def enter(event, t0):  # JAX's record_scalar as an interval begins
+        log._enter(event, t0, fun_name="f")
+
+    enter(TRACE, 10.0)                      # train_step {
+    enter(TRACE, 10.1)                      #   inner {
+    enter(TRACE, 10.2)                      #     sin
+    log._time_span(TRACE, 10.2, 10.3, fun_name="sin")
+    log._time_span(TRACE, 10.1, 10.5, fun_name="inner")
+    enter(TRACE, 10.6)
+    log._time_span(TRACE, 10.6, 10.7, fun_name="matmul")
+    log._time_span(TRACE, 10.0, 11.0, fun_name="train_step")
+    # a lowering rule's own traces fall inside the lowering
+    enter(LOWER, 11.1)
+    enter(TRACE, 11.2)
+    log._time_span(TRACE, 11.2, 11.3, fun_name="add")
+    log._time_span(LOWER, 11.1, 11.5, fun_name="jit(train_step)")
+    enter(TRACE, 12.0)
+    log._time_span(TRACE, 12.0, 12.1, fun_name="later")
+    assert [(c[0], c[1]) for c in log.compiles] == [
+        ("trace", "train_step"), ("lower", "jit(train_step)"),
+        ("trace", "later")]
+    # an interval whose beginning was never announced (a listener that
+    # registered inside it) is kept, and the count does not go below zero
+    log._time_span(TRACE, 13.0, 13.1, fun_name="unannounced")
+    assert log.compiles[-1][1] == "unannounced" and log._pending.depth == 0
+    log._enter("/jax/some/other/event", 1.0)
+    log._time_span("/jax/some/other/event", 1.0, 2.0)
+    assert len(log.compiles) == 4
+
+
+def test_launch_log_bound_counts_what_it_drops():
+    from deep_vision_tpu.obs import launch
+
+    log = launch.LaunchLog()
+    for i in range(launch.MAX_INTERVALS + 7):
+        log._time_span(BACKEND, float(i), i + 0.5, fun_name="jit(f)")
+    assert len(log.compiles) == launch.MAX_INTERVALS and log.dropped == 7
+    # a full record still names a program that compiles inside the loop
+    with log.epoch():
+        log.first("first_dispatch")
+        log._time_span(BACKEND, 9000.0, 9012.3, fun_name="jit(train_step)")
+        assert log.late == [("jit(train_step)", pytest.approx(12.3), None, None)]
+    assert log.dropped == 8
+    with log.epoch():
+        assert log.late == []
+
+
+def test_launch_log_stages_tile_from_the_process_start():
+    from deep_vision_tpu.obs.launch import LaunchLog
+
+    before = time.monotonic()
+    log = LaunchLog()
+    with log.stage("build"):
+        with log.once("backend"):  # a stage inside a stage splits it
+            pass
+    with log.once("backend"):  # the second time it is no stage
+        pass
+    with log.stage("init"):
+        pass
+    for call in range(2):
+        with log.epoch():
+            log.first("first_dispatch")
+            log.first("first_dispatch")  # idempotent within a call
+            log.first("first_fetch")
+            if call == 1:
+                stages = log.stages()  # read while the epoch is open
+    assert [s[0] for s in stages] == [
+        "outside", "import", "caller", "build", "backend", "build", "caller",
+        "init", "caller", "first_dispatch", "first_fetch", "epoch", "caller",
+        "first_dispatch", "first_fetch", "epoch"]
+    assert all(a[3] == b[2] for a, b in zip(stages, stages[1:]))
+    assert all(t0 <= t1 for _, _, t0, t1 in stages)
+    # the origin is the OS's start of this process: before this test, and
+    # after nothing the interpreter did
+    assert stages[0][2] < before and stages[0][2] == log.span.marks[0][1]
+    assert stages[-1][3] >= stages[-1][2]
+    assert [n for name, n, _, _ in stages if name == "epoch"] == [0, 1]
+    assert log.epochs == 2 and not log._open
+
+
+def test_launch_log_without_proc_starts_at_its_creation(monkeypatch):
+    from deep_vision_tpu.obs import launch
+
+    monkeypatch.setattr(launch, "process_start_monotonic", lambda: None)
+    before = time.monotonic()
+    log = launch.LaunchLog()
+    with log.stage("build"):
+        pass
+    assert [s[0] for s in log.stages()] == ["caller", "build", "caller"]
+    assert log.span.marks[0][1] >= before
+
+
+def test_launch_summary_line_names_what_compiled_under_the_first_step():
+    from deep_vision_tpu.obs.launch import LaunchLog
+
+    log = LaunchLog()
+    with log.stage("build"):
+        pass
+    compiled(log, "init", 5.0, 1.0, "hit")
+    with log.epoch():
+        compiled(log, "train_step", 100.0, 38.0, "miss")
+        compiled(log, "convert_element_type", 139.0, 0.01)
+        log.first("first_dispatch")
+        log.first("first_fetch")
+        compiled(log, "late", 200.0, 5.0, "miss")
+    line = log.summary()
+    assert re.fullmatch(
+        r"\[launch\] outside \d+\.\ds import \d+\.\ds build 0\.0s "
+        r"caller 0\.0s first step 0\.0s \(compile 38\.4s: jit\(train_step\) "
+        r"miss\) first fetch 0\.0s", line), line
+
+
+def test_launch_listener_registers_once_however_many_trainers(tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    from deep_vision_tpu.core.compile_cache import enable_compile_cache
+    from deep_vision_tpu.core.config import get_config
+    from deep_vision_tpu.core.trainer import Trainer
+    from deep_vision_tpu.obs import launch
+    from deep_vision_tpu.parallel import make_mesh
+    from deep_vision_tpu.tasks.classification import ClassificationTask
+
+    log = launch.start()
+    assert launch.start() is log
+    cfg = get_config("lenet5")
+    mesh = make_mesh(devices=jax.devices()[:1])
+    for i in range(3):
+        Trainer(cfg, cfg.model(), ClassificationTask(num_classes=10),
+                mesh=mesh, workdir=str(tmp_path / str(i)))
+    prev_dir = jax.config.jax_compilation_cache_dir
+    prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    try:
+        enable_compile_cache()
+        enable_compile_cache()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev_dir)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          prev_min)
+    stages = [s[0] for s in log.stages()]
+    assert stages.count("build") >= 3 and stages.count("cache") <= 1
+    mark = log.mark()
+    dropped = log.dropped
+
+    def never_jitted_before(x):
+        return jnp.cos(x) * 3.25 + x
+
+    jax.jit(never_jitted_before)(jnp.ones((3, 5))).block_until_ready()
+    found = log.since(mark, "never_jitted_before")
+    # one listener: the one program is one compile, not one a trainer
+    assert found["programs"] + (log.dropped - dropped > 0) == 1
