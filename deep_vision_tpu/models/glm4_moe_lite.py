@@ -58,7 +58,7 @@ op and nothing else), ``dense_block``, ``routed_ffn`` (inside it
 is another model's (``mamba``, ``attention``, ``mlp``, ``moe``, ``conv_op``,
 ``gqa_op``, ``dense_ffn``): the benchmark's readers tell models apart by
 them.  The model returns ``(logits, logits2, counters)``: ``logits2`` is
-None without a module; the counters are the five of ``models/lfm2_moe.py``
+None without a module; the counters are the six of ``models/lfm2_moe.py``
 over every routed layer, the module's among them.
 """
 

@@ -51,18 +51,19 @@ rule), so that none of them runs twice and the backward kernel builds a
 block's probabilities from the log-sum-exp.  The routed block keeps its routing (``ops/moe.py``'s
 ``moe_routing``: the chosen experts and the sort, integers of 0.5 MB a
 layer), so the top-k and the sort run once; its rows are not kept: the
-buffers are sized for the worst case (``L x k`` rows, 0.33 GB a layer for
-the gathered rows and the two products' outputs at 8,192 tokens) while the
-rows computed are a quarter of that with 16 of 64 experts held, so the
-gather and the grouped products are computed again (PERF.md s6, PR 33).
+block's backward pass gathers them and runs the grouped products again, in
+buffers of the capacity the layer chose on the device for the rows it holds
+(``ops/moe.py``'s ladder: 3/8 of the worst case's ``L x k`` rows while 16
+of 64 experts hold their even share or half as much again, PERF.md s6, PR 38).
 
 Scopes ``embed``, ``conv_op``, ``gqa_op``, ``dense_ffn``, ``moe`` (inside it
 ``moe_route`` and ``moe_experts``, ``ops/moe.py``) and ``lm_head`` name the
 parts in a device trace; a layer's norm and residual go by its operator's
-or its block's scope.  The model returns the logits and five routing
+or its block's scope.  The model returns the logits and six routing
 counters (``moe_assignments`` summed over the expert layers,
 ``moe_max_load`` the largest, ``moe_unrouted_tokens`` their mean,
-``moe_dropped`` summed, ``moe_bias_lift`` the mean over layers and tokens of
+``moe_dropped`` summed, ``moe_buffer_rows`` the rows of the buffers the
+layers chose, summed, ``moe_bias_lift`` the mean over layers and tokens of
 ``sum_k w_k b_{e_k}``: the selection bias of a token's experts averaged
 with the weights it gives them), which ``LanguageModelingTask`` hands on
 as the step's metrics.
@@ -149,7 +150,8 @@ class Lfm2MoeConfig:
 # what a rematerialised layer keeps between the passes beside its input
 KEPT = ("conv_in_proj", "q_proj", "k_proj", "v_proj", attention.OUT,
         attention.LSE, "operator_out_proj", "ffn_w1", "ffn_w3", moe.ROUTING)
-COUNTERS = ("assignments", "max_load", "unrouted_tokens", "dropped", "bias_lift")
+COUNTERS = ("assignments", "max_load", "unrouted_tokens", "dropped",
+            "buffer_rows", "bias_lift")
 
 
 def model_counters(per_layer: list) -> dict:
@@ -160,6 +162,7 @@ def model_counters(per_layer: list) -> dict:
             "moe_max_load": stacked["max_load"].max(),
             "moe_unrouted_tokens": stacked["unrouted_tokens"].mean(),
             "moe_dropped": stacked["dropped"].sum(),
+            "moe_buffer_rows": stacked["buffer_rows"].sum(),
             "moe_bias_lift": stacked["bias_lift"].mean()}
 
 

@@ -6,7 +6,8 @@ position and every position whose successor starts another document to 0).
 A model may hand back ``(logits, counters)`` in place of the logits alone:
 ``counters`` is a dict of scalars computed on the device beside the forward
 pass (a routed model's ``moe_assignments``, ``moe_max_load``,
-``moe_unrouted_tokens``, ``moe_dropped``, ``moe_bias_lift``), which the loss passes on as the
+``moe_unrouted_tokens``, ``moe_dropped``, ``moe_buffer_rows``,
+``moe_bias_lift``), which the loss passes on as the
 step's metrics, so that they reach ``metrics.jsonl`` the way
 ``token_accuracy`` does and without a host read of their own.
 

@@ -78,7 +78,7 @@ def kept_between_passes(capsys):
                     or "output of cos" in where:
                 continue
             dtype, shape = aval[:-1].split("[")
-            found.append((dtype, tuple(int(n) for n in shape.split(","))))
+            found.append((dtype, tuple(int(n) for n in shape.split(",") if n)))
         return found
 
     return kept

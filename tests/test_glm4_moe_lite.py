@@ -354,6 +354,13 @@ def test_scopes_name_the_parts_and_none_is_another_models():
     assert {"embed", "mla_op", "mla_core", "dense_block", "routed_ffn",
             "shared_expert", "moe_route", "moe_experts", "lm_head", "mtp"} <= parts
     assert not parts & OTHER_MODELS
+    # ops/moe.py's switch: in every branch, forward, recomputed and backward,
+    # the block's scopes are whole components under ``routed_ffn``
+    branch = [name.split("/") for name in names if "feed_forward/cond/branch_" in name]
+    assert branch and all("routed_ffn" in c and ("moe_route" in c or "moe_experts" in c)
+                          for c in branch)
+    assert {"buffer_256", "jvp(buffer_256)", "transpose(jvp(buffer_256))"} <= parts
+    assert not {p for p in parts if re.search(r"\((moe_route|moe_experts)\)", p)}
     inside = {part for name in names if "mtp" in name.split("/")
               for part in name.split("/")}
     assert {"mla_op", "mla_core", "routed_ffn", "shared_expert", "lm_head",
@@ -524,6 +531,10 @@ def test_three_steps_through_train_epoch_log_the_counters(tmp_path, mesh1):
     assert len(series["train_loss"]) == 3 and np.isfinite(series["train_loss"]).all()
     assert series["train_moe_dropped"] == [0.0] * 3
     assert all(0 < v <= 2 * 3 * LENGTH * 4 for v in series["train_moe_assignments"])
+    # three routed blocks a step, each in buffers of 256 or of all 512 rows
+    assert all(held <= rows and rows in {768, 1024, 1280, 1536}
+               for rows, held in zip(series["train_moe_buffer_rows"],
+                                     series["train_moe_assignments"]))
     _, w2 = second_targets(jnp.asarray(batch["targets"]),
                            jnp.asarray(batch["loss_weights"]))
     assert series["train_mtp_targets"] == [float(w2.sum())] * 3

@@ -196,12 +196,35 @@ def test_gradient_reaches_the_router_through_the_weights_and_never_the_bias():
 # --------------------------------------------------------- routed_experts
 
 ROUTINGS = ["even", "skewed", "one_takes_all", "one_gets_none", "none_held"]
+# 512 tokens x 4: buffers of 768 or of all 2,048 rows; the held rows of each
+# case land inside a rung, on its edge, one past it, and fill the top
+RUNG_TOKENS, RUNGS = 512, (768, 2048)
+HELD_ROWS = [100, 768, 769, 1537, 2048]
+CASES = ROUTINGS + [f"held_{rows}" for rows in HELD_ROWS]
 
 
-def routing(kind, tokens=40, experts=16, k=4, first=4, count=4, seed=0):
+def tokens_of(kind):
+    return RUNG_TOKENS if kind.startswith("held_") else 40
+
+
+def rung_of(rows):
+    return next(c for c in RUNGS if c >= rows)
+
+
+def routing(kind, experts=16, k=4, first=4, count=4, seed=0):
     """(T, k) experts a token, distinct within a token, and float32 weights."""
     rng = np.random.default_rng(seed)
-    if kind == "even":
+    tokens = tokens_of(kind)
+    if kind.startswith("held_"):     # exactly that many assignments are held
+        rows = int(kind[5:])
+        absent = np.array([e for e in range(experts)
+                           if not first <= e < first + count])
+        idx = np.stack([np.concatenate([
+            rng.permutation(count)[:held] + first,
+            rng.choice(absent, k - held, replace=False)])
+            for held in rows // tokens + (np.arange(tokens) < rows % tokens)])
+        idx = rng.permuted(idx, axis=1)
+    elif kind == "even":
         idx = np.stack([(np.arange(k) * (experts // k) + t) % experts
                         for t in range(tokens)])
     elif kind == "skewed":
@@ -225,6 +248,19 @@ def routing(kind, tokens=40, experts=16, k=4, first=4, count=4, seed=0):
     return jnp.asarray(idx, jnp.int32), jnp.asarray(w / w.sum(-1, keepdims=True))
 
 
+def only_the_top_rung(monkeypatch):
+    """The block as it was before the ladder: every buffer T x k rows."""
+    monkeypatch.setattr(moe, "LADDER", ((1, 1),))
+
+
+def test_the_ladder_is_fractions_of_the_worst_case_in_whole_row_tiles():
+    assert moe._capacities(8192 * 4) == (12288, 32768)
+    assert moe._capacities(RUNG_TOKENS * 4) == RUNGS
+    assert moe._capacities(2 * LENGTH * 4) == (256, 512)      # the models' rows
+    assert moe._capacities(40 * 4) == (160,)        # one rung: no switch at all
+    assert all(c % moe.TILE[0] == 0 for c in moe._capacities(8192 * 4))
+
+
 def expert_inputs(seed=1, tokens=40, hidden=32, width=24, count=4):
     keys = jax.random.split(jax.random.PRNGKey(seed), 4)
     return (jax.random.normal(keys[0], (tokens, hidden)),
@@ -243,11 +279,11 @@ def plain_experts(u, indices, weights, w1, w3, w2, first):
     return out
 
 
-@pytest.mark.parametrize("kind", ROUTINGS)
-def test_routed_experts_match_every_expert_on_every_token(kind):
+@pytest.mark.parametrize("kind", CASES)
+def test_routed_experts_match_every_expert_on_every_token(kind, monkeypatch):
     first, count = 4, 4
     indices, weights = routing(kind)
-    u, w1, w3, w2 = expert_inputs()
+    u, w1, w3, w2 = expert_inputs(tokens=tokens_of(kind))
     got, counters = moe.routed_experts(u, indices, weights, w1, w3, w2, first)
     want = plain_experts(u, indices, weights, w1, w3, w2, first)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
@@ -257,6 +293,18 @@ def test_routed_experts_match_every_expert_on_every_token(kind):
     assert counters["max_load"] == max(loads)
     assert counters["unrouted_tokens"] == (~held.any(-1)).sum()
     assert counters["dropped"] == 0         # dropless, whatever the imbalance
+    if kind.startswith("held_"):
+        rows = int(kind[5:])
+        assert counters["assignments"] == rows
+        assert counters["buffer_rows"] == rung_of(rows)
+        # the same block with every buffer at T x k rows, bit for bit
+        only_the_top_rung(monkeypatch)
+        top, theirs = moe.routed_experts(u, indices, weights, w1, w3, w2, first)
+        assert theirs.pop("buffer_rows") == 4 * RUNG_TOKENS
+        np.testing.assert_array_equal(got, top)
+        assert theirs == {k: v for k, v in counters.items() if k != "buffer_rows"}
+    else:
+        assert counters["buffer_rows"] == 160
     if kind == "one_takes_all":
         assert max(loads) == 40 and counters["assignments"] == 40 * 4
     if kind == "one_gets_none":
@@ -265,11 +313,11 @@ def test_routed_experts_match_every_expert_on_every_token(kind):
         assert not np.asarray(got).any() and counters["unrouted_tokens"] == 40
 
 
-@pytest.mark.parametrize("kind", ROUTINGS)
-def test_routed_experts_gradients_match_the_plain_form(kind):
+@pytest.mark.parametrize("kind", CASES)
+def test_routed_experts_gradients_match_the_plain_form(kind, monkeypatch):
     first = 4
     indices, weights = routing(kind, seed=2)
-    args = expert_inputs(seed=3)
+    args = expert_inputs(seed=3, tokens=tokens_of(kind))
     probe = jax.random.normal(jax.random.PRNGKey(8), args[0].shape)
 
     def loss(fn, u, weights, w1, w3, w2):
@@ -284,13 +332,20 @@ def test_routed_experts_gradients_match_the_plain_form(kind):
         np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5)
     if kind == "one_gets_none":
         assert not np.asarray(got[2][2]).any()    # no row, no gradient
+    if kind.startswith("held_"):     # a lower rung's gradients are the top's,
+        only_the_top_rung(monkeypatch)   # to float32's rounding of a row's sum
+        top = jax.grad(lambda *a: loss(moe.routed_experts, *a), range(5))(*operands)
+        for g, t in zip(got, top):
+            np.testing.assert_allclose(g, t, rtol=1e-5, atol=1e-6)
 
 
-def test_a_row_the_products_leave_out_is_counted_as_dropped(monkeypatch):
+@pytest.mark.parametrize("kind", ["skewed", "held_300", "held_1000"])
+def test_a_row_the_products_leave_out_is_counted_as_dropped(kind, monkeypatch):
     """``dropped`` is read off the grouped products' result: a program that
-    cut a load short (a capacity) would leave rows out, and they count."""
-    u, w1, w3, w2 = expert_inputs()
-    indices, weights = routing("skewed")
+    cut a load short (a capacity) would leave rows out, and they count, in
+    whichever rung's buffers."""
+    u, w1, w3, w2 = expert_inputs(tokens=tokens_of(kind))
+    indices, weights = routing(kind)
     product = moe._grouped_product
 
     def short(x, w, loads):       # the fullest expert's last three rows cut
@@ -304,6 +359,8 @@ def test_a_row_the_products_leave_out_is_counted_as_dropped(monkeypatch):
     _, cut = moe.routed_experts(u, indices, weights, w1, w3, w2, first=4)
     assert sound["dropped"] == 0 and cut["dropped"] == 3
     assert cut["assignments"] == sound["assignments"]
+    assert cut["buffer_rows"] == sound["buffer_rows"] == {
+        "skewed": 160, "held_300": 768, "held_1000": 2048}[kind]
 
 
 def test_routed_experts_in_bfloat16_are_the_float32_ones_within_rounding():
@@ -316,12 +373,14 @@ def test_routed_experts_in_bfloat16_are_the_float32_ones_within_rounding():
     assert relative(got.astype(jnp.float32), want) < 2e-2
 
 
-def test_no_scatter_of_rows_in_either_pass(jaxpr_equations):
-    """Rows move by gathers alone, forward and backward: what is scattered
-    outside the kernels is vectors (the inverse permutation, the kernels'
-    tables of tiles and groups), never rows."""
-    indices, weights = routing("skewed")
-    args = expert_inputs()
+@pytest.mark.parametrize("kind, rungs", [("skewed", 1), ("held_600", 2)])
+def test_no_scatter_of_rows_in_either_pass(jaxpr_equations, kind, rungs):
+    """Rows move by gathers alone, forward and backward, in every branch of
+    the switch: what is scattered outside the kernels is vectors (the
+    inverse permutation, the kernels' tables of tiles and groups), never
+    rows."""
+    indices, weights = routing(kind)
+    args = expert_inputs(tokens=tokens_of(kind))
 
     def loss(u, w1, w3, w2):
         return jnp.sum(moe.routed_experts(u, indices, weights, w1, w3, w2, 4)[0])
@@ -332,7 +391,38 @@ def test_no_scatter_of_rows_in_either_pass(jaxpr_equations):
     assert scatters and all(e.outvars[0].aval.ndim == 1 for e in scatters)
     grouped = [e for e in jaxpr_equations(jaxpr.jaxpr, closed=("pallas_call",))
                if e.primitive.name == "pallas_call"]
-    assert len(grouped) == 9        # forward, dx and dW of the three products
+    # a rung: forward; then in the backward branch its recomputation, dx, dW
+    assert len(grouped) == 12 * rungs
+
+
+def test_conditionals_hold_no_buffer_of_a_rung_and_none_lies_between_the_passes(
+        jaxpr_equations, kept_between_passes):
+    """One forward and one backward conditional a block.  What goes into one
+    and comes out has the block's own shapes ((T, D), (T, k), the experts'
+    leaves, vectors of T x k integers), never a rung's row buffer: the
+    two-dimensional operands of a conditional, and its results, have T x k
+    rows in all at most.  Between the passes lies nothing shaped like a
+    buffer, of the rung that ran or of another: autodiff of the switch itself
+    would keep zeros of every capacity's."""
+    indices, weights = routing("held_600")
+    u, w1, w3, w2 = expert_inputs(tokens=RUNG_TOKENS)
+    worst = RUNG_TOKENS * 4
+
+    def loss(u, weights, w1, w3, w2):
+        return jnp.sum(jnp.sin(
+            moe.routed_experts(u, indices, weights, w1, w3, w2, 4)[0]))
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, range(5)))(u, weights, w1, w3, w2)
+    conds = [e for e in jaxpr_equations(jaxpr.jaxpr, closed=("pallas_call", "cond"))
+             if e.primitive.name == "cond"]
+    assert [len(e.params["branches"]) for e in conds] == [2, 2]
+    for eqn in conds:
+        for side in (eqn.invars, eqn.outvars):
+            rows = [v.aval.shape[0] for v in side if v.aval.ndim == 2]
+            assert rows and sum(rows) <= worst, [v.aval for v in side]
+    for dtype, shape in kept_between_passes(loss, u, weights, w1, w3, w2):
+        assert len(shape) <= 1 or shape == weights.shape or (
+            shape == u.shape and dtype == "f32"), (dtype, shape)   # the sine's
 
 
 # ------------------------------------------------------ the shares add up
@@ -519,6 +609,17 @@ def test_scopes_name_the_parts_and_none_is_another_models():
     assert {"embed", "conv_op", "gqa_op", "dense_ffn", "moe", "moe_route",
             "moe_experts", "lm_head"} <= parts
     assert not parts & {"mamba", "attention", "mlp", "ssd"}
+    # inside the switch's branches, forward, recomputed and backward, the
+    # block's two scopes stay whole components: a transform wraps the
+    # branch's own ``buffer_<rows>`` and never them
+    for rows in moe._capacities(2 * LENGTH * 4):
+        assert {f"buffer_{rows}", f"jvp(buffer_{rows})",
+                f"transpose(jvp(buffer_{rows}))"} <= parts
+    assert not {p for p in parts if re.search(r"\((moe|moe_route|moe_experts)\)", p)}
+    branch = [name.split("/") for name in op_names(text)
+              if "feed_forward/cond/branch_" in name]
+    assert branch and all("moe" in c and ("moe_route" in c or "moe_experts" in c)
+                          for c in branch)
 
 
 # ---------------------------------------------------- zoo, files, counts
@@ -600,6 +701,31 @@ def test_flops_counted_from_shapes():
         cell) == 6 * 8192 * total
 
 
+def test_buffer_fill_reads_held_rows_over_buffer_rows_in_the_traced_steps(tmp_path):
+    """``moe_buffer_fill_pct``: the two counters' means over the steps logged
+    inside the traced ones (after the checked and, in the GLM cell, the
+    settling steps); nothing for a program that logs no buffer rows."""
+    from benchmark.byname import load_module
+
+    reader = load_module(os.path.join(ROOT, "benchmark", "metrics",
+                                      "moe_buffer_fill_pct.py"), "fill_reader")
+    logged = {"train_moe_assignments": {3: 900.0, 13: 40000.0, 23: 36000.0, 33: 1.0},
+              "train_moe_buffer_rows": {3: 131072.0, 13: 61440.0, 23: 49152.0, 33: 1.0}}
+
+    def run(names, **traffic):
+        with open(tmp_path / "metrics.jsonl", "w") as f:
+            for name in names:
+                for step, value in logged[name].items():
+                    f.write(json.dumps({"name": name, "step": step, "value": value}) + "\n")
+        return {"window": {"workdir": str(tmp_path)},
+                "traffic": {"trace_steps": [10, 25], "check_steps": 3, **traffic}}
+
+    assert reader.read(run(logged)) == pytest.approx(100 * 76000 / 110592)
+    assert reader.read(run(logged, settle_steps=10)) == pytest.approx(
+        100 * 36001 / 49153)
+    assert reader.read(run(["train_moe_assignments"])) is None   # the parent
+
+
 # ------------------------------------------------------ optimizer, trainer
 
 def test_decay_mask_leaves_out_every_norm_and_the_bias_is_no_parameter():
@@ -674,6 +800,10 @@ def test_three_steps_through_train_epoch_log_the_counters(tmp_path, mesh1):
             series.setdefault(row["name"], []).append(row["value"])
     assert len(series["train_loss"]) == 3 and np.isfinite(series["train_loss"]).all()
     assert series["train_moe_dropped"] == [0.0] * 3
+    rungs = moe._capacities(2 * LENGTH * 4)      # two routed layers a step
+    for rows, held in zip(series["train_moe_buffer_rows"],
+                          series["train_moe_assignments"]):
+        assert held <= rows and rows in {a + b for a in rungs for b in rungs}
     assert all(0 < v <= 2 * 2 * LENGTH * 4 for v in series["train_moe_assignments"])
     mean = 2 * LENGTH * 4 / 16
     assert all(mean <= v <= 2 * LENGTH for v in series["train_moe_max_load"])
